@@ -108,9 +108,9 @@ def main():
     print("* multi-chip projection: benchmarks/time_to_accuracy_r5.tsv —\n"
           "  compression pays where the link is slow (DCN-class, stable\n"
           "  across latency/overlap assumptions: tta_sensitivity_r5.tsv);\n"
-          "* the wire fast path: Block-Top-K (benchmarks/wire_wall_r5.txt);\n"
-          "* the LM/stretch side: harness.lm --preset llama3_8b\n"
-          "  (benchmarks/lm_throughput_r5.txt, MFU 0.72 at 128k vocab).")
+          "* the wire fast path: Block-Top-K;\n"
+          "* the LM/stretch side: harness.lm --preset llama3_8b;\n"
+          "* chip numbers: PERF.md and PERF_LEDGER.jsonl.")
     summary = {
         "dense": dense["test acc"], "topk_lw_1pct": topk["test acc"],
         "wire_topk_1pct": wire["test acc"], "adaptive_EF": ada["test acc"],

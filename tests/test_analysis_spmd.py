@@ -20,7 +20,6 @@ from tpu_compressed_dp.analysis.spmd import (check_barrier_chain,
                                              check_signature_match,
                                              collective_signature,
                                              count_eqns)
-from tpu_compressed_dp.compat import shard_map
 from tpu_compressed_dp.parallel.mesh import make_data_mesh
 
 pytestmark = pytest.mark.quick
@@ -32,7 +31,10 @@ def mesh():
 
 
 def _smap(fn, mesh, n_in=1):
-    return shard_map(fn, mesh=mesh, in_specs=(P(),) * n_in, out_specs=P())
+    # trace-only fixtures: check_vma off so a psum of the replicated probe
+    # input stays a psum eqn and all_gather results may leave through P()
+    return jax.shard_map(fn, mesh=mesh, in_specs=(P(),) * n_in, out_specs=P(),
+                         check_vma=False)
 
 
 def _codes(findings):

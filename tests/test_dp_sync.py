@@ -5,7 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
-from tpu_compressed_dp.compat import shard_map
+from jax import shard_map
 
 from tpu_compressed_dp.parallel.dp import CompressionConfig, init_ef_state, make_grad_sync
 
@@ -275,3 +275,18 @@ class TestTerngradChunkResolution:
         assert CompressionConfig(
             method="terngrad", granularity="entiremodel",
             terngrad_chunk=0).resolved_terngrad_chunk == 0
+
+
+def test_merged_stats_are_traced_in_one_order():
+    """The adds merge_stat_dicts traces end up in the compiled step; a
+    hash-ordered walk over the keys would change the program from process
+    to process (PYTHONHASHSEED) and the persistent compile cache would
+    never hit (found on the chip: the LM step missed a warm cache)."""
+    from tpu_compressed_dp.parallel.dp import merge_stat_dicts
+
+    keys = [f"stat_{i}" for i in range(40)]
+    a = {k: 1.0 for k in keys[:30]}
+    b = {k: 2.0 for k in keys[10:]}
+    merged = merge_stat_dicts(a, b)
+    assert list(merged) == sorted(keys)
+    assert merged["stat_5"] == 1.0 and merged["stat_20"] == 3.0

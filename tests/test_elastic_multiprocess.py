@@ -6,9 +6,8 @@ half: actual ``jax.distributed`` worlds of 2 OS processes on the CPU
 backend, where a peer's death really wedges the collectives and the
 survivor must rendezvous, re-init, and remesh to keep training.
 
-Both drills are gated on ``HAS_CPU_MULTIPROCESS`` (jax < 0.5 has no
-cross-process CPU collectives) and live in the slow tier: they burn
-wall-clock on real peer-timeout windows.
+Both drills live in the slow tier: they burn wall-clock on real
+peer-timeout windows.
 """
 
 import json
@@ -21,18 +20,11 @@ import time
 
 import pytest
 
-from tpu_compressed_dp import compat
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 WATCHDOG = os.path.join(REPO, "tools", "watchdog.py")
 
-pytestmark = [
-    pytest.mark.slow,
-    pytest.mark.skipif(
-        not compat.HAS_CPU_MULTIPROCESS,
-        reason="this jax's CPU backend has no cross-process collectives — "
-               "a 2-process elastic world cannot form"),
-]
+pytestmark = pytest.mark.slow
 
 
 def _free_port() -> int:

@@ -64,9 +64,8 @@ def test_tool_imports_side_effect_free(module):
 @pytest.mark.quick
 @pytest.mark.imports_smoke
 def test_public_surface():
-    # the version-shimmed shard_map and the stateful-compressor entry points
-    # must be reachable from the package root / their canonical homes
-    assert callable(tpu_compressed_dp.shard_map)
+    # the stateful-compressor entry points must be reachable from their
+    # canonical homes
     from tpu_compressed_dp.ops.compressors import REGISTRY, get_compressor
     from tpu_compressed_dp.parallel.dp import init_comp_state  # noqa: F401
 

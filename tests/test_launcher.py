@@ -15,7 +15,6 @@ import os
 
 import pytest
 
-from tpu_compressed_dp import compat
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 LAUNCHER = os.path.join(REPO, "tools", "launch_tpu.py")
@@ -46,10 +45,6 @@ class TestGcloudMode:
 
 class TestLocalMode:
     @pytest.mark.timeout(300)
-    @pytest.mark.skipif(
-        not compat.HAS_CPU_MULTIPROCESS,
-        reason="this jax's CPU backend has no cross-process collectives — "
-               "the 2-process local launch cannot sync gradients")
     def test_two_process_dawn_trains(self, tmp_path):
         """2 processes x 2 virtual CPU devices: the dawn harness shards the
         global batch per process (`ShardedBatches`), syncs compressed

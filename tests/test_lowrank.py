@@ -20,7 +20,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from tpu_compressed_dp.compat import shard_map
+from jax import shard_map
 from tpu_compressed_dp.ops import compressors, lowrank
 from tpu_compressed_dp.parallel.dp import (
     CompressionConfig,

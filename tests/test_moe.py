@@ -15,7 +15,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from tpu_compressed_dp.compat import shard_map
+from jax import shard_map
 
 from tpu_compressed_dp.models import transformer as tf
 

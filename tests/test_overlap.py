@@ -12,7 +12,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from tpu_compressed_dp.compat import shard_map
+from jax import shard_map
 from tpu_compressed_dp.parallel.dp import (CompressionConfig, init_comp_state,
                                            init_ef_state, make_grad_sync,
                                            make_leaf_groups)

@@ -8,7 +8,6 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from tpu_compressed_dp import compat
 from tpu_compressed_dp.models import transformer as tf
 from tpu_compressed_dp.parallel.dp import CompressionConfig
 from tpu_compressed_dp.train.optim import SGD
@@ -134,14 +133,6 @@ def test_validation_errors():
         stack_layer_params(tf.init_llama(cfg, jax.random.key(0)))
 
 
-@pytest.mark.xfail(
-    not compat.HAS_VMA,
-    reason="old-jax layout artifact: Orbax-restored arrays compile a "
-           "different executable than step outputs (bitwise-equal values "
-           "and shardings verified), whose fp reduction reorder flips "
-           "top-k threshold ties — ~1e-3 trajectory divergence after one "
-           "step; exact on VMA-era jax",
-    strict=False)
 def test_pp_checkpoint_resume(tmp_path):
     """PP-step checkpoint/resume (`train_imagenet_nv.py:193-198` analog):
     save mid-run, restore into a fresh state, re-place on the (data, pipe)

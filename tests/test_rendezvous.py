@@ -4,8 +4,8 @@ The protocol is plain files + injectable clocks, so every multi-rank
 interleaving here is scripted deterministically from a single thread: a
 follower's ``sleep`` callback runs the leader's ``propose`` (or writes the
 epoch file directly), and the follower's next poll observes the commit.
-The real ``jax.distributed`` wiring is exercised by the
-``HAS_CPU_MULTIPROCESS``-gated drills in tests/test_elastic_multiprocess.py.
+The real ``jax.distributed`` wiring is exercised by the 2-process drills
+in tests/test_elastic_multiprocess.py.
 """
 
 import json

@@ -16,18 +16,12 @@ import sys
 
 import pytest
 
-from tpu_compressed_dp import compat
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TOOL = os.path.join(ROOT, "tools", "validate_transport.py")
 
 
 @pytest.mark.timeout(600)
-@pytest.mark.skipif(
-    not compat.HAS_CPU_MULTIPROCESS,
-    reason="this jax's CPU backend has no cross-process collectives "
-           "('Multiprocess computations aren't implemented on the CPU "
-           "backend') — the 2-process rendezvous cannot run")
 def test_measured_lo_bytes_track_analytic(tmp_path):
     out = tmp_path / "transport.tsv"
     env = dict(os.environ)

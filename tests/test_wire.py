@@ -11,7 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
-from tpu_compressed_dp.compat import shard_map
+from jax import shard_map
 
 from tpu_compressed_dp.ops import wire
 from tpu_compressed_dp.parallel.dp import CompressionConfig, init_ef_state, make_grad_sync

@@ -23,7 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
-from tpu_compressed_dp.compat import shard_map
+from jax import shard_map
 
 from tpu_compressed_dp.ops import wire, wire_sharded
 from tpu_compressed_dp.parallel.dp import (CompressionConfig,
@@ -269,6 +269,32 @@ class TestHierEquivalence:
             r8 = hier_dcn(8, pods) / flat_dcn(8, pods)
             r64 = hier_dcn(64, pods) / flat_dcn(64, pods)
             assert r64 < r8 / 3 < 0.25, (pods, r8, r64)
+
+
+def test_group_psum_under_the_replication_check():
+    """The train step's shard_map keeps check_vma on, where jax.lax.psum
+    refuses axis_index_groups: the hierarchical transport's pod sums go
+    through wire._group_psum, typed varying unless every group agrees."""
+    groups = [[0, 1], [2, 3]]
+    x = jnp.arange(8.0).reshape(4, 2)
+
+    def f(v):
+        pod = wire._group_psum(v, "data", groups, same_everywhere=False)
+        assert jax.typeof(pod).vma == {"data"}
+        # every rank holds half the world total, so both pods of two ranks
+        # arrive at the same sum
+        total = wire._group_psum(
+            jax.lax.psum(v, "data") / 2.0, "data", groups,
+            same_everywhere=True)
+        assert not jax.typeof(total).vma
+        return pod, total
+
+    pod, total = jax.jit(shard_map(
+        f, mesh=mesh_of(4), in_specs=P("data"),
+        out_specs=(P("data"), P())))(x)
+    np.testing.assert_array_equal(
+        np.asarray(pod), [[2., 4.], [2., 4.], [10., 12.], [10., 12.]])
+    np.testing.assert_array_equal(np.asarray(total), [[12., 16.]])
 
 
 class TestAcceptance:

@@ -314,7 +314,7 @@ def drill_loss_scale(mesh) -> Dict:
 def drill_ef_identity(mesh, transport="allgather", mode="simulate") -> Dict:
     """transmitted + residual == gradient on a non-vetoed guarded sync:
     per worker, ``psum(acc - new_ef)/W == synced`` where acc = grad + ef."""
-    from tpu_compressed_dp.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from tpu_compressed_dp.parallel.dp import CompressionConfig, make_grad_sync
@@ -1429,6 +1429,9 @@ def run_drills(names, mesh=None) -> Dict[str, Dict]:
 
 
 def main(argv=None) -> int:
+    from tpu_compressed_dp.parallel.mesh import setup_compile_cache
+
+    setup_compile_cache()
     p = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     p.add_argument("--quick", action="store_true",
                    help="tier-1 smoke subset (skip_consistency, loss_scale, "

@@ -150,8 +150,8 @@ GRID = [
                              "adaptive_threshold", "--error_feedback",
                              "--epochs", "40"]),
     # r5: small-block Block-Top-K — the granularity<->accuracy frontier
-    # companion to the throughput bs-sweep (benchmarks/wire_wall_r5.txt):
-    # does bs=64 selection (the 1.64x-dense wire point) converge like
+    # companion to the round-5 throughput bs-sweep: does bs=64 selection
+    # (1.64x dense on the wire in that session, pre-ledger) converge like
     # element Top-K (0.9619) or cost accuracy?
     ("blocktopk-em-1%-wire-bs64", ["--compress", "entiremodel", "--method",
                                    "blocktopk", "--ratio", "0.01",

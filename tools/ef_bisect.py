@@ -317,6 +317,9 @@ def run_operating_point(args):
 
 
 def main(argv=None):
+    from tpu_compressed_dp.parallel.mesh import setup_compile_cache
+
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=640)
     ap.add_argument("--peak_lr", type=float, default=0.4)

@@ -89,7 +89,9 @@ def main(argv=None) -> int:
     p.add_argument("--run", action="store_true",
                    help="execute the gcloud command (default: print it)")
     p.add_argument("--local_procs", type=int, default=None,
-                   help="spawn N local processes instead of gcloud")
+                   help="spawn N local CPU-only processes instead of gcloud "
+                        "(a chip belongs to one process; these never ask "
+                        "for one)")
     p.add_argument("--devices_per_proc", type=int, default=2)
     p.add_argument("--port", type=int, default=29431)
     p.add_argument("train_cmd", nargs=argparse.REMAINDER,

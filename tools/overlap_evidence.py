@@ -267,6 +267,9 @@ DEFAULT_CASES = [
 
 
 def main(argv=None):
+    from tpu_compressed_dp.parallel.mesh import setup_compile_cache
+
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default=None,
                     help="output artifact (default: benchmarks/"

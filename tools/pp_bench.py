@@ -33,6 +33,9 @@ from tpu_compressed_dp.train.pp_step import (
 
 
 def main():
+    from tpu_compressed_dp.parallel.mesh import setup_compile_cache
+
+    setup_compile_cache()
     import dataclasses
     cfg = dataclasses.replace(
         tf.tiny_llama(), vocab_size=32768, dim=128, n_layers=4,

@@ -214,6 +214,9 @@ def run(out: str, *, ratio: float, keyframe_every: int, steps: int,
 
 
 def main(argv: Optional[List[str]] = None) -> int:
+    from tpu_compressed_dp.parallel.mesh import setup_compile_cache
+
+    setup_compile_cache()
     p = argparse.ArgumentParser(
         description=__doc__.split("\n")[0],
         formatter_class=argparse.RawDescriptionHelpFormatter)

@@ -108,13 +108,17 @@ def measure_row(label: str, extra, cache: dict, steps: int, warmup: int):
     on the ResNet-9 bs-512 32px workload (the convergence grid's model).
 
     Returns ``(record, was_cache_hit)``; the cache key includes the
-    measurement parameters AND a hash of the grid point's args, so a
-    --steps/--warmup change — or a recipe change under an unchanged label
-    (ADVICE r4) — re-measures instead of silently reusing stale numbers."""
+    measurement parameters, a hash of the grid point's args, the device
+    kind and the JAX version, so a --steps/--warmup change, a recipe change
+    under an unchanged label (ADVICE r4), another chip or another compiler
+    re-measures instead of silently reusing stale numbers."""
     import hashlib
 
+    import jax
+
     args_h = hashlib.md5(json.dumps(list(extra)).encode()).hexdigest()[:10]
-    key = f"{label}@steps={steps},warmup={warmup},args={args_h}"
+    key = (f"{label}@steps={steps},warmup={warmup},args={args_h},"
+           f"device={jax.devices()[0].device_kind},jax={jax.__version__}")
     if key in cache:
         return cache[key], True
     from tpu_compressed_dp.bench.sweep import run_point
@@ -136,6 +140,9 @@ def measure_row(label: str, extra, cache: dict, steps: int, warmup: int):
 
 
 def main(argv=None):
+    from tpu_compressed_dp.parallel.mesh import setup_compile_cache
+
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--convergence", default="benchmarks/convergence_r3.tsv")
     ap.add_argument("--out", default="benchmarks/time_to_accuracy_r4.tsv")

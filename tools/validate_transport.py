@@ -73,12 +73,16 @@ def worker(args) -> None:
     """Rank entry: real jax.distributed 2-process CPU mesh, N sync steps."""
     import jax
 
+    from tpu_compressed_dp.parallel.mesh import setup_compile_cache
+
+    # the parent only spawns and parses; the workers are what compiles
+    setup_compile_cache()
     jax.config.update("jax_platforms", "cpu")
     jax.distributed.initialize(f"127.0.0.1:{args.port}", args.procs, args.rank)
     import jax.numpy as jnp
     import numpy as np
     from jax.sharding import Mesh, PartitionSpec as P
-    from tpu_compressed_dp.compat import shard_map
+    from jax import shard_map
     from tpu_compressed_dp.parallel.dp import CompressionConfig, make_grad_sync
 
     _, method, mode, extra = next(c for c in CASES if c[0] == args.case)

@@ -10,7 +10,6 @@ workloads, phase schedules, checkpointing, and comm observability — all over
 
 __version__ = "0.1.0"
 
-from tpu_compressed_dp.compat import shard_map  # noqa: F401  (version shim)
 from tpu_compressed_dp.parallel.dp import CompressionConfig  # noqa: F401
 from tpu_compressed_dp.parallel.mesh import make_data_mesh, distributed_init  # noqa: F401
 from tpu_compressed_dp.train.optim import SGD  # noqa: F401

@@ -70,7 +70,7 @@ __all__ = [
 #: primitives that hit the interconnect — any of these inside divergent
 #: control flow is a cross-worker deadlock in waiting
 COLLECTIVE_PRIMS = frozenset({
-    "psum", "psum2", "pmin", "pmax", "ppermute", "pbroadcast",
+    "psum", "psum_invariant", "pmin", "pmax", "ppermute", "pbroadcast",
     "all_gather", "all_gather_invariant", "all_to_all",
     "reduce_scatter", "psum_scatter",
 })
@@ -99,7 +99,7 @@ def _sub_jaxprs(eqn) -> Iterable[Any]:
 def _is_var(v) -> bool:
     """True for jaxpr Vars (hashable, traceable to a producer) — excludes
     Literals, which also carry ``.aval`` but are constants."""
-    from jax.core import Literal
+    from jax.extend.core import Literal
     return hasattr(v, "aval") and not isinstance(v, Literal)
 
 
@@ -131,8 +131,6 @@ def collective_signature(jaxpr) -> List[Sig]:
 
 def _influencing_invars(jaxpr) -> Set[int]:
     """Indices of ``jaxpr.invars`` the outputs transitively depend on."""
-    from jax import core  # noqa: F401  (Literal detection below)
-
     producers: Dict[Any, Any] = {}
     for eqn in jaxpr.eqns:
         for ov in eqn.outvars:
@@ -181,7 +179,7 @@ def _while_predicate_data_dependent(eqn) -> bool:
     site and through one body application) touches float data.  A pure
     counter loop (``fori_loop``: int carry updated from literals) passes."""
     import jax.numpy as jnp
-    from jax.core import Literal
+    from jax.extend.core import Literal
 
     cond_closed = eqn.params["cond_jaxpr"]
     cj = getattr(cond_closed, "jaxpr", cond_closed)
@@ -448,7 +446,6 @@ def trace_sync(cfg, mesh, *, chunked: bool = False):
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from tpu_compressed_dp.compat import shard_map
     from tpu_compressed_dp.parallel import dp, overlap
 
     grads = _grads()
@@ -466,8 +463,11 @@ def trace_sync(cfg, mesh, *, chunked: bool = False):
     def f(g, e, c, k):
         return sync(g, e, c, k, ok=jnp.asarray(True))
 
-    sm = shard_map(f, mesh=mesh, in_specs=(P(), P(), P(), P()),
-                   out_specs=P())
+    # trace-only probe: the outputs are per-worker values that P() does not
+    # describe, so the replication check is off (the collective program is
+    # the same either way)
+    sm = jax.shard_map(f, mesh=mesh, in_specs=(P(), P(), P(), P()),
+                       out_specs=P(), check_vma=False)
     closed = jax.make_jaxpr(sm)(grads, ef, comp, jax.random.key(0))
     return closed, len(leaves), len(groups), plans
 

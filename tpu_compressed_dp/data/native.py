@@ -6,8 +6,9 @@ hash) and exposes :func:`crop_resize` — fused crop + PIL-BILINEAR resize +
 horizontal flip on uint8 RGB arrays.  ctypes releases the GIL for the call,
 so the loaders' thread pools parallelise across images.
 
-Falls back cleanly: :func:`available` is False when no compiler exists or
-the build fails, and the loaders keep their pure-PIL path.
+:func:`available` is False when no compiler exists or the build fails, and
+the loaders keep their pure-PIL path; the first load says on stderr which of
+the two paths this process took, and why.
 """
 
 from __future__ import annotations
@@ -16,6 +17,7 @@ import ctypes
 import hashlib
 import os
 import subprocess
+import sys
 import threading
 from typing import Optional, Tuple
 
@@ -58,9 +60,12 @@ def _load() -> Optional[ctypes.CDLL]:
             return _LIB
         try:
             lib = ctypes.CDLL(build())
-        except Exception:
+        except (OSError, RuntimeError, subprocess.SubprocessError) as err:
             _FAILED = True
+            print(f"image ops: PIL path (native kernel unavailable: {err})",
+                  file=sys.stderr)
             return None
+        print(f"image ops: native kernel {lib._name}", file=sys.stderr)
         lib.crop_resize_bilinear.restype = ctypes.c_int
         lib.crop_resize_bilinear.argtypes = [
             ctypes.c_void_p, ctypes.c_int, ctypes.c_int,

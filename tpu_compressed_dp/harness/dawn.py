@@ -59,7 +59,8 @@ from tpu_compressed_dp.models.common import (
 )
 from tpu_compressed_dp.parallel.dp import (CompressionConfig, init_comp_state,
                                            init_ef_state)
-from tpu_compressed_dp.parallel.mesh import make_data_mesh
+from tpu_compressed_dp.parallel.mesh import (make_data_mesh,
+                                             setup_compile_cache)
 from tpu_compressed_dp.train.optim import SGD
 from tpu_compressed_dp.train.guard import init_guard_state
 from tpu_compressed_dp.train.schedules import piecewise_linear
@@ -453,10 +454,17 @@ def run(args) -> dict:
             state, meta = restorer.restore(state)
         finally:
             restorer.close()
-        state = state.with_mesh_sharding(mesh)
         start_epoch = int(meta.get("epoch", -1)) + 1
         print(f"resumed step {int(state.step)} from {args.resume} "
               f"(starting epoch {start_epoch})")
+    # fresh or restored, the state is built on one device: lay it out as the
+    # step's in_specs expect (EF and compressor rows over the data axis)
+    # before the first call, so that call neither holds every worker's rows
+    # on device 0 nor compiles for a layout no later call has.  (Several
+    # processes: device_put cannot address the other hosts' devices; each
+    # process hands jit its identical local copy, as before.)
+    if procs == 1:
+        state = state.with_mesh_sharding(mesh)
 
     # epoch summaries print master-only, like the reference's rank-0-gated
     # loggers (`logger.py:74-121`); metrics are globally reduced so every
@@ -770,6 +778,7 @@ def run(args) -> dict:
 
 
 def main(argv: Optional[list] = None):
+    setup_compile_cache()
     args = build_parser().parse_args(argv)
     return run(args)
 

@@ -82,6 +82,7 @@ from tpu_compressed_dp.parallel.dp import (CompressionConfig, init_comp_state,
 from tpu_compressed_dp.parallel.mesh import (
     make_data_mesh,
     make_global_batch,
+    setup_compile_cache,
 )
 from tpu_compressed_dp.train.optim import SGD, bn_wd_mask
 from tpu_compressed_dp.train.guard import init_guard_state
@@ -416,13 +417,16 @@ def run(args) -> Dict[str, float]:
         restore = Checkpointer(args.resume)
         state, meta = restore.restore(state)
         restore.close()
-        state = state.with_mesh_sharding(mesh)
         start_epoch = int(meta.get("epoch", 0)) + 1
         if ckpt is not None and restore.best_metric is not None:
             # carry best-so-far forward so a worse epoch can't evict the true
             # best (the reference restores best_top5, `train_imagenet_nv.py:195-197`)
             ckpt.best_metric = restore.best_metric
         print(f"resumed step {int(state.step)} (epoch {start_epoch})")
+    # fresh or restored, the state is built on one device: lay it out as the
+    # step's in_specs expect before the first call (see harness/dawn.py)
+    if jax.process_count() == 1:
+        state = state.with_mesh_sharding(mesh)
 
     step_cache: Dict = {}
 
@@ -833,6 +837,7 @@ def run(args) -> Dict[str, float]:
 
 
 def main(argv: Optional[list] = None):
+    setup_compile_cache()
     return run(build_parser().parse_args(argv))
 
 

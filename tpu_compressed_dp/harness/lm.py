@@ -27,6 +27,7 @@ import numpy as np
 from tpu_compressed_dp.data import lm as lm_data
 from tpu_compressed_dp.models import transformer as tf
 from tpu_compressed_dp.parallel.dp import CompressionConfig
+from tpu_compressed_dp.parallel.mesh import setup_compile_cache
 from tpu_compressed_dp.train.lm_step import (
     init_lm_ef_state,
     make_lm_mesh,
@@ -290,13 +291,16 @@ def run(args) -> Dict[str, float]:
                                         guard_cfg=guard_cfg, chaos=chaos)
         ckpt = Checkpointer(args.checkpoint_dir) if args.checkpoint_dir else None
         if args.resume:
-            from tpu_compressed_dp.train.pp_step import place_pp_state
-
             restore = Checkpointer(args.resume)
             state, meta = restore.restore(state)
             restore.close()
-            state = place_pp_state(state, cfg, comp, mesh)
             print(f"resumed step {int(state.step)}")
+        from tpu_compressed_dp.train.pp_step import place_pp_state
+
+        # fresh or restored, the state is built on one device: shard it per
+        # the step's specs before the first call (see harness/dawn.py)
+        if jax.process_count() == 1:
+            state = place_pp_state(state, cfg, comp, mesh)
     else:
         from tpu_compressed_dp.train.lm_step import init_lm_comp_state
 
@@ -309,13 +313,16 @@ def run(args) -> Dict[str, float]:
         )
         ckpt = Checkpointer(args.checkpoint_dir) if args.checkpoint_dir else None
         if args.resume:
-            from tpu_compressed_dp.train.lm_step import place_lm_state
-
             restore = Checkpointer(args.resume)
             state, meta = restore.restore(state)
             restore.close()
-            state = place_lm_state(state, cfg, comp, mesh)
             print(f"resumed step {int(state.step)}")
+        from tpu_compressed_dp.train.lm_step import place_lm_state
+
+        # fresh or restored, the state is built on one device: shard it per
+        # the step's specs before the first call (see harness/dawn.py)
+        if jax.process_count() == 1:
+            state = place_lm_state(state, cfg, comp, mesh)
 
         train_step = lm_step_for(active_comp())
     mesh_str = (f"dp{dp}xsp{args.sp}xpp{args.pp}xtp{args.tp}(mb{args.microbatches})" if pipelined
@@ -692,6 +699,7 @@ def run(args) -> Dict[str, float]:
 
 
 def main(argv: Optional[list] = None):
+    setup_compile_cache()
     return run(build_parser().parse_args(argv))
 
 

@@ -34,7 +34,6 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from tpu_compressed_dp import compat
 from jax.sharding import PartitionSpec as P
 
 from tpu_compressed_dp.ops.ring_attention import ring_attention
@@ -371,16 +370,7 @@ def use_fused_head_xent(n_tokens: int = 0, vocab: int = 0,
     logits dtype width in bytes (``jnp.dtype(cfg.dtype).itemsize`` — fp32
     configs materialise a 2x larger buffer than the old hardcoded bf16
     estimate, so the crossover fired at twice the intended size, ADVICE r5).
-
-    Requires VMA typing: the custom VJP places its cross-shard cotangent
-    psums by diffing primal/cotangent varying-axes (``match_vma``), which
-    old JAX cannot express — there the hand-placed psums would silently
-    vanish and tp>1 gradients would be per-shard partials.  The unfused
-    vocab-parallel path is correct everywhere, so old JAX always takes it
-    (this is a peak-memory feature, not a correctness one).
     """
-    if not compat.HAS_VMA:
-        return False
     if _FUSED_XENT in ("0", "1"):
         return _FUSED_XENT == "1"
     return n_tokens * vocab * itemsize > _FUSED_XENT_AUTO_BYTES
@@ -447,11 +437,11 @@ def _fhx_scan_stats(h2, w, targets1, off, v_local, c, nc):
     # the varying h/w/targets — targets can vary on axes h does not, e.g.
     # pipe in the deferred-head uneven fallback); pcast the replicated init
     # so scan's carry types match
-    vma = tuple(sorted(getattr(compat.typeof(h2), "vma", frozenset())
-                       | getattr(compat.typeof(w), "vma", frozenset())
-                       | getattr(compat.typeof(targets1), "vma", frozenset())))
+    vma = tuple(sorted(jax.typeof(h2).vma
+                       | jax.typeof(w).vma
+                       | jax.typeof(targets1).vma))
     if vma:
-        init = tuple(compat.pcast(v, vma, to="varying") for v in init)
+        init = tuple(jax.lax.pcast(v, vma, to="varying") for v in init)
     (m, l, zt), _ = jax.lax.scan(
         body, init, (w3.transpose(1, 0, 2), jnp.arange(nc)))
     return m, l, zt
@@ -520,13 +510,13 @@ def _fhx_bwd(tensor_axis, chunk, res, g):
         return dh, dw_c
 
     dh0 = jnp.zeros((n, d), jnp.float32)
-    vma = tuple(sorted(getattr(compat.typeof(h2), "vma", frozenset())
-                       | getattr(compat.typeof(w_p), "vma", frozenset())
-                       | getattr(compat.typeof(lse), "vma", frozenset())
-                       | getattr(compat.typeof(targets1), "vma", frozenset())
-                       | getattr(compat.typeof(dnll), "vma", frozenset())))
+    vma = tuple(sorted(jax.typeof(h2).vma
+                       | jax.typeof(w_p).vma
+                       | jax.typeof(lse).vma
+                       | jax.typeof(targets1).vma
+                       | jax.typeof(dnll).vma))
     if vma:
-        dh0 = compat.pcast(dh0, vma, to="varying")
+        dh0 = jax.lax.pcast(dh0, vma, to="varying")
     dh, dw_stack = jax.lax.scan(body, dh0, (w3, jnp.arange(nc)))
     dw = dw_stack.transpose(1, 0, 2).reshape(d, v_pad)[:, :v_local]
 
@@ -538,9 +528,8 @@ def _fhx_bwd(tensor_axis, chunk, res, g):
     # pvary where replicated values meet varying operands; a custom VJP must
     # place them by hand.
     def match_vma(ct, primal):
-        extra = tuple(sorted(getattr(compat.typeof(ct), "vma", frozenset())
-                             - getattr(compat.typeof(primal), "vma",
-                                       frozenset())))
+        extra = tuple(sorted(jax.typeof(ct).vma
+                             - jax.typeof(primal).vma))
         return jax.lax.psum(ct, extra) if extra else ct
 
     dh = match_vma(dh, h)

@@ -41,15 +41,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from tpu_compressed_dp import compat
-
-try:  # pragma: no cover - CPU-only builds
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAVE_PALLAS = True
-except ImportError:  # pragma: no cover
-    _HAVE_PALLAS = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 Array = jax.Array
 
@@ -59,7 +52,7 @@ _NEG_INF = -1e30
 
 
 def _vma(x: Array):
-    return getattr(compat.typeof(x), "vma", frozenset())
+    return jax.typeof(x).vma
 
 
 def _causal_pos(qi, kj, blk_q, blk_k):
@@ -290,7 +283,7 @@ def _fwd(q, k, v, scale, blk, interpret, d):
         ],
         out_specs=pl.BlockSpec((1, bq, ds), lambda bh, qi: (bh, qi, 0),
                                memory_space=pltpu.VMEM),
-        out_shape=compat.shape_dtype_struct((b * h, t, ds), jnp.float32, vma=vma),
+        out_shape=jax.ShapeDtypeStruct((b * h, t, ds), jnp.float32, vma=vma),
         scratch_shapes=[
             pltpu.VMEM((bq, d_pad), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
@@ -322,7 +315,7 @@ def _bwd(q, k, v, dop, scale, blk, interpret, out_dtype, d):
         ],
         out_specs=pl.BlockSpec((1, bq, d_pad), lambda bh, qi: (bh, qi, 0),
                                memory_space=pltpu.VMEM),
-        out_shape=compat.shape_dtype_struct((b * h, t, d_pad), out_dtype, vma=vma),
+        out_shape=jax.ShapeDtypeStruct((b * h, t, d_pad), out_dtype, vma=vma),
         scratch_shapes=[pltpu.VMEM((bq, d_pad), jnp.float32)],
         interpret=interpret,
     )(qs, ks, vs, dops)
@@ -342,8 +335,8 @@ def _bwd(q, k, v, dop, scale, blk, interpret, out_dtype, d):
     else:
         dkv_kernel = functools.partial(
             _dkv_kernel_streamed, scale, bq, bk, t // bq, d)
-        qd_specs = [pl.BlockSpec(memory_space=pltpu.ANY), kv_block, kv_block,
-                    pl.BlockSpec(memory_space=pltpu.ANY)]
+        qd_specs = [pl.BlockSpec(memory_space=pl.ANY), kv_block, kv_block,
+                    pl.BlockSpec(memory_space=pl.ANY)]
         extra_scratch = [
             pltpu.VMEM((2, bq, d_pad), qs.dtype),
             pltpu.VMEM((2, bq, ds), dops.dtype),
@@ -361,8 +354,8 @@ def _bwd(q, k, v, dop, scale, blk, interpret, out_dtype, d):
                          memory_space=pltpu.VMEM),
         ],
         out_shape=[
-            compat.shape_dtype_struct((b * h, t, d_pad), out_dtype, vma=vma),
-            compat.shape_dtype_struct((b * h, t, d_pad), out_dtype, vma=vma),
+            jax.ShapeDtypeStruct((b * h, t, d_pad), out_dtype, vma=vma),
+            jax.ShapeDtypeStruct((b * h, t, d_pad), out_dtype, vma=vma),
         ],
         scratch_shapes=[
             pltpu.VMEM((bk, d_pad), jnp.float32),

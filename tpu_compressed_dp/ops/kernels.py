@@ -39,11 +39,11 @@ that map badly onto stock XLA at gradient scale (SURVEY.md §7 "hard parts"):
     monotone-row invariant the owner-side sorted-scatter hints rely on.
 
 Dispatch: ``auto`` (default) uses the kernels on TPU backends for tensors
-of at least ``MIN_PALLAS_ELEMS`` elements and falls back to pure JAX
+of at least ``MIN_PALLAS_ELEMS`` elements and the pure-JAX operators
 elsewhere; ``off`` / ``force`` override.  Off-TPU, ``force`` runs the
-non-PRNG kernels under the Pallas interpreter — slow, but it executes the
-fused dispatch call sites end to end in CPU CI (PRNG kernels additionally
-need the TPU-semantics interpreter, `compat.HAS_TPU_INTERPRET`).  The
+kernels under the Pallas interpreter — slow, but it executes the fused
+dispatch call sites end to end in CPU CI (the PRNG kernels under the
+TPU-semantics interpreter, ``pltpu.InterpretParams``).  The
 quantizer kernels draw from the TPU hardware PRNG, a *different stream* than
 ``jax.random`` — same distribution, so estimators stay unbiased, but
 bitwise results differ from the pure path (the dispatch seed is derived from
@@ -58,15 +58,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from tpu_compressed_dp import compat
-
-try:  # Pallas TPU lowering is unavailable on some CPU-only builds
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAVE_PALLAS = True
-except ImportError:  # pragma: no cover
-    _HAVE_PALLAS = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 Array = jax.Array
 
@@ -114,7 +107,7 @@ def pallas_mode() -> str:
 
 
 def _dispatch_to_pallas(n: int) -> bool:
-    if not _HAVE_PALLAS or _MODE == "off":
+    if _MODE == "off":
         return False
     if _MODE == "force":
         return True
@@ -124,8 +117,8 @@ def _dispatch_to_pallas(n: int) -> bool:
 def _auto_interpret() -> bool:
     """``force`` off-TPU runs the kernels under the Pallas interpreter, so
     the fused dispatch *paths* (wire/sharded call sites included) execute end
-    to end in CPU CI instead of dying in Mosaic lowering.  PRNG kernels stay
-    on the TPU-semantics interpreter gate (`compat.HAS_TPU_INTERPRET`) — the
+    to end in CPU CI instead of dying in Mosaic lowering.  PRNG kernels run
+    under the TPU-semantics interpreter (``pltpu.InterpretParams``) — the
     stock HLO interpreter's PRNG is a zero stub."""
     return _MODE == "force" and jax.default_backend() != "tpu"
 
@@ -229,7 +222,7 @@ def _count_edges_kernel(edges_ref, x_ref, counts_ref):
 def _vma(x: Array):
     """Varying-mesh-axes of ``x`` — must be propagated onto pallas_call
     out_shapes when the kernel runs on device-varying data inside shard_map."""
-    return getattr(compat.typeof(x), "vma", frozenset())
+    return jax.typeof(x).vma
 
 
 def _topk_threshold_pallas(
@@ -253,7 +246,7 @@ def _topk_threshold_pallas(
             pl.BlockSpec((_HIST_ROWS, _LANES), lambda i: (i, 0), memory_space=pltpu.VMEM),
         ],
         out_specs=pl.BlockSpec((1, _LANES), lambda i: (0, 0), memory_space=pltpu.VMEM),
-        out_shape=compat.shape_dtype_struct((1, _LANES), jnp.float32, vma=_vma(mag)),
+        out_shape=jax.ShapeDtypeStruct((1, _LANES), jnp.float32, vma=_vma(mag)),
         interpret=interpret,
     )
 
@@ -285,11 +278,11 @@ def _topk_threshold_pallas(
         # carries become device-varying after a count round (counts derive
         # from the varying magnitudes) — pcast replicated values so loop /
         # cond branch types match
-        vma = tuple(_vma(mag))
+        vma = tuple(sorted(_vma(mag)))
         if not vma:
             return vals
         return tuple(
-            compat.pcast(v, vma, to="varying") if not _vma(v) else v for v in vals
+            jax.lax.pcast(v, vma, to="varying") if not _vma(v) else v for v in vals
         )
 
     # max|g| strictly below hi so the top element always lands in a bin.
@@ -361,7 +354,8 @@ def _topk_threshold_pallas(
     hi0 = full_init[1]                                       # max*(1+eps)
     edges = jnp.stack(
         [jnp.float32(0.0) if not _vma(mag)
-         else compat.pcast(jnp.float32(0.0), tuple(_vma(mag)), to="varying")]
+         else jax.lax.pcast(jnp.float32(0.0), tuple(sorted(_vma(mag))),
+                            to="varying")]
         + [jnp.where(jnp.isfinite(e), jnp.minimum(e, hi0), hi0)
            for e in interior] + [hi0]
     )
@@ -377,7 +371,7 @@ def _topk_threshold_pallas(
         ],
         out_specs=pl.BlockSpec((1, _LANES), lambda i: (0, 0),
                                memory_space=pltpu.VMEM),
-        out_shape=compat.shape_dtype_struct((1, _LANES), jnp.float32, vma=_vma(mag)),
+        out_shape=jax.ShapeDtypeStruct((1, _LANES), jnp.float32, vma=_vma(mag)),
         interpret=interpret,
     )
     counts = count_edges(edges.reshape(1, -1), x2d)[0][:_HIST_BINS]
@@ -543,10 +537,10 @@ def fused_sparsify(acc: Array, t: Array, *, want_ef: bool = True,
     big = pl.BlockSpec((rows, _LANES), lambda i: (i, 0), memory_space=pltpu.VMEM)
     out_specs = [big] + ([big] if want_ef else []) + [
         pl.BlockSpec((1, _LANES), lambda i: (0, 0), memory_space=pltpu.VMEM)]
-    out_shape = [compat.shape_dtype_struct(x2d.shape, jnp.float32, vma=vma)]
+    out_shape = [jax.ShapeDtypeStruct(x2d.shape, jnp.float32, vma=vma)]
     if want_ef:
-        out_shape.append(compat.shape_dtype_struct(x2d.shape, jnp.float32, vma=vma))
-    out_shape.append(compat.shape_dtype_struct((1, _LANES), jnp.int32, vma=vma))
+        out_shape.append(jax.ShapeDtypeStruct(x2d.shape, jnp.float32, vma=vma))
+    out_shape.append(jax.ShapeDtypeStruct((1, _LANES), jnp.int32, vma=vma))
     outs = pl.pallas_call(
         functools.partial(_fused_sparsify_kernel, want_ef, n),
         grid=(num_chunks,),
@@ -599,8 +593,8 @@ def _pack_kernel(n: int, cap_rows: int, want_ef: bool, t_ref, x_ref, *refs):
     plus (optionally) the EF residual and the survivor count.
 
     Replaces the r2 chain threshold-mask -> hierarchical rank -> gather ->
-    EF scatter (4+ passes with element-granular gathers at ~25-50 M/s,
-    benchmarks/lm_throughput_r2.txt) with: per-row inclusive prefix via a
+    EF scatter (4+ passes with element-granular gathers at ~25-50 M/s in
+    the round-2 sessions) with: per-row inclusive prefix via a
     lower-triangular matmul, in-row one-hot compaction with the row's
     lane-rotation folded into the one-hot destination (Mosaic has no
     dynamic element-granular stores OR dynamic 1-D rotates), two
@@ -774,18 +768,18 @@ def pack_by_threshold(acc: Array, t: Array, keep: int, *, want_ef: bool = True,
     cap_rows = pack_payload_slots(n, keep) // _LANES
     out_rows = cap_rows + _PACK_ROWS          # slack for the last DMA window
     out_shape = [
-        compat.shape_dtype_struct((out_rows, _LANES), jnp.float32, vma=vma),
-        compat.shape_dtype_struct((out_rows, _LANES), jnp.int32, vma=vma),
+        jax.ShapeDtypeStruct((out_rows, _LANES), jnp.float32, vma=vma),
+        jax.ShapeDtypeStruct((out_rows, _LANES), jnp.int32, vma=vma),
     ]
     out_specs = [
-        pl.BlockSpec(memory_space=pltpu.ANY),
-        pl.BlockSpec(memory_space=pltpu.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
     ]
     if want_ef:
-        out_shape.append(compat.shape_dtype_struct(x2d.shape, jnp.float32, vma=vma))
+        out_shape.append(jax.ShapeDtypeStruct(x2d.shape, jnp.float32, vma=vma))
         out_specs.append(pl.BlockSpec((_PACK_ROWS, _LANES), lambda i: (i, 0),
                                       memory_space=pltpu.VMEM))
-    out_shape.append(compat.shape_dtype_struct((1, 3), jnp.int32, vma=vma))
+    out_shape.append(jax.ShapeDtypeStruct((1, 3), jnp.int32, vma=vma))
     out_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
     outs = pl.pallas_call(
         functools.partial(_pack_kernel, n, cap_rows, want_ef),
@@ -806,8 +800,8 @@ def pack_by_threshold(acc: Array, t: Array, keep: int, *, want_ef: bool = True,
             pltpu.SemaphoreType.DMA,
             pltpu.SemaphoreType.DMA,
         ],
-        interpret=compat.pallas_interpret_params() if interpret else False,
-        compiler_params=compat.pallas_compiler_params(
+        interpret=pltpu.InterpretParams() if interpret else False,
+        compiler_params=pltpu.CompilerParams(
             has_side_effects=True,
             # the unrolled one-hot sub-blocks keep several [S,128,128]
             # temporaries live; the default 16M scoped-vmem limit is too
@@ -987,9 +981,9 @@ def seg_pack_by_threshold(acc: Array, t: Array, keep: int, *,
                            memory_space=pltpu.VMEM)
     out_specs = [seg_out, seg_out] + ([blk] if want_ef else [])
     out_shape = [
-        compat.shape_dtype_struct((nseg, _LANES), jnp.float32, vma=vma),
-        compat.shape_dtype_struct((nseg, _LANES), jnp.int32, vma=vma),
-    ] + ([compat.shape_dtype_struct(x2d.shape, jnp.float32, vma=vma)]
+        jax.ShapeDtypeStruct((nseg, _LANES), jnp.float32, vma=vma),
+        jax.ShapeDtypeStruct((nseg, _LANES), jnp.int32, vma=vma),
+    ] + ([jax.ShapeDtypeStruct(x2d.shape, jnp.float32, vma=vma)]
          if want_ef else [])
     outs = pl.pallas_call(
         functools.partial(_seg_pack_kernel, n, int(keep), want_ef),
@@ -1201,9 +1195,9 @@ def fused_select_pack(flat: Array, t: Array, keep: int, *,
         ],
         out_specs=[blk, blk, seg_out],
         out_shape=[
-            compat.shape_dtype_struct(x2d.shape, flat.dtype, vma=vma),
-            compat.shape_dtype_struct(x2d.shape, jnp.int32, vma=vma),
-            compat.shape_dtype_struct((nseg, _LANES), jnp.int32, vma=vma),
+            jax.ShapeDtypeStruct(x2d.shape, flat.dtype, vma=vma),
+            jax.ShapeDtypeStruct(x2d.shape, jnp.int32, vma=vma),
+            jax.ShapeDtypeStruct((nseg, _LANES), jnp.int32, vma=vma),
         ],
         interpret=interpret,
     )(jnp.asarray(t).reshape(1, 1).astype(jnp.float32), x2d)
@@ -1267,11 +1261,11 @@ def _run_quant(kernel, out_dtype, flat: Array, inv_scale: Array, seed: Array,
             pl.BlockSpec((_ROWS, _LANES), lambda i: (i, 0), memory_space=pltpu.VMEM),
         ],
         out_specs=pl.BlockSpec((_ROWS, _LANES), lambda i: (i, 0), memory_space=pltpu.VMEM),
-        out_shape=compat.shape_dtype_struct(x2d.shape, out_dtype, vma=_vma(flat)),
+        out_shape=jax.ShapeDtypeStruct(x2d.shape, out_dtype, vma=_vma(flat)),
         # TPU-semantics interpreter: the stock HLO interpreter has no
         # prng_seed/prng_random_bits (NB: its PRNG is a zero stub — dither
         # u == 0 under interpretation; see tests/test_kernels.py)
-        interpret=compat.pallas_interpret_params() if interpret else False,
+        interpret=pltpu.InterpretParams() if interpret else False,
     )(
         seed.reshape(1, 1).astype(jnp.int32),
         inv_scale.reshape(1, 1).astype(jnp.float32),
@@ -1292,7 +1286,7 @@ def qsgd_quantize(flat: Array, key: Array, *, qstates: int = 255,
     dither drawn from the TPU hardware PRNG seeded off ``key``.
     """
     if interpret is None:
-        interpret = _auto_interpret() and compat.HAS_TPU_INTERPRET
+        interpret = _auto_interpret()
     norm = jnp.linalg.norm(flat.astype(jnp.float32))
     inv = jnp.where(norm > 0, 1.0 / jnp.where(norm > 0, norm, 1.0), 0.0)
     levels = _run_quant(
@@ -1308,7 +1302,7 @@ def terngrad_quantize(flat: Array, key: Array, *,
     """Fused TernGrad levels: ``(int8 levels in {-1,0,1}, fp32 scale)``
     (`core.py:200-206`), dither from the TPU hardware PRNG."""
     if interpret is None:
-        interpret = _auto_interpret() and compat.HAS_TPU_INTERPRET
+        interpret = _auto_interpret()
     gmax = jnp.max(jnp.abs(flat.astype(jnp.float32)))
     inv = jnp.where(gmax > 0, 1.0 / jnp.where(gmax > 0, gmax, 1.0), 0.0)
     levels = _run_quant(
@@ -1322,7 +1316,7 @@ def terngrad_quantize_prescaled(scaled: Array, key: Array, *,
     """TernGrad levels for an already chunk-normalised input (``|x| <= 1``,
     unit scale) — the chunked-scale path's quantisation pass."""
     if interpret is None:
-        interpret = _auto_interpret() and compat.HAS_TPU_INTERPRET
+        interpret = _auto_interpret()
     return _run_quant(
         _terngrad_kernel, jnp.int8, scaled,
         jnp.asarray(1.0, jnp.float32), _seed_from_key(key), interpret,
@@ -1330,14 +1324,8 @@ def terngrad_quantize_prescaled(scaled: Array, key: Array, *,
 
 
 def use_quant_kernels(n: int) -> bool:
-    """Whether the fused quantizer kernels should serve this tensor.
-
-    Forced off-TPU the PRNG kernels need the TPU-semantics interpreter
-    (the stock HLO interpreter's PRNG is a zero stub) — without it the
-    jnp paths serve instead of crashing the lowering."""
-    if not _dispatch_to_pallas(n):
-        return False
-    return not _auto_interpret() or compat.HAS_TPU_INTERPRET
+    """Whether the fused quantizer kernels should serve this tensor."""
+    return _dispatch_to_pallas(n)
 
 
 # ---------------------------------------------------------------------------
@@ -1347,15 +1335,15 @@ def use_quant_kernels(n: int) -> bool:
 # The quantizer kernels above emit integer LEVELS; XLA then runs
 # `wire.pack_ternary` / `wire.pack_bits` as separate shift/sum passes over
 # the levels before anything hits the wire.  These kernels emit the wire
-# BYTES directly.  Bit-packing on the VPU has no sub-word shuffles: packing
-# is one matmul against a 0/1-weighted selector — codes [R, 128] times
-# packmat [128, 128/g] where column l//g carries weight base^(l%g) — and a
-# row-major reshape of the [R, 128/g] byte panel back to 128-lane rows.
-# Operands are small exact integers (codes <= 2, weights <= 128, bytes <=
-# 255 < 2^24), so even the MXU's bf16 default precision is exact, like the
-# 0/1 count matmuls in the pack kernels.  Byte order matches the XLA
-# packers bitwise: byte j of the flat output packs elements g*j .. g*j+g-1
-# little-endian, which is exactly row-major order of the reshaped panel.
+# BYTES directly.  Bit-packing on the VPU has no sub-word shuffles, so
+# packing is two matmuls (`_bytepack`): the first weighs each lane by its
+# bit position and sums every ``g`` consecutive lanes into one byte, the
+# second stacks ``g`` consecutive rows' bytes side by side into one
+# 128-lane byte row.  Operands are small exact integers (codes <= 2,
+# weights <= 128, bytes <= 255 < 2^8), so even the MXU's bf16 default
+# precision is exact, like the 0/1 count matmuls in the pack kernels.  Byte
+# order matches the XLA packers bitwise: byte j of the flat output packs
+# elements g*j .. g*j+g-1 little-endian.
 
 # 256-row element blocks: ternary bytes come out [64, 128] and sign-bitmap
 # bytes [32, 128] — both at or above the uint8 (32, 128) min tile
@@ -1363,18 +1351,31 @@ _QPACK_ROWS = 256
 
 
 def _bytepack(v: Array, g: int) -> Array:
-    """[R, 128] f32 small-int codes -> [R * 128 // (g * 128), 128] f32 bytes
-    packing ``g`` consecutive lanes per byte, little-endian (weight
-    ``(2^(8/g))^(l%g)`` at column ``l//g``)."""
+    """[R, 128] f32 small-int codes -> [R // g, 128] f32 bytes packing ``g``
+    consecutive lanes per byte, little-endian; flat order == wire order.
+
+    Row ``r`` yields ``128 / g`` bytes, and ``g`` consecutive rows fill one
+    output row.  Every array keeps the full 128-lane minor dimension
+    (Mosaic has no ``[R, 128/g] -> [R/g, 128]`` shape cast): the packing
+    matrix writes the row's bytes into all ``g`` lane blocks at once, a
+    mask keeps lane block ``r % g``, and a 0/1 grouping matmul sums each
+    ``g`` rows, whose kept blocks are disjoint."""
     rows = v.shape[0]
-    cols = _LANES // g
-    li = jax.lax.broadcasted_iota(jnp.int32, (_LANES, cols), 0)
-    ci = jax.lax.broadcasted_iota(jnp.int32, (_LANES, cols), 1)
-    pm = jnp.where(li // g == ci, (1 << ((li % g) * (8 // g))), 0
+    cols = _LANES // g                  # bytes per element row
+    lg, lc = g.bit_length() - 1, cols.bit_length() - 1    # both powers of two
+    src = jax.lax.broadcasted_iota(jnp.int32, (_LANES, _LANES), 0)
+    dst = jax.lax.broadcasted_iota(jnp.int32, (_LANES, _LANES), 1)
+    weight = jnp.left_shift(1, (src & (g - 1)) * (8 // g))
+    pm = jnp.where((src >> lg) == (dst & (cols - 1)), weight, 0
                    ).astype(jnp.float32)
-    b = jax.lax.dot(v, pm, preferred_element_type=jnp.float32)  # [R, cols]
-    # row-major reshape to full 128-lane byte rows; flat order == wire order
-    return b.reshape(rows * cols // _LANES, _LANES)
+    y = jax.lax.dot(v, pm, preferred_element_type=jnp.float32)     # [R, 128]
+    r = jax.lax.broadcasted_iota(jnp.int32, (rows, _LANES), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, _LANES), 1)
+    z = jnp.where((lane >> lc) == (r & (g - 1)), y, 0.0)
+    out_r = jax.lax.broadcasted_iota(jnp.int32, (rows // g, rows), 0)
+    in_r = jax.lax.broadcasted_iota(jnp.int32, (rows // g, rows), 1)
+    group = ((in_r >> lg) == out_r).astype(jnp.float32)
+    return jax.lax.dot(group, z, preferred_element_type=jnp.float32)
 
 
 def _pack2b_kernel(levels_ref, out_ref):
@@ -1403,7 +1404,7 @@ def _pack_bytes_call(kernel, levels: Array, out_divs, out_dtypes,
                                memory_space=pltpu.VMEM)],
         out_specs=[pl.BlockSpec((_QPACK_ROWS // d, _LANES), lambda i, d=d: (i, 0),
                                 memory_space=pltpu.VMEM) for d in out_divs],
-        out_shape=[compat.shape_dtype_struct((x2d.shape[0] // d, _LANES), dt,
+        out_shape=[jax.ShapeDtypeStruct((x2d.shape[0] // d, _LANES), dt,
                                              vma=vma)
                    for d, dt in zip(out_divs, out_dtypes)],
         interpret=interpret,
@@ -1475,11 +1476,11 @@ def _run_quant_pack(kernel, flat: Array, inv_scale: Array, seed: Array,
         ],
         out_specs=[pl.BlockSpec((_QPACK_ROWS // d, _LANES), lambda i, d=d: (i, 0),
                                 memory_space=pltpu.VMEM) for d in out_divs],
-        out_shape=[compat.shape_dtype_struct((x2d.shape[0] // d, _LANES),
+        out_shape=[jax.ShapeDtypeStruct((x2d.shape[0] // d, _LANES),
                                              jnp.uint8, vma=vma)
                    for d in out_divs],
         # hardware PRNG — TPU-semantics interpreter required off-TPU
-        interpret=compat.pallas_interpret_params() if interpret else False,
+        interpret=pltpu.InterpretParams() if interpret else False,
     )(
         seed.reshape(1, 1).astype(jnp.int32),
         inv_scale.reshape(1, 1).astype(jnp.float32),
@@ -1496,7 +1497,7 @@ def terngrad_pack(flat: Array, key: Array, *,
     as :func:`terngrad_quantize` (unbiased, not bitwise with `jax.random`);
     chunk padding packs as code 1 exactly like the XLA packer's zero-pad."""
     if interpret is None:
-        interpret = _auto_interpret() and compat.HAS_TPU_INTERPRET
+        interpret = _auto_interpret()
     gmax = jnp.max(jnp.abs(flat.astype(jnp.float32)))
     inv = jnp.where(gmax > 0, 1.0 / jnp.where(gmax > 0, gmax, 1.0), 0.0)
     (packed,) = _run_quant_pack(
@@ -1510,7 +1511,7 @@ def terngrad_pack_prescaled(scaled: Array, key: Array, *,
     """Quantize+pack for an already chunk-normalised input (``|x| <= 1``) —
     the chunked-scale TernGrad path's fused second pass."""
     if interpret is None:
-        interpret = _auto_interpret() and compat.HAS_TPU_INTERPRET
+        interpret = _auto_interpret()
     (packed,) = _run_quant_pack(
         _terngrad_pack_kernel, scaled, jnp.asarray(1.0, jnp.float32),
         _seed_from_key(key), (4,), interpret)
@@ -1527,7 +1528,7 @@ def qsgd_pack(flat: Array, key: Array, *, qstates: int = 255,
     if not 0 < qstates <= 255:
         raise ValueError(f"qsgd_pack packs uint8 magnitudes; qstates={qstates}")
     if interpret is None:
-        interpret = _auto_interpret() and compat.HAS_TPU_INTERPRET
+        interpret = _auto_interpret()
     norm = jnp.linalg.norm(flat.astype(jnp.float32))
     inv = jnp.where(norm > 0, 1.0 / jnp.where(norm > 0, norm, 1.0), 0.0)
     mags, signs = _run_quant_pack(
@@ -1539,12 +1540,8 @@ def qsgd_pack(flat: Array, key: Array, *, qstates: int = 255,
 
 
 def use_quant_pack(n: int) -> bool:
-    """Whether the fused quantize+pack kernels should serve this tensor.
-    Off-TPU (including ``force``) they need the TPU-semantics interpreter —
-    the stock interpreter's PRNG stub would silently zero the dither."""
-    if not _dispatch_to_pallas(n):
-        return False
-    return jax.default_backend() == "tpu" or compat.HAS_TPU_INTERPRET
+    """Whether the fused quantize+pack kernels should serve this tensor."""
+    return _dispatch_to_pallas(n)
 
 
 # ---------------------------------------------------------------------------
@@ -1571,9 +1568,9 @@ def _uniform_pallas(seed: Array, n: int, interpret: bool = False) -> Array:
         in_specs=[pl.BlockSpec((1, 1), lambda i: (0, 0), memory_space=pltpu.SMEM)],
         out_specs=pl.BlockSpec((_UNIFORM_ROWS, _LANES), lambda i: (i, 0),
                                memory_space=pltpu.VMEM),
-        out_shape=compat.shape_dtype_struct((padded_n // _LANES, _LANES), jnp.float32,
+        out_shape=jax.ShapeDtypeStruct((padded_n // _LANES, _LANES), jnp.float32,
                                        vma=_vma(seed)),
-        interpret=compat.pallas_interpret_params() if interpret else False,
+        interpret=pltpu.InterpretParams() if interpret else False,
     )(seed.reshape(1, 1).astype(jnp.int32))
     return out.reshape(-1)[:n]
 
@@ -1599,40 +1596,62 @@ def uniform(key: Array, n: int) -> Array:
 # Because the indices are ascending, each destination's accepted elements
 # are a CONTIGUOUS window [starts[w], starts[w] + min(count, cap)) of the
 # payload — so the scatter is really W windowed copies.  The kernel grids
-# over destinations, DMAs each window from HBM at its dynamic start offset,
-# masks the tail, and writes full bucket rows: zero value / `shard_n` guard
-# index on empty slots, identical bit-for-bit to the scatter build, and
-# rows stay monotone (window order = payload order), preserving the
-# owner-side sorted-scatter hints.
+# over destinations, DMAs each window from HBM, masks the tail, and writes
+# full bucket rows: zero value / `shard_n` guard index on empty slots,
+# identical bit-for-bit to the scatter build, and rows stay monotone (window
+# order = payload order), preserving the owner-side sorted-scatter hints.
+#
+# Mosaic addresses HBM at row granularity, and a window starts at any
+# element: the payload is viewed as [rows, 128], the DMA fetches the rows
+# that cover the window, and the window's offset inside its first row is
+# taken out in VMEM by one dynamic lane rotation plus a one-row shift.
 
-# per-destination window bound: 2 value+index scratch windows of cap_p
-# elements must sit in VMEM alongside the output block
+# per-destination window bound: value + index windows of cap_p elements
+# must sit in VMEM alongside the output block
 _ROUTE_MAX_CAPP = 1 << 15
+# window rows are a whole number of (16, 128) tiles, so the output block
+# satisfies the bf16 tiling as well as the 32-bit one
+_ROUTE_ROW_ALIGN = 16
 
 
-def _bucket_route_kernel(cap: int, cap_p: int, shard_n: int,
+def _route_rows(cap: int) -> int:
+    return -(-cap // (_ROUTE_ROW_ALIGN * _LANES)) * _ROUTE_ROW_ALIGN
+
+
+def _bucket_route_kernel(cap: int, r2: int, shard_n: int,
                          starts_ref, counts_ref, vals_ref, idx_ref,
-                         bv_ref, bi_ref, scratch_v, scratch_i, sem_v, sem_i):
+                         bv_ref, bi_ref, win_v, win_i, sem_v, sem_i):
     w = pl.program_id(0)
     start = starts_ref[w]
     cnt = jnp.minimum(counts_ref[w], cap)
-    # dynamic element-offset DMA: the payload is padded by cap_p so the last
-    # destination's window read stays in bounds whatever its start
-    cv = pltpu.make_async_copy(vals_ref.at[pl.ds(start, cap_p)], scratch_v,
+    first_row = start >> 7                         # start // 128, start >= 0
+    off = start & (_LANES - 1)
+    rows = win_v.shape[0]                          # r2 + 8: one spill row, tile-aligned
+    # the payload is padded so the last destination's rows stay in bounds
+    cv = pltpu.make_async_copy(vals_ref.at[pl.ds(first_row, rows), :], win_v,
                                sem_v)
-    ci = pltpu.make_async_copy(idx_ref.at[pl.ds(start, cap_p)], scratch_i,
+    ci = pltpu.make_async_copy(idx_ref.at[pl.ds(first_row, rows), :], win_i,
                                sem_i)
     cv.start()
     ci.start()
     cv.wait()
     ci.wait()
-    r2 = cap_p // _LANES
-    v = scratch_v[:].reshape(r2, _LANES)
-    ix = scratch_i[:].reshape(r2, _LANES)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, _LANES), 1)
+    shift = (_LANES - off) & (_LANES - 1)
+
+    def window(a):
+        # out[r, l] = payload[start + r * 128 + l]: lanes below 128 - off
+        # come from row r, the rest from row r + 1
+        here = pltpu.roll(a, shift, 1)
+        below = pltpu.roll(here, rows - 1, 0)
+        return jnp.where(lane < _LANES - off, here, below)[:r2]
+
+    v = window(win_v[:])
+    ix = window(win_i[:])
     pos = (jax.lax.broadcasted_iota(jnp.int32, (r2, _LANES), 0) * _LANES
            + jax.lax.broadcasted_iota(jnp.int32, (r2, _LANES), 1))
     take = pos < cnt
-    bv_ref[:] = jnp.where(take, v, jnp.zeros((), v.dtype))
+    bv_ref[:] = jnp.where(take, v, 0.0).astype(bv_ref.dtype)
     # bucket-local index; empty slots carry the shard_n guard row the owner
     # reduce scatters into
     bi_ref[:] = jnp.where(take, ix - w * shard_n, shard_n)
@@ -1649,25 +1668,33 @@ def fused_bucket_route(vals: Array, idx: Array, dest: Array, world: int,
     k = vals.shape[0]
     if interpret is None:
         interpret = _auto_interpret()
-    cap_p = -(-cap // _LANES) * _LANES
-    r2 = cap_p // _LANES
+    r2 = _route_rows(cap)
+    cap_p = r2 * _LANES
+    win_rows = r2 + 8
     # per-destination totals and exclusive starts (tiny: W+1 buckets); the
     # dump bucket keeps invalid tail slots out of every window
     counts_all = jnp.zeros((world + 1,), jnp.int32).at[dest].add(
         1, indices_are_sorted=True, mode="promise_in_bounds")
     starts = (jnp.cumsum(counts_all) - counts_all)[:world].astype(jnp.int32)
     counts = counts_all[:world]
-    vpad = jnp.concatenate([vals, jnp.zeros((cap_p,), vals.dtype)])
-    ipad = jnp.concatenate([idx, jnp.zeros((cap_p,), jnp.int32)])
+    # 32-bit windows (sub-32-bit rows pack in pairs and cannot start at an
+    # odd row); bf16 -> f32 -> bf16 is exact
+    pay_rows = k // _LANES + win_rows
+    pad = pay_rows * _LANES - k
+    vpad = jnp.concatenate([vals.astype(jnp.float32),
+                            jnp.zeros((pad,), jnp.float32)]
+                           ).reshape(pay_rows, _LANES)
+    ipad = jnp.concatenate([idx, jnp.zeros((pad,), jnp.int32)]
+                           ).reshape(pay_rows, _LANES)
     vma = _vma(vals)
     outs = pl.pallas_call(
-        functools.partial(_bucket_route_kernel, int(cap), cap_p, int(shard_n)),
+        functools.partial(_bucket_route_kernel, int(cap), r2, int(shard_n)),
         grid=(world,),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
             pl.BlockSpec((r2, _LANES), lambda i: (i, 0),
@@ -1676,12 +1703,12 @@ def fused_bucket_route(vals: Array, idx: Array, dest: Array, world: int,
                          memory_space=pltpu.VMEM),
         ],
         out_shape=[
-            compat.shape_dtype_struct((world * r2, _LANES), vals.dtype, vma=vma),
-            compat.shape_dtype_struct((world * r2, _LANES), jnp.int32, vma=vma),
+            jax.ShapeDtypeStruct((world * r2, _LANES), vals.dtype, vma=vma),
+            jax.ShapeDtypeStruct((world * r2, _LANES), jnp.int32, vma=vma),
         ],
         scratch_shapes=[
-            pltpu.VMEM((cap_p,), vals.dtype),
-            pltpu.VMEM((cap_p,), jnp.int32),
+            pltpu.VMEM((win_rows, _LANES), jnp.float32),
+            pltpu.VMEM((win_rows, _LANES), jnp.int32),
             pltpu.SemaphoreType.DMA,
             pltpu.SemaphoreType.DMA,
         ],
@@ -1696,6 +1723,5 @@ def use_bucket_route(k: int, world: int, cap: int) -> bool:
     """Whether the sharded route phase should take the fused window kernel.
     Element-granular payloads only (the blocky Block-Top-K row layout keeps
     the XLA scatter); the window bound keeps both scratch copies in VMEM."""
-    cap_p = -(-cap // _LANES) * _LANES
     return (_dispatch_to_pallas(k) and k <= _INT32_MAX and world >= 2
-            and cap_p <= _ROUTE_MAX_CAPP)
+            and _route_rows(cap) * _LANES <= _ROUTE_MAX_CAPP)

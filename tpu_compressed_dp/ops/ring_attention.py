@@ -59,12 +59,7 @@ def use_fused_attention(q_shape, k_shape, itemsize: int = 2) -> bool:
     (:mod:`tpu_compressed_dp.ops.flash_attention`): TPU backend, seq a lane
     multiple, head_dim MXU-friendly, K/V small enough to stream through
     VMEM whole."""
-    if not _FUSED_ATTN:
-        return False
-    try:
-        if jax.default_backend() != "tpu":
-            return False
-    except RuntimeError:  # pragma: no cover - backend not initialised
+    if not _FUSED_ATTN or jax.default_backend() != "tpu":
         return False
     b, h, t, d = q_shape
     d_pad = d + (-d) % 128

@@ -86,6 +86,12 @@ from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+# The gathered payload is identical on every worker; the *_invariant
+# variant carries that fact in the type so shard_map's replication
+# checker accepts replicated out_specs downstream (plain all_gather
+# keeps the device-varying tag).
+from jax._src.lax.parallel import all_gather_invariant as _all_gather
+from jax._src.lax.parallel import psum_p as _psum_p
 
 from tpu_compressed_dp.ops import compressors
 
@@ -97,15 +103,6 @@ __all__ = ["make_wire_grad_sync", "WIRE_METHODS", "pack_ternary",
 
 WIRE_METHODS = ("randomk", "topk", "blocktopk", "terngrad", "qsgd",
                 "thresholdv", "adaptive_threshold")
-
-try:
-    # The gathered payload is identical on every worker; the *_invariant
-    # variant carries that fact in the type so shard_map's replication
-    # checker accepts replicated out_specs downstream (plain all_gather
-    # keeps the device-varying tag).
-    from jax._src.lax.parallel import all_gather_invariant as _all_gather
-except ImportError:  # pragma: no cover - older/newer jax layouts
-    _all_gather = jax.lax.all_gather
 
 
 def pack_ternary(levels: Array) -> Array:
@@ -267,9 +264,8 @@ def packed_indices_from_mask(mask: Array, keep: int) -> Array:
 
     The per-rank stage is TWO gathers per rank (round 5; was three + an
     fp32 tri-matmul): per-rank costs are billed per random ACCESS, and the
-    round-5 bisect (tools/wire_profile.py --subs and the scratch bisect in
-    benchmarks/wire_wall_r5.txt) measured ~7 ms per [keep]-sized gather at
-    keep=1.25M — so gathering ``row_ends`` and ``row_counts`` separately
+    round-5 bisect (tools/wire_profile.py --subs) measured ~7 ms per
+    [keep]-sized gather at keep=1.25M — so gathering ``row_ends`` and ``row_counts`` separately
     just to subtract them was a wasted 8 ms: one precomputed ``row_starts``
     array halves that stage.  The in-row prefix matmul runs in bf16 (row
     prefix counts are <= 128, exactly representable), halving the gathered
@@ -467,7 +463,7 @@ def _blocktopk_small_bs(flat: Array, bidx: Array, block_size: int,
     A ``[nb, block_size]`` view pads every row to the 128-lane register
     width, so gathering/scattering ``block_size``-wide rows at bs=8 wastes
     16x the memory machinery (measured 36 ms of "rest" at the 125M/1%
-    config, benchmarks/wire_wall_r5.txt).  Instead keep the natural
+    config, round 5).  Instead keep the natural
     ``[m, 128]`` layout and touch only full cache-line rows:
 
       * payload gather: fetch each selected block's COVERING 128-lane row
@@ -586,6 +582,29 @@ def _shard_plan(cfg, n_units: int, keep: int, world: int, unit_size: int):
         cfg.shard_route_factor, cfg.shard_return_factor)
 
 
+def _group_psum(x: Array, axis_name, groups, *, same_everywhere: bool
+                ) -> Array:
+    """Sum ``x`` inside each group of ``axis_index_groups``.
+
+    ``jax.lax.psum`` types its result as invariant over the axis and so
+    refuses groups under shard_map's replication check (a group sum differs
+    from group to group).  The all-reduce primitive itself lowers groups to
+    replica groups; bind it directly and state the result's type here:
+    varying, unless the caller knows every group arrives at the same sum.
+    """
+    axes = tuple(axis_name) if isinstance(axis_name, (tuple, list)) else (
+        axis_name,)
+    varies = jax.typeof(x).vma
+    out = _psum_p.bind(x, axes=axes,
+                       axis_index_groups=tuple(tuple(g) for g in groups))
+    # the primitive's type rule forgets every axis; put back the ones the
+    # sum still varies over (model axes always, the summed axes unless all
+    # groups agree)
+    if same_everywhere:
+        varies = varies - set(axes)
+    return jax.lax.pcast(out, tuple(sorted(varies)), to="varying") if varies else out
+
+
 def _hier_combine(contrib: Array, keep: int, axis_name: str, world, cfg):
     """Two-level (ICI x DCN) exchange of one group's compressed-dense
     contribution (``transport='hierarchical'``).
@@ -636,8 +655,8 @@ def _hier_combine(contrib: Array, keep: int, axis_name: str, world, cfg):
 
     with obs_trace.phase("ici_reduce"):
         if C > 1:
-            pod_sum = jax.lax.psum(contrib, axis_name,
-                                   axis_index_groups=ici_groups)
+            pod_sum = _group_psum(contrib, axis_name, ici_groups,
+                                  same_everywhere=False)
             bits_ici = _payload_bits(contrib)
         else:
             pod_sum = contrib
@@ -670,14 +689,18 @@ def _hier_combine(contrib: Array, keep: int, axis_name: str, world, cfg):
         s_valid = jax.lax.dynamic_slice_in_dim(uvalid, c_rank * slab, slab)
 
     dense_u, sent, route_bits, ret_bits, dcn_overflow = (
-        wire_sharded.sharded_combine(s_vals, s_idx, plan.dcn, axis_name,
-                                     valid=s_valid,
-                                     axis_index_groups=dcn_groups))
+        wire_sharded.sharded_combine(
+            s_vals, s_idx, plan.dcn, axis_name, valid=s_valid,
+            # one chip a pod: the column is the whole axis, and the ungrouped
+            # gather types its result as the same on every worker
+            axis_index_groups=dcn_groups if C > 1 else None))
     partial = dense_u[:n]
     with obs_trace.phase("ici_reduce"):
         if C > 1:
-            total = jax.lax.psum(partial, axis_name,
-                                 axis_index_groups=ici_groups)
+            # chip c of every pod holds the same inter-pod sum of slab c,
+            # so every pod adds up the same slabs
+            total = _group_psum(partial, axis_name, ici_groups,
+                                same_everywhere=True)
             bits_ici += _payload_bits(partial)
         else:
             total = partial

@@ -217,9 +217,12 @@ def merge_stat_dicts(a: Dict[str, Any], b: Dict[str, Any]) -> Dict[str, Any]:
     ``guard/nonfinite``) combine with their registry-declared reduction,
     and survive when EITHER side reports them — a slice of diagnostic-free
     groups must not silence the other slice's divergence signal."""
+    # sorted: a set of strings iterates in hash order, which changes from
+    # process to process, and the order these adds are traced in is part of
+    # the program the persistent compile cache keys on
     merged = {
         k: a.get(k, 0.0) + b.get(k, 0.0)
-        for k in (set(a) | set(b)) - set(_DIAG_STATS)
+        for k in sorted((set(a) | set(b)) - set(_DIAG_STATS))
     }
     for k, (_, combine) in _DIAG_STATS.items():
         vals = [c[k] for c in (a, b) if k in c]

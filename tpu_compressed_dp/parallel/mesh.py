@@ -19,6 +19,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = [
     "distributed_init",
+    "setup_compile_cache",
     "make_data_mesh",
     "make_mesh",
     "data_sharding",
@@ -45,21 +46,6 @@ def distributed_init(
     (``--master_address``, ``--world_size``, ``--rank``, `dawn.py:11-13`).
     No-ops when running single-process.
     """
-    # Eagerly-registered PJRT plugins force their platform into the config at
-    # interpreter start and ignore JAX_PLATFORMS set later by a parent
-    # process.  For processes WE spawned (the local launcher's rendezvous
-    # marker is present), re-assert the launcher's platform choice through
-    # the config — effective until first backend use.  Never touch the
-    # platform otherwise: the ambient environment may carry the plugin's own
-    # JAX_PLATFORMS, and clobbering an explicit user config with it would
-    # break CPU-forced test processes.
-    if "TPU_CDP_COORDINATOR" in os.environ:
-        want = os.environ.get("JAX_PLATFORMS")
-        if want:
-            try:
-                jax.config.update("jax_platforms", want)
-            except Exception:
-                pass
     if num_processes is not None and num_processes <= 1:
         return
     if coordinator_address is None and num_processes is None and "COORDINATOR_ADDRESS" not in os.environ:
@@ -90,6 +76,26 @@ def force_host_devices(n: int, env: Optional[dict] = None) -> dict:
     flags.append(f"--xla_force_host_platform_device_count={n}")
     env["XLA_FLAGS"] = " ".join(flags)
     return env
+
+
+def setup_compile_cache() -> str:
+    """Place JAX's persistent compilation cache; every entry point calls
+    this first, before anything compiles.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it itself and no
+    cache option is set in code.  Otherwise the cache lives at
+    ``<checkout>/.jax_cache``, derived from the package location: the
+    directory is part of the cache key, so it is never a temporary, pid- or
+    time-derived name.  Returns the directory in use.
+    """
+    from_env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if from_env:
+        return from_env
+    checkout = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    path = os.path.join(checkout, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def make_data_mesh(num_devices: Optional[int] = None, devices: Optional[Sequence[jax.Device]] = None) -> Mesh:

@@ -938,8 +938,8 @@ class ElasticRuntime:
 
     # -- multi-process world transitions ---------------------------------
     # These paths only run under jax.process_count() > 1 with a rendezvous
-    # armed; they are exercised by the HAS_CPU_MULTIPROCESS-gated 2-process
-    # drills (tests/test_elastic_multiprocess.py).  The pure pieces (rank ->
+    # armed; they are exercised by the 2-process drills
+    # (tests/test_elastic_multiprocess.py).  The pure pieces (rank ->
     # row maps, local-shard gathers) are unit tested single-process.
 
     def _proc_data_rows(self, ranks: Iterable[int]) -> List[int]:

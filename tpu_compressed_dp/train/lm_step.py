@@ -32,8 +32,6 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from tpu_compressed_dp import compat
-from tpu_compressed_dp.compat import shard_map
 
 from tpu_compressed_dp.models.transformer import (
     LlamaConfig,
@@ -249,7 +247,7 @@ def make_lm_train_step(
             return (xent + cfg.moe_aux_weight * aux) * ls_scale, xent
 
         varying = jax.tree.map(
-            lambda p: compat.pcast(p, sync_axes, to="varying"), state.params
+            lambda p: jax.lax.pcast(p, sync_axes, to="varying"), state.params
         )
         with obs_trace.phase("grad"):
             (_, loss), grads = jax.value_and_grad(
@@ -309,7 +307,7 @@ def make_lm_train_step(
 
     state_spec = lm_state_specs(cfg, comp_cfg)
     data_spec = P("data", "seq")
-    sharded = shard_map(
+    sharded = jax.shard_map(
         local_step,
         mesh=mesh,
         in_specs=(state_spec, data_spec, data_spec),
@@ -351,7 +349,7 @@ def make_lm_eval_step(cfg: LlamaConfig, mesh: Mesh):
         }
 
     pspecs = param_specs(cfg)
-    sharded = shard_map(
+    sharded = jax.shard_map(
         local_eval, mesh=mesh,
         in_specs=(pspecs, P("data", "seq"), P("data", "seq")),
         out_specs=P(),

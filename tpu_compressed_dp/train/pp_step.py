@@ -42,8 +42,6 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from tpu_compressed_dp import compat
-from tpu_compressed_dp.compat import shard_map
 
 from tpu_compressed_dp.models.transformer import (
     LlamaConfig,
@@ -334,13 +332,13 @@ def make_pp_train_step(
                 inject = (stage == 0) & (t < M)
                 x_t = xs[jnp.clip(t, 0, M - 1)]
                 emb = params["embed"].astype(dt)[x_t]
-                emb = compat.pcast(emb, ("pipe",), to="varying")
+                emb = jax.lax.pcast(emb, ("pipe",), to="varying")
                 h_in = jnp.where(inject, emb, h_cur)
                 h_out = stage_apply(h_in)
                 h_next = jax.lax.ppermute(h_out, "pipe", perm)
                 return h_next, h_out
 
-            h0 = compat.pcast(jnp.zeros((mb, t_len, cfg.dim), dt),
+            h0 = jax.lax.pcast(jnp.zeros((mb, t_len, cfg.dim), dt),
                                sync_axes + ("pipe",), to="varying")
             _, h_ticks = jax.lax.scan(tick, h0, jnp.arange(M + stages - 1))
             # The final-norm + LM-head + loss are DEFERRED past the loop
@@ -362,12 +360,12 @@ def make_pp_train_step(
                 m_s = M // stages
                 my_h = jax.lax.dynamic_slice_in_dim(emitted, stage * m_s, m_s)
                 my_y = jax.lax.dynamic_slice_in_dim(
-                    compat.pcast(ys, ("pipe",), to="varying"),
+                    jax.lax.pcast(ys, ("pipe",), to="varying"),
                     stage * m_s, m_s)
                 scale = 1.0 / stages
             else:  # uneven split: every stage heads the full drained set
                 m_s, my_h, scale = M, emitted, 1.0 / stages
-                my_y = compat.pcast(ys, ("pipe",), to="varying")
+                my_y = jax.lax.pcast(ys, ("pipe",), to="varying")
             hn = _rms_norm(my_h.reshape(m_s * mb, t_len, cfg.dim),
                            params["final_norm"], cfg.norm_eps)
             if use_fused_head_xent(m_s * mb * t_len, cfg.vocab_size // tp,
@@ -386,7 +384,7 @@ def make_pp_train_step(
             return loss * ls_scale
 
         varying = jax.tree.map(
-            lambda p: compat.pcast(p, sync_axes, to="varying"), state.params
+            lambda p: jax.lax.pcast(p, sync_axes, to="varying"), state.params
         )
         with obs_trace.phase("grad"):
             loss, grads = jax.value_and_grad(loss_fn)(varying)
@@ -446,7 +444,7 @@ def make_pp_train_step(
 
     state_spec = pp_state_specs(cfg, comp_cfg, tensor=tp > 1, seq=sp > 1)
     data_spec = P("data", "seq") if sp > 1 else P("data")
-    sharded = shard_map(
+    sharded = jax.shard_map(
         local_step,
         mesh=mesh,
         in_specs=(state_spec, data_spec, data_spec),

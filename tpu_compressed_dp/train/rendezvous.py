@@ -34,8 +34,8 @@ world's listener.
 
 Everything here is plain files + injectable clocks: the protocol is unit
 tested single-process and deterministic (tier-1); the 2-process drills
-that exercise it against a real ``jax.distributed`` runtime are gated on
-``HAS_CPU_MULTIPROCESS`` in the slow tier.
+that exercise it against a real ``jax.distributed`` runtime are in the
+slow tier.
 """
 
 from __future__ import annotations
@@ -400,7 +400,7 @@ def reinit_distributed(decision: EpochDecision, *,
     dead coordinator), then ``initialize`` against the re-elected
     coordinator with this process's new contiguous id.  Injectable for the
     single-process unit tests; the real wiring is exercised by the
-    ``HAS_CPU_MULTIPROCESS``-gated drills."""
+    2-process drills (tests/test_elastic_multiprocess.py)."""
     import jax
 
     if decision.process_id is None:

@@ -31,8 +31,6 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from tpu_compressed_dp import compat
-from tpu_compressed_dp.compat import shard_map
 
 from tpu_compressed_dp.obs import trace as obs_trace
 from tpu_compressed_dp.parallel.dp import CompressionConfig, make_grad_sync
@@ -275,7 +273,7 @@ def make_train_step(
         step=P(), params=P(), batch_stats=P(), opt_state=P(), ef=P(axis_name),
         rng=P(), comp=P(axis_name), guard=P(), control=P(),
     )
-    sharded = shard_map(
+    sharded = jax.shard_map(
         local_step,
         mesh=mesh,
         in_specs=(state_spec, P(axis_name), P(axis_name)),
@@ -313,7 +311,7 @@ def make_train_step(
 def _to_varying(x: Array, axis_name: str) -> Array:
     """Mark a replicated value as device-varying (identity on the forward pass,
     blocks the automatic psum on the backward pass)."""
-    return compat.pcast(x, axis_name, to="varying")
+    return jax.lax.pcast(x, axis_name, to="varying")
 
 
 def optimizer_lr(optimizer: SGD, step: Array) -> Array:
@@ -351,7 +349,7 @@ def make_eval_step(apply_fn: ApplyFn, mesh: Mesh, *, axis_name: str = "data"):
         step=P(), params=P(), batch_stats=P(), opt_state=P(), ef=P(axis_name),
         rng=P(), comp=P(axis_name), guard=P(), control=P(),
     )
-    sharded = shard_map(
+    sharded = jax.shard_map(
         local_eval,
         mesh=mesh,
         in_specs=(state_spec, P(axis_name), P(axis_name), P(axis_name)),
