@@ -4,12 +4,17 @@ Prometheus export, watchdog, loggers, meters, profiler wiring."""
 import itertools
 import json
 import os
+import subprocess
+import sys
+import threading
+import time
 
 import numpy as np
 import pytest
 
 from tpu_compressed_dp.obs import export as obs_export
 from tpu_compressed_dp.obs import registry as obs_registry
+from tpu_compressed_dp.obs import trace as obs_trace
 from tpu_compressed_dp.obs.trace import StepTimeline
 from tpu_compressed_dp.utils import meters
 from tpu_compressed_dp.utils.loggers import FileLogger, NoOp, TensorboardLogger
@@ -149,89 +154,288 @@ class TestRegistryConformance:
         assert obs_registry.undeclared(step_keys) == []
 
 
+MS = 1_000_000      # the timeline's clock counts nanoseconds
+
+
+class FakeClock:
+    """Nanosecond clock the test sets; the watcher thread reads it too,
+    at the time the output it waited on was scripted to be done."""
+    t = 0
+    stamped = 0
+
+    def __call__(self):
+        return self.t
+
+
+class FakeOutput:
+    """A step output whose readiness the test releases: the watcher's
+    ``block_until_ready`` returns only then, at the scripted time."""
+
+    def __init__(self, clock, done_at=None, fails=False):
+        self.clock, self.done_at, self.fails = clock, done_at, fails
+        self.ready = threading.Event()
+
+    def release(self):
+        self.ready.set()
+        return self
+
+    def block_until_ready(self):
+        assert self.ready.wait(10), "the test never released this output"
+        if self.fails:
+            raise RuntimeError("the step failed on the device")
+        if self.done_at is not None:      # stamps never run backwards
+            self.clock.t = self.clock.stamped = max(self.clock.stamped,
+                                                    self.done_at)
+
+
+def _step(tl, clk, data, copy, dispatch, output):
+    """One scripted step: the three spans of the given lengths (ms)."""
+    for name, ms in (("data_wait", data), ("to_device", copy),
+                     ("dispatch", dispatch)):
+        with tl.span(name):
+            clk.t += ms * MS
+    tl.step_done({"loss": output})
+
+
+def _scripted(capacity=8):
+    """The schedule the stamp tests share, in ms: step 0 opens the call on
+    a drained device (enqueued 16, done 116); step 1 is enqueued at 20 with
+    the queue full (done 216); step 2 waits 280 on its data, is enqueued at
+    306, 90 after the device ran dry, and is done at 406."""
+    clk = FakeClock()
+    tl = StepTimeline(capacity=capacity, clock=clk)
+    tl.begin_call()
+    outs = [FakeOutput(clk, 116 * MS), FakeOutput(clk, 216 * MS),
+            FakeOutput(clk, 406 * MS)]
+    _step(tl, clk, 10, 1, 5, outs[0])
+    _step(tl, clk, 1, 1, 2, outs[1])
+    _step(tl, clk, 280, 1, 5, outs[2])
+    return tl, clk, outs
+
+
 @pytest.mark.quick
 class TestStepTimeline:
-    def _clock(self):
-        class C:
-            t = 0.0
+    @pytest.mark.parametrize("step,device,starved,by,under", [
+        # the first step: the pipeline was drained, idle from the call's begin
+        (0, 100, 16, {"data_wait": 10, "to_device": 1, "dispatch": 5,
+                      "other": 0}, "data_wait"),
+        # a full queue: enqueued long before the previous step was done
+        (1, 100, 0, None, None),
+        # starved under data_wait: 84 of the 90 ms fall in next()
+        (2, 100, 90, {"data_wait": 84, "to_device": 1, "dispatch": 5,
+                      "other": 0}, "data_wait"),
+    ])
+    def test_stamps_give_device_starved_and_cover(self, step, device,
+                                                  starved, by, under):
+        tl, clk, outs = _scripted()
+        for out in outs:
+            out.release()
+        assert tl.flush(10)
+        rec = tl.calls()[0]["records"][step]
+        assert rec["ord"] == step and rec["first"] == (step == 0)
+        assert rec["device"] == device * MS
+        assert rec["starved"] == starved * MS
+        assert rec["starved_by"] == (
+            by and {k: v * MS for k, v in by.items()})
+        ev = tl.drain()[step]
+        assert ev["starved_in"] == under
+        assert ev["device"] == pytest.approx(device / 1e3)
+        assert ev["starved"] == pytest.approx(starved / 1e3)
 
-            def __call__(self):
-                return self.t
+    def test_stamps_arrive_in_dispatch_order(self):
+        """Released last-first, the outputs are still stamped first-last:
+        the watcher waits on them in the order they were dispatched."""
+        tl, clk, outs = _scripted()
+        outs[2].release()
+        outs[1].release()
+        assert not tl.flush(0.05)       # step 0 holds the queue
+        assert all(r["done"] is None for r in tl.calls()[0]["records"])
+        outs[0].release()
+        assert tl.flush(10)
+        done = [r["done"] for r in tl.calls()[0]["records"]]
+        assert done == [116 * MS, 216 * MS, 406 * MS]
 
-        return C()
-
-    def test_splits_and_percentiles(self):
-        clk = self._clock()
-        tl = StepTimeline(capacity=8, clock=clk, sync=lambda: None)
-        for i in range(4):
-            clk.t += 0.25          # data wait
-            tl.batch_ready()
-            clk.t += 0.75          # dispatch
-            tl.step_dispatched()
-        p = tl.percentiles()
-        assert p["p50"] == pytest.approx(1.0)
-        assert p["p95"] == pytest.approx(1.0)
-        assert tl.data_wait_frac() == pytest.approx(0.25)
-        assert tl.steps_per_sec() == pytest.approx(1.0)
+    def test_snapshot_percentiles_from_completion_intervals(self):
+        tl, clk, outs = _scripted()
+        for out in outs:
+            out.release()
+        assert tl.flush(10)
+        # completion intervals 116 (from the call's begin), 100, 190; the
+        # host's enqueue intervals were 16, 4 and 286
+        assert sorted(tl.step_intervals()) == pytest.approx([.100, .116, .190])
         snap = tl.snapshot()
-        assert snap["time/step_p95_ms"] == pytest.approx(1000.0)
-        assert snap["time/data_wait_frac"] == pytest.approx(0.25)
+        assert snap["time/step_p50_ms"] == pytest.approx(116.0)
+        assert snap["time/step_p95_ms"] == pytest.approx(190.0)
+        assert snap["time/host_data_wait_frac"] == pytest.approx(291 / 306)
+        assert snap["time/device_starved_frac"] == pytest.approx(106 / 406)
+        assert snap["time/steps_per_sec"] == pytest.approx(3 / .306)
+
+    def test_step_without_stamp_falls_back_to_host_interval(self):
+        """A step whose output failed on the device keeps done=None; it
+        and the step after it (no completion to measure from) report the
+        host's enqueue interval, and the watcher goes on."""
+        clk = FakeClock()
+        tl = StepTimeline(capacity=8, clock=clk)
+        tl.begin_call()
+        outs = [FakeOutput(clk, 50 * MS), FakeOutput(clk, fails=True),
+                FakeOutput(clk, 90 * MS), FakeOutput(clk, 120 * MS)]
+        for out in outs:
+            _step(tl, clk, 1, 1, 2, out.release())
+        assert tl.flush(10)
+        recs = tl.calls()[0]["records"]
+        assert [r["done"] for r in recs] == [50 * MS, None, 90 * MS, 120 * MS]
+        assert recs[2]["starved"] is None and recs[2]["device"] is None
+        assert recs[3]["device"] == 30 * MS
+        assert tl.step_intervals() == pytest.approx([.050, .004, .004, .030])
 
     def test_ring_bounds_memory_and_drain(self):
-        clk = self._clock()
-        tl = StepTimeline(capacity=4, clock=clk, sync=lambda: None)
+        clk = FakeClock()
+        tl = StepTimeline(capacity=4, clock=clk)
         for _ in range(10):
-            clk.t += 1.0
-            tl.batch_ready()
-            clk.t += 1.0
-            tl.step_dispatched()
+            _step(tl, clk, 1, 0, 1, 0.0)    # a plain value is ready at once
+        assert tl.flush(10)
         assert len(tl.records) == 4      # ring: most recent only
         assert tl.steps == 10
+        call = tl.calls()[-1]
+        assert call["steps"] == 10 and len(call["records"]) == 4
         drained = tl.drain()
         assert len(drained) <= 4         # pending is capacity-bounded too
         assert tl.drain() == []          # drained once
-        assert {"t0", "data", "dispatch", "total"} <= set(drained[0])
+        assert {"t0", "data", "to_device", "dispatch", "total", "done",
+                "device", "starved", "starved_in", "ord", "call"} \
+            == set(drained[0])
+        assert [d["ord"] for d in drained] == [6, 7, 8, 9]
 
     def test_resume_excludes_between_step_work(self):
         """Blocking between-step work (eval, checkpoint saves, a log-window
-        device_get drain) must not be billed as the next step's data wait."""
-        clk = self._clock()
-        tl = StepTimeline(capacity=8, clock=clk, sync=lambda: None)
-        clk.t += 0.1
-        tl.batch_ready()
-        clk.t += 0.9
-        tl.step_dispatched()
-        clk.t += 100.0          # epoch-end eval + checkpoint
+        device_get drain) must not be billed to the next step."""
+        clk = FakeClock()
+        tl = StepTimeline(capacity=8, clock=clk)
+        tl.begin_call()
+        outs = [FakeOutput(clk, 1500 * MS), FakeOutput(clk, 102_500 * MS)]
+        _step(tl, clk, 100, 0, 900, outs[0])
+        clk.t += 100_000 * MS    # epoch-end eval + checkpoint
         tl.resume()
-        clk.t += 0.1
-        tl.batch_ready()
-        clk.t += 0.9
-        tl.step_dispatched()
-        recs = list(tl.records)
-        assert recs[1]["data"] == pytest.approx(0.1)
-        assert recs[1]["total"] == pytest.approx(1.0)
-        assert tl.data_wait_frac() == pytest.approx(0.1)
+        _step(tl, clk, 100, 0, 900, outs[1])
+        outs[0].release()
+        outs[1].release()
+        assert tl.flush(10)
+        evs = tl.drain()
+        assert evs[1]["data"] == pytest.approx(0.1)
+        assert evs[1]["total"] == pytest.approx(1.0)
+        # a segment opener: starved since the resume mark, not since step 0
+        assert evs[1]["starved"] == pytest.approx(1.0)
+        snap = tl.snapshot()
+        assert snap["time/host_data_wait_frac"] == pytest.approx(0.1)
+        assert snap["time/step_p95_ms"] == pytest.approx(1500.0)
 
-    def test_device_sync_sampling(self):
-        clk = self._clock()
-        synced = []
+    def test_snapshot_keys_declared(self):
+        tl, clk, outs = _scripted()
+        assert obs_registry.undeclared(tl.snapshot()) == []
+        assert {"time/host_data_wait_frac", "time/device_starved_frac"} \
+            <= set(tl.snapshot())
+        assert not obs_registry.is_declared("time/data_wait_frac")
+        for out in outs:
+            out.release()
 
-        def sync():
-            synced.append(clk.t)
-            clk.t += 0.5     # the drain the sample measures
+    def test_run_train_epoch_records_into_process_timeline(self, monkeypatch):
+        """With no timeline passed the loop's four spans, their step
+        ordinals and the stamps land in the process-wide timeline, grouped
+        by call, and every span is a profiler annotation."""
+        from tpu_compressed_dp.harness.loop import run_train_epoch
 
-        tl = StepTimeline(capacity=8, device_sync_every=2, clock=clk,
-                          sync=sync)
-        for _ in range(4):
-            clk.t += 0.1
-            tl.batch_ready()
-            clk.t += 0.1
-            tl.step_dispatched()
-        assert len(synced) == 2          # steps 2 and 4
-        recs = list(tl.records)
-        assert "device" not in recs[0] and "device" in recs[1]
-        assert recs[1]["device"] == pytest.approx(0.5)
-        assert recs[1]["total"] == pytest.approx(0.7)
+        clk = FakeClock()
+        tl = StepTimeline(capacity=obs_trace.PROCESS_CAPACITY, clock=clk)
+        monkeypatch.setattr(obs_trace, "_PROCESS_TIMELINE", tl)
+        seen = []
+        real_span = obs_trace.host_span
+
+        def host_span(name, **meta):
+            seen.append((name, meta.get("step")))
+            return real_span(name, **meta)
+
+        monkeypatch.setattr(obs_trace, "host_span", host_span)
+
+        def batches(n):
+            for _ in range(n):
+                clk.t += 3 * MS
+                yield {"input": np.zeros((2,), np.float32)}
+
+        def train_step(state, batch):
+            clk.t += 2 * MS
+            return state + 1, {"loss": 1.0, "count": 2.0}
+
+        state, acc = run_train_epoch(train_step, 0, batches(3))
+        state, acc = run_train_epoch(train_step, state, batches(2))
+        assert state == 5 and acc.steps == 2
+        assert obs_trace.process_timeline() is tl
+        calls = tl.calls()
+        assert [c["call"] for c in calls] == [0, 1]
+        assert [c["steps"] for c in calls] == [3, 2]
+        assert [r["ord"] for c in calls for r in c["records"]] == [0, 1, 2, 3, 4]
+        for c in calls:
+            assert c["fetch"] is not None and c["t1"] >= c["fetch"][1]
+            assert [r["first"] for r in c["records"]] == \
+                [True] + [False] * (c["steps"] - 1)
+            for r in c["records"]:
+                assert r["data_wait"][1] - r["data_wait"][0] == 3 * MS
+                assert r["dispatch"][1] - r["dispatch"][0] == 2 * MS
+                assert r["data_wait"][1] <= r["to_device"][0] \
+                    <= r["to_device"][1] <= r["dispatch"][0]
+                assert r["done"] is not None    # whole when the call returns
+        # annotations: the three spans of every step under its ordinal (the
+        # exhausted iterator's last next() opens one more data_wait), and
+        # the fetch once a call
+        for ordinal in range(5):
+            for name in ("loop.data_wait", "loop.to_device", "loop.dispatch"):
+                assert (name, ordinal) in seen
+        assert [n for n, _ in seen].count("loop.fetch") == 2
+
+    def test_raising_step_leaves_no_stamp_and_flush_is_bounded(self):
+        from tpu_compressed_dp.harness.loop import run_train_epoch
+
+        clk = FakeClock()
+        tl = StepTimeline(capacity=8, clock=clk)
+        stuck = FakeOutput(clk)             # never released
+
+        def train_step(state, batch):
+            if state == 2:
+                raise RuntimeError("boom")
+            return state + 1, {"loss": stuck if state == 1 else 0.0}
+
+        with pytest.raises(RuntimeError, match="boom") as info:
+            run_train_epoch(train_step, 0,
+                            iter([{"input": np.zeros(2)}] * 4), timeline=tl)
+        assert info.value.elastic_state == 2
+        recs = tl.calls()[0]["records"]
+        assert len(recs) == 3 and recs[2]["dispatch"] is not None
+        t0 = time.monotonic()
+        assert not tl.flush(0.05)           # bounded: step 1 never completes
+        assert time.monotonic() - t0 < 5
+        assert [r["done"] is None for r in tl.calls()[0]["records"]] == \
+            [False, True, True]
+        assert tl._watcher.daemon           # the exit does not wait for it
+        stuck.release()
+        assert tl.flush(10)
+        assert tl.calls()[0]["records"][2]["done"] is None   # it raised
+
+    def test_interpreter_exits_with_a_stamp_outstanding(self):
+        """A process whose watcher still waits on an output that never
+        becomes ready exits when its main thread does."""
+        code = (
+            "import threading\n"
+            "from tpu_compressed_dp.obs.trace import StepTimeline\n"
+            "class Never:\n"
+            "    def block_until_ready(self): threading.Event().wait()\n"
+            "tl = StepTimeline()\n"
+            "with tl.span('dispatch'): pass\n"
+            "tl.step_done({'loss': Never()})\n"
+            "print('flushed', tl.flush(0.05))\n")
+        out = subprocess.run([sys.executable, "-c", code], timeout=120,
+                             capture_output=True, text=True,
+                             env={**os.environ, "JAX_PLATFORMS": "cpu"})
+        assert out.returncode == 0, out.stderr
+        assert "flushed False" in out.stdout
 
 
 @pytest.mark.quick
@@ -293,20 +497,17 @@ class TestEventStreamAndPrometheus:
         assert "skipme" not in body
 
     def test_telemetry_snapshot(self):
-        clk_t = [0.0]
-
-        class TL(StepTimeline):
-            pass
-
-        tl = StepTimeline(clock=lambda: clk_t[0], sync=lambda: None)
-        clk_t[0] = 1.0
-        tl.batch_ready()
-        clk_t[0] = 2.0
-        tl.step_dispatched()
+        clk = FakeClock()
+        tl = StepTimeline(clock=clk)
+        _step(tl, clk, 1000, 0, 1000, FakeOutput(clk, 2500 * MS).release())
+        assert tl.flush(10)
         snap = obs_export.telemetry_snapshot(tl, step=7, last_good_step=5)
         assert snap["step"] == 7 and snap["last_good_step"] == 5
         assert snap["steps_per_sec"] == pytest.approx(0.5)
-        assert snap["step_p95_ms"] == pytest.approx(2000.0)
+        # the step was done 0.5 s after its enqueue: the stamp says 2.5 s
+        assert snap["step_p95_ms"] == pytest.approx(2500.0)
+        assert snap["device_starved_frac"] == pytest.approx(2.0 / 2.5)
+        assert "data_wait_frac" not in snap
 
 
 @pytest.mark.quick
@@ -701,9 +902,13 @@ class TestTraceReport:
     def _events(self, tmp_path):
         p = str(tmp_path / "ev.jsonl")
         with obs_export.EventStream(p, meta={"harness": "dawn"}) as es:
-            spans = [{"t0": 10.0 + i, "data": 0.2, "dispatch": 0.8,
-                      "total": 1.0} for i in range(4)]
-            spans[1]["device"] = 0.5
+            spans = [{"t0": 10.0 + i, "data": 0.2, "to_device": 0.1,
+                      "dispatch": 0.7, "total": 1.0, "done": None,
+                      "device": None, "starved": None, "starved_in": None}
+                     for i in range(4)]
+            # one step carries its completion stamp
+            spans[1].update(done=12.0, device=0.5, starved=0.3,
+                            starved_in="data_wait")
             es.emit("epoch", epoch=1, step=4,
                     metrics={"train loss": 2.0, "comm MB/s": 3.25},
                     throughput={"throughput/examples_per_sec": 512.0,
@@ -720,15 +925,25 @@ class TestTraceReport:
         bd = tr.phase_breakdown(events)
         assert bd["data"]["mean_ms"] == pytest.approx(200.0)
         assert bd["data"]["share"] == pytest.approx(0.2)
+        assert bd["to_device"]["share"] == pytest.approx(0.1)
+        # device and starved: over the one step that has a stamp
         assert bd["device"]["mean_ms"] == pytest.approx(500.0)
+        assert bd["starved"]["share"] == pytest.approx(0.3)
         rows = tr.throughput_rows(events)
         assert rows[0]["rate"] == 512.0 and rows[0]["mfu"] == 0.5
         report = tr.render_report(events)
         assert "per-phase step-time breakdown" in report
         assert "MFU" in report and "guard events: 1" in report
+        assert "device starved under: data_wait 300.00 ms" in report
         ch = tr.chrome_trace_events(events)
-        # 4 steps x (data + dispatch) + 1 device span
-        assert len(ch) == 9
+        # 4 steps x (data + to_device + dispatch) on the host's thread, and
+        # the stamped step's starved + device on the device's
+        assert len(ch) == 14
+        dev = [e for e in ch if e["tid"] == 1]
+        assert [e["name"] for e in dev] == ["starved", "device"]
+        # device ends at done (12.0 s, 2 s after the first t0)
+        assert dev[1]["ts"] + dev[1]["dur"] == pytest.approx(2.0e6)
+        assert dev[0]["ts"] + dev[0]["dur"] == pytest.approx(dev[1]["ts"])
         assert all(e["ph"] == "X" and e["dur"] > 0 for e in ch)
         out = str(tmp_path / "chrome.json")
         assert tr.main([self._events(tmp_path), "--chrome", out]) == 0

@@ -76,33 +76,62 @@ def merge_timeline(bundles: Dict[int, Dict[str, Any]]
     return merged
 
 
+#: the host loop's spans of a step record, in the order a step passes them
+HOST_PHASES = ("data", "to_device", "dispatch")
+
+
+def span_trace_events(spans: List[Dict[str, Any]], pid: int = 0,
+                      **args: Any) -> List[Dict[str, Any]]:
+    """Trace-event-format spans (``ph='X'``, microseconds) of one rank's
+    step records: the host loop's spans end to end from each step's
+    ``t0`` on thread 0, and on thread 1 what the stamps say of the device:
+    ``device`` ending at ``done``, ``starved`` just before it."""
+    spans = [s for s in spans if "t0" in s]
+    if not spans:
+        return []
+    t_base = min(s["t0"] for s in spans)
+    out = []
+    for i, s in enumerate(spans):
+        t = (s["t0"] - t_base) * 1e6
+        for ph in HOST_PHASES:
+            dur = s.get(ph)
+            if dur is None:
+                continue
+            out.append({"name": ph, "cat": "host", "ph": "X", "pid": pid,
+                        "tid": 0, "ts": t, "dur": dur * 1e6,
+                        "args": {"step_index": i, **args}})
+            t += dur * 1e6
+        if s.get("done") is None or s.get("device") is None:
+            continue
+        t = (s["done"] - s["device"] - t_base) * 1e6
+        starved = s.get("starved") or 0.0
+        for ph, ts, dur in (("starved", t - starved * 1e6, starved),
+                            ("device", t, s["device"])):
+            if dur > 0:
+                out.append({"name": ph, "cat": "device", "ph": "X",
+                            "pid": pid, "tid": 1, "ts": ts, "dur": dur * 1e6,
+                            "args": {"step_index": i, **args}})
+    return out
+
+
 def rank_lane_events(spans_by_rank: Dict[int, List[Dict[str, Any]]]
                      ) -> List[Dict[str, Any]]:
     """chrome://tracing trace events with one PROCESS LANE PER RANK
-    (``pid=rank``) from per-rank step-span lists (the ``step_spans`` a
-    harness event stream carries, ``t0`` included).  Reused by
-    ``tools/trace_report.py --merge``.  Spans are aligned on each rank's
-    earliest ``t0`` — host clocks are per-process, so cross-rank offsets
-    show relative pacing (who lags inside a step), not absolute order."""
+    (``pid=rank``) from per-rank step-record lists (the ``step_spans`` a
+    harness event stream carries, ``t0`` included): the host loop's
+    ``data`` / ``to_device`` / ``dispatch`` on thread 0 and, where a step
+    carries its completion stamp ``done``, ``device`` and ``starved`` on
+    thread 1.  Reused by ``tools/trace_report.py --merge``.  Spans are
+    aligned on each rank's earliest ``t0`` — host clocks are per-process,
+    so cross-rank offsets show relative pacing (who lags inside a step),
+    not absolute order."""
     out: List[Dict[str, Any]] = []
     for rank in sorted(spans_by_rank):
-        spans = [s for s in spans_by_rank[rank] if "t0" in s]
-        if not spans:
-            continue
-        out.append({"name": "process_name", "ph": "M", "pid": rank,
-                    "args": {"name": f"rank {rank}"}})
-        t_base = min(s["t0"] for s in spans)
-        for i, s in enumerate(spans):
-            t = (s["t0"] - t_base) * 1e6
-            for ph in ("data", "dispatch", "device"):
-                dur = s.get(ph)
-                if dur is None:
-                    continue
-                out.append({"name": ph, "cat": "host", "ph": "X",
-                            "pid": rank, "tid": 0, "ts": t,
-                            "dur": dur * 1e6,
-                            "args": {"step_index": i, "rank": rank}})
-                t += dur * 1e6
+        lane = span_trace_events(spans_by_rank[rank], pid=rank, rank=rank)
+        if lane:
+            out.append({"name": "process_name", "ph": "M", "pid": rank,
+                        "args": {"name": f"rank {rank}"}})
+            out.extend(lane)
     return out
 
 

@@ -5,9 +5,10 @@ Renders, from the records the harnesses emit through
 :mod:`tpu_compressed_dp.obs.export`:
 
   * a **per-phase step-time breakdown** — mean/p50/p95 of the host
-    timeline's data-wait / dispatch / (sampled) device-drain splits, and
-    the data-wait fraction — the "where does a step's wall time go"
-    table the paper's thesis needs;
+    loop's data-wait / to-device / dispatch spans and, from each step's
+    completion stamp, the device's time on the step and the time it sat
+    starved (nothing queued), with the span it starved under — the "where
+    does a step's wall time go" table the paper's thesis needs;
   * a **throughput trajectory** — per epoch / log window: examples|tokens
     per second, MFU, per-chip comm MB/s, loss;
   * optionally (``--chrome out.json``) a **chrome://tracing /
@@ -37,7 +38,16 @@ from typing import Any, Dict, List, Optional
 from tpu_compressed_dp.obs.export import SCHEMA_VERSION, read_all_events
 from tpu_compressed_dp.obs.trace import percentile
 
+try:
+    from tools.postmortem import (HOST_PHASES, rank_lane_events,
+                                  span_trace_events)
+except ImportError:  # script mode: sys.path[0] is tools/
+    from postmortem import HOST_PHASES, rank_lane_events, span_trace_events
+
 WINDOW_KINDS = ("epoch", "step")  # records that carry metrics + timeline
+#: a step record's durations, in report order: the host loop's spans, what
+#: the completion stamp says of the device, the host's enqueue interval
+PHASES = HOST_PHASES + ("device", "starved", "total")
 
 
 def check_schema(events: List[Dict[str, Any]]) -> None:
@@ -60,14 +70,16 @@ def step_spans(events: List[Dict[str, Any]]) -> List[Dict[str, float]]:
 
 def phase_breakdown(events: List[Dict[str, Any]]) -> Dict[str, Dict[str, float]]:
     """``{phase: {mean_ms, p50_ms, p95_ms, share}}`` over every step span
-    in the stream.  ``share`` is the phase's fraction of step wall time —
-    computed against the SAME steps the phase was measured on, so the
-    sampled ``device`` split (``device_sync_every > 0`` records it only
-    every Nth step) is not diluted by the unsampled steps' totals."""
+    in the stream.  ``share`` is the phase's fraction of the host loop's
+    step time (``total``, enqueue to enqueue) — computed against the SAME
+    steps the phase was measured on, so ``device`` and ``starved``, which
+    a step without a completion stamp lacks, are not diluted by those
+    steps' totals.  Under async dispatch the device works while the host
+    loops, so ``device``'s share can pass 1."""
     spans = step_spans(events)
     out: Dict[str, Dict[str, float]] = {}
-    for ph in ("data", "dispatch", "device", "total"):
-        have = [s for s in spans if s.get(ph) is not None and ph in s]
+    for ph in PHASES:
+        have = [s for s in spans if s.get(ph) is not None]
         if not have:
             continue
         vals = sorted(s[ph] for s in have)
@@ -106,24 +118,9 @@ def throughput_rows(events: List[Dict[str, Any]]) -> List[Dict[str, Any]]:
 
 
 def chrome_trace_events(events: List[Dict[str, Any]]) -> List[Dict[str, Any]]:
-    """Trace-event-format spans (``ph='X'``, microseconds) of the host
-    timeline — load in chrome://tracing or ui.perfetto.dev."""
-    spans = step_spans(events)
-    if not spans:
-        return []
-    t_base = min(s["t0"] for s in spans)
-    out = []
-    for i, s in enumerate(spans):
-        t = (s["t0"] - t_base) * 1e6
-        for ph in ("data", "dispatch", "device"):
-            dur = s.get(ph)
-            if dur is None:
-                continue
-            out.append({"name": ph, "cat": "host", "ph": "X", "pid": 0,
-                        "tid": 0, "ts": t, "dur": dur * 1e6,
-                        "args": {"step_index": i}})
-            t += dur * 1e6
-    return out
+    """The stream's step records as trace events — load in
+    chrome://tracing or ui.perfetto.dev."""
+    return span_trace_events(step_spans(events))
 
 
 def _fmt(v: Optional[float], spec: str = "10.2f") -> str:
@@ -140,10 +137,11 @@ def render_report(events: List[Dict[str, Any]]) -> str:
 
     bd = phase_breakdown(events)
     lines.append("")
-    lines.append("per-phase step-time breakdown (host timeline):")
+    lines.append("per-phase step-time breakdown (host spans; device and "
+                 "starved from completion stamps):")
     lines.append(f"  {'phase':<10}{'mean ms':>10}{'p50 ms':>10}"
                  f"{'p95 ms':>10}{'share':>8}")
-    for ph in ("data", "dispatch", "device", "total"):
+    for ph in PHASES:
         if ph not in bd:
             continue
         r = bd[ph]
@@ -152,6 +150,14 @@ def render_report(events: List[Dict[str, Any]]) -> str:
                      f"{r['p95_ms']:>10.2f}{share:>8}")
     if not bd:
         lines.append("  (no step spans in stream)")
+    under: Dict[str, float] = {}
+    for s in step_spans(events):
+        if s.get("starved") and s.get("starved_in"):
+            under[s["starved_in"]] = under.get(s["starved_in"], 0.0) + s["starved"]
+    if under:
+        lines.append("  device starved under: " + ", ".join(
+            f"{name} {sec * 1e3:.2f} ms"
+            for name, sec in sorted(under.items(), key=lambda kv: -kv[1])))
 
     lines.append("")
     lines.append("throughput trajectory:")
@@ -177,7 +183,7 @@ def render_schedule(path: str) -> str:
     ``tools/overlap_evidence.py`` (``benchmarks/overlap_hlo_r8.txt``)
     alongside the host report: which ``tcdp.chunk<ii>`` collective sits
     where in the compiled schedule, and how much model compute remains to
-    hide it — the overlap, directly.  The host timeline cannot see device
+    hide it — the overlap, directly.  The step records cannot see device
     phases; the AOT schedule artifact is the device-side view."""
     lines = ["", f"compiled-schedule overlap ({path}):"]
     try:
@@ -215,10 +221,6 @@ def main(argv: Optional[List[str]] = None) -> int:
     if args.merge:
         if not args.chrome:
             p.error("--merge requires --chrome OUT.json")
-        try:
-            from tools.postmortem import rank_lane_events
-        except ImportError:  # script mode: sys.path[0] is tools/
-            from postmortem import rank_lane_events
         spans_by_rank: Dict[int, List[Dict[str, Any]]] = {}
         for rank, path in enumerate(args.events):
             evs = read_all_events(path)

@@ -437,6 +437,7 @@ def run(args) -> Dict[str, float]:
         rows = args.global_batch        # post-remesh: largest dp-divisible cut
         warm_until = start + 1          # compile-reset horizon (moves on remesh)
         step_i = start
+        timeline.begin_call()           # the whole loop is one call
         while step_i < args.steps:
             try:
                 if prof_window is not None and step_i == prof_window[0]:
@@ -449,13 +450,15 @@ def run(args) -> Dict[str, float]:
                 preempt.check(step_i)
                 if el is not None:
                     el.poll(step_i)
-                batch = ds.batch(step_i)
-                if rows != args.global_batch:
-                    batch = {k: v[:rows] for k, v in batch.items()}
-                timeline.batch_ready()
-                state, metrics = train_step(
-                    state, {k: jnp.asarray(v) for k, v in batch.items()})
-                timeline.step_dispatched()
+                with timeline.span("data_wait"):
+                    batch = ds.batch(step_i)
+                    if rows != args.global_batch:
+                        batch = {k: v[:rows] for k, v in batch.items()}
+                with timeline.span("to_device"):
+                    batch = {k: jnp.asarray(v) for k, v in batch.items()}
+                with timeline.span("dispatch"):
+                    state, metrics = train_step(state, batch)
+                timeline.step_done(metrics)
                 if crash is not None:
                     # the mid-collective plane: the step's collectives are
                     # already in flight when this one fires
@@ -473,8 +476,12 @@ def run(args) -> Dict[str, float]:
                     timed_from = step_i + 1
                     timeline.resume()  # the compile drain is not data wait
                 if (step_i + 1) % args.log_every == 0 or step_i == args.steps - 1:
-                    m = (el.bounded_get(metrics, step=step_i + 1)
-                         if el is not None else jax.device_get(metrics))
+                    with timeline.span("fetch"):
+                        m = (el.bounded_get(metrics, step=step_i + 1)
+                             if el is not None else jax.device_get(metrics))
+                    # the fetch drained the device: the watcher is at most
+                    # a few stamps behind, and the window's spans go out whole
+                    timeline.flush()
                     # spans drain ONCE per window and fan out to every
                     # consumer; the flight rings fill BEFORE the wedge check
                     # so a GuardExceeded dump carries the streak history
@@ -635,6 +642,7 @@ def run(args) -> Dict[str, float]:
                         timed_from = step_i + 1
                         timeline.resume()
             except Exception as err:  # noqa: BLE001 - converted or re-raised
+                timeline.step_failed()
                 failure = el.failure_from(err) if el is not None else None
                 if failure is None:
                     if flight is not None and not isinstance(

@@ -9,6 +9,7 @@ globally-reduced metrics.
 from __future__ import annotations
 
 import contextlib
+import itertools
 import os
 from typing import Dict, Iterable, Optional, Tuple
 
@@ -16,6 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from tpu_compressed_dp.obs.trace import process_timeline
 from tpu_compressed_dp.train.state import TrainState
 from tpu_compressed_dp.utils.loggers import MetricAccumulator
 from tpu_compressed_dp.utils.timer import Timer
@@ -689,6 +691,9 @@ def pad_batch(batch: Dict[str, np.ndarray], size: int) -> Dict[str, np.ndarray]:
     return {"input": x, "target": y, "mask": mask}
 
 
+_EXHAUSTED = object()
+
+
 def run_train_epoch(train_step, state: TrainState, batches: Iterable[Dict],
                     *, crash=None, step_offset: int = 0, guard_cfg=None,
                     timeline=None, elastic=None, preempt=None, flight=None,
@@ -707,9 +712,12 @@ def run_train_epoch(train_step, state: TrainState, batches: Iterable[Dict],
     # serialize the pipeline; detection latency here is one epoch, and the
     # raise lands inside run_with_recovery's retry loop like any failure).
     #
-    # ``timeline`` (obs/trace.StepTimeline) splits each step's host time
-    # into input-pipeline wait (the `next()` inside the for statement) and
-    # dispatch; it never syncs the device unless configured to sample.
+    # ``timeline`` (obs/trace.StepTimeline) takes each step's host spans
+    # (the `next()` on the batch iterator, the host-to-device copy, the
+    # step's call) and hands one of the step's outputs to its watcher
+    # thread for the completion stamp; with none passed the spans go to the
+    # process-wide timeline, so every caller leaves them behind.  Neither
+    # the spans nor the stamps wait on the device in this thread.
     #
     # ``elastic`` (train/elastic.ElasticRuntime) adds the per-batch gossip
     # poll and the second crash check AFTER dispatch (phase
@@ -725,27 +733,34 @@ def run_train_epoch(train_step, state: TrainState, batches: Iterable[Dict],
     # the live state out for the emergency save.
     acc = MetricAccumulator()
     step_metrics = []
-    if timeline is not None:
-        # exclude whatever happened since the previous epoch's last dispatch
-        # (eval, checkpoint saves, loader swaps) from step 0's data wait
-        timeline.resume()
+    if timeline is None:
+        timeline = process_timeline()
+    # a new call: whatever happened since the previous epoch's last dispatch
+    # (eval, checkpoint saves, loader swaps) stays out of step 0
+    timeline.begin_call()
+    batches = iter(batches)
     try:
-        for i, batch in enumerate(batches):
-            if timeline is not None:
-                timeline.batch_ready()
+        for i in itertools.count():
+            with timeline.span("data_wait"):
+                batch = next(batches, _EXHAUSTED)
+            if batch is _EXHAUSTED:
+                break
             if crash is not None:
                 crash.check(step_offset + i)
             if preempt is not None:
                 preempt.check(step_offset + i)
             if elastic is not None:
                 elastic.poll(step_offset + i)
-            state, metrics = train_step(state, {k: jnp.asarray(v) for k, v in batch.items()})
+            with timeline.span("to_device"):
+                batch = {k: jnp.asarray(v) for k, v in batch.items()}
+            with timeline.span("dispatch"):
+                state, metrics = train_step(state, batch)
+            timeline.step_done(metrics)
             if crash is not None:
                 crash.check(step_offset + i, phase="mid_collective")
-            if timeline is not None:
-                timeline.step_dispatched()
             step_metrics.append(metrics)
     except Exception as err:
+        timeline.step_failed()
         # donation consumed the caller's pre-epoch buffers at step 0, so
         # the only live TrainState is this frame's local — ride it out on
         # the exception for the elastic remesh handler (steps dispatched
@@ -754,11 +769,13 @@ def run_train_epoch(train_step, state: TrainState, batches: Iterable[Dict],
         # re-runs on the surviving mesh)
         err.elastic_state = state
         raise
-    if elastic is not None:
-        fetched = elastic.bounded_get(step_metrics,
-                                      step=step_offset + len(step_metrics))
-    else:
-        fetched = jax.device_get(step_metrics)
+    with timeline.span("fetch"):
+        if elastic is not None:
+            fetched = elastic.bounded_get(step_metrics,
+                                          step=step_offset + len(step_metrics))
+        else:
+            fetched = jax.device_get(step_metrics)
+    timeline.end_call()
     for metrics in fetched:
         acc.update(metrics)
     if flight is not None:

@@ -6,8 +6,9 @@
   * :mod:`~tpu_compressed_dp.obs.trace` — phase-level step tracing:
     ``jax.named_scope`` phase annotations through both sync engines, the
     sharded wire path and all three step factories, plus the host-side
-    :class:`~tpu_compressed_dp.obs.trace.StepTimeline` ring buffer
-    (p50/p95/p99 step latency, data-wait fraction, step rate).
+    :class:`~tpu_compressed_dp.obs.trace.StepTimeline` ring buffer (the
+    loop's spans and a completion stamp per step: p50/p95/p99 of the
+    completion intervals, device-starved fraction, step rate).
   * :mod:`~tpu_compressed_dp.obs.export` — schema-versioned JSONL event
     stream, Prometheus textfile exporter, and the heartbeat telemetry
     snapshot consumed by ``tools/watchdog.py --check``.

@@ -208,7 +208,8 @@ def write_prometheus(metrics: Dict[str, float], path: str,
 def telemetry_snapshot(timeline=None, *, step: Optional[int] = None,
                        last_good_step: Optional[int] = None
                        ) -> Dict[str, float]:
-    """The heartbeat's health payload: step rate + p95 latency from the
+    """The heartbeat's health payload: step rate, p95 of the step
+    completion intervals and the device-starved fraction from the
     :class:`~tpu_compressed_dp.obs.trace.StepTimeline` window, plus the
     progress watermarks the watchdog's wedge check reads."""
     out: Dict[str, float] = {}
@@ -220,5 +221,5 @@ def telemetry_snapshot(timeline=None, *, step: Optional[int] = None,
         snap = timeline.snapshot()
         out["steps_per_sec"] = snap["time/steps_per_sec"]
         out["step_p95_ms"] = snap["time/step_p95_ms"]
-        out["data_wait_frac"] = snap["time/data_wait_frac"]
+        out["device_starved_frac"] = snap["time/device_starved_frac"]
     return out

@@ -62,7 +62,7 @@ FLIGHT_SCHEMA = 1
 #:   elastic  gossip / remesh / readmit transitions
 #:   ckpt     checkpoint lifecycle (save / rollback / prune)
 #:   chaos    armed fault-injection specs (what WAS configured to misfire)
-#:   timing   per-phase host spans drained from the StepTimeline
+#:   timing   per-step spans and device splits drained from the StepTimeline
 #:   fault    observed exceptions (the dump trigger trail)
 #:   stream   delta-stream lifecycle (keyframe / flush / warm rejoin)
 CHANNELS = ("step", "guard", "control", "elastic", "ckpt", "chaos",
@@ -201,11 +201,15 @@ class FlightRecorder:
             self.record("guard", "counters", step=int(step), metrics=guard)
 
     def note_spans(self, spans: List[Dict[str, float]]) -> None:
-        """Per-step host spans drained from the StepTimeline (data /
-        dispatch / total splits) — the straggler evidence."""
+        """Per-step records drained from the StepTimeline (data /
+        to_device / dispatch / total host splits; device, starved and the
+        span the device starved under, from the completion stamps) — the
+        straggler evidence.  The record's absolute times and ordinals stay
+        out: the ring has its own clock and sequence."""
         for span in spans:
             self.record("timing", "span",
-                        **{k: span[k] for k in span if k != "t0"})
+                        **{k: span[k] for k in span
+                           if k not in ("t0", "done", "ord", "call")})
 
     def note_chaos(self, cfg: Any) -> None:
         """The armed fault-injection scenario (a ChaosConfig, its spec

@@ -179,13 +179,22 @@ declare("guard/skip_rate", GAUGE, "ratio", "mean", "host",
         "vetoed-step fraction over the logging window "
         "(windowed mean of guard/nonfinite)")
 declare("time/step_p50_ms", TIMING, "ms", "mean", "host",
-        "median host-observed step latency over the timeline window")
+        "median step time over the timeline window: the interval between "
+        "consecutive steps' completion stamps (the host's enqueue interval "
+        "only for a step that got no stamp)")
 declare("time/step_p95_ms", TIMING, "ms", "mean", "host",
-        "p95 host-observed step latency")
+        "p95 step time: completion-stamp intervals, host enqueue interval "
+        "only where a step got no stamp")
 declare("time/step_p99_ms", TIMING, "ms", "mean", "host",
-        "p99 host-observed step latency")
-declare("time/data_wait_frac", GAUGE, "ratio", "mean", "host",
-        "fraction of step wall time spent waiting on the input pipeline")
+        "p99 step time: completion-stamp intervals, host enqueue interval "
+        "only where a step got no stamp")
+declare("time/host_data_wait_frac", GAUGE, "ratio", "mean", "host",
+        "fraction of the host loop's own time spent in next() on the "
+        "input pipeline; not the device's: see time/device_starved_frac")
+declare("time/device_starved_frac", GAUGE, "ratio", "mean", "host",
+        "fraction of the window's wall time in which the device had no "
+        "step queued: previous step's completion stamp to the next "
+        "step's enqueue")
 declare("time/steps_per_sec", GAUGE, "steps/s", "mean", "host",
         "host-observed step rate over the timeline window")
 

@@ -7,14 +7,20 @@ Two complementary views of where a step's time goes:
     ``jax.named_scope``, so XLA op names — and therefore xprof/tensorboard
     traces — attribute device time to named phases instead of a soup of
     fused ops.  Zero runtime cost: named scopes exist only at trace time.
-  * **Host view** — :class:`StepTimeline` is a ring buffer of per-step
-    host timings that JAX's async dispatch CAN honestly observe without
-    stalling the pipeline: input-pipeline wait and dispatch time every
-    step, plus an optional sampled device-drain measurement
-    (``device_sync_every``) that closes the async gap at a chosen cadence.
-    It yields p50/p95/p99 step latency, the data-wait fraction, and the
-    step rate — the numbers the heartbeat telemetry snapshot and the JSONL
-    event stream carry.
+  * **Host view** — :class:`StepTimeline` records, per step, the host
+    loop's spans (:data:`LOOP_SPANS`: input-pipeline wait, host-to-device
+    copy, dispatch; once per epoch call the closing fetch) and the time the
+    step's outputs became ready, stamped by a watcher thread so that
+    neither the compiled step nor the loop's thread waits on the device.
+    From the two it derives what the device did: its time on each step and
+    how long it sat with nothing queued (``starved``), attributed to the
+    span the host was in.  It yields p50/p95/p99 of the completion
+    intervals, the device-starved fraction, the host loop's data-wait
+    fraction and the step rate — the numbers the heartbeat telemetry
+    snapshot and the JSONL event stream carry.  Every span is also a
+    profiler annotation (:func:`host_span`), and the clock is the one a
+    captured trace's annotations are stamped with, so the records lay over
+    the device operations of an ``.xplane.pb``.
 
 This is the measurement layer the paper's thesis needs: compression claims
 are stated in bits, but they live or die on *seconds per phase*
@@ -24,13 +30,16 @@ are stated in bits, but they live or die on *seconds per phase*
 from __future__ import annotations
 
 import collections
+import queue
+import threading
 import time
-from typing import Callable, Dict, List, Optional
+import weakref
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 
-__all__ = ["PHASES", "phase", "chunk", "host_span", "StepTimeline",
-           "percentile"]
+__all__ = ["PHASES", "LOOP_SPANS", "phase", "chunk", "host_span",
+           "StepTimeline", "process_timeline", "percentile"]
 
 #: The phase taxonomy — every named scope the engines and step factories
 #: emit uses one of these (xprof filters on the ``tcdp.`` prefix):
@@ -69,12 +78,29 @@ def chunk(index: int):
     return jax.named_scope(f"tcdp.chunk{index:02d}")
 
 
-def host_span(name: str):
+#: The host loop's per-step spans, in the order a step passes through
+#: them; ``fetch`` (the epoch-closing metrics fetch) is once per call.
+LOOP_SPANS = ("data_wait", "to_device", "dispatch")
+
+#: Steps the process-wide timeline keeps: the last four epoch calls of up
+#: to 1,024 steps each.
+PROCESS_CAPACITY = 4 * 1024
+
+#: Bound of the wait for the watcher after an epoch's fetch.  The fetch has
+#: already drained the device, so the wait is the watcher's own few
+#: microseconds a step; the bound only keeps a lost device from hanging
+#: the loop.
+FLUSH_TIMEOUT_S = 2.0
+
+
+def host_span(name: str, **meta):
     """Host-side profiler annotation (``jax.profiler.TraceAnnotation``):
-    marks a wall-clock span on the host timeline of a captured trace —
-    for the parts of the loop that are NOT traced computation (input
-    pipeline, checkpoint saves)."""
-    return jax.profiler.TraceAnnotation(f"tcdp.{name}")
+    marks a wall-clock span ``tcdp.<name>`` on the host timeline of a
+    captured trace — for the parts of the loop that are NOT traced
+    computation (input pipeline, host-to-device copy, dispatch, fetch).
+    ``meta`` (``step=``) lands as the event's stats; it is encoded only
+    while a trace is being captured."""
+    return jax.profiler.TraceAnnotation(f"tcdp.{name}", **meta)
 
 
 def percentile(sorted_vals: List[float], q: float) -> float:
@@ -87,125 +113,370 @@ def percentile(sorted_vals: List[float], q: float) -> float:
     return sorted_vals[idx]
 
 
+def _overlap(a0: int, a1: int, span: Optional[Tuple[int, int]]) -> int:
+    if span is None:
+        return 0
+    return max(0, min(a1, span[1]) - max(a0, span[0]))
+
+
+def _seconds(span: Optional[Tuple[int, int]]) -> float:
+    return 0.0 if span is None else (span[1] - span[0]) / 1e9
+
+
+def _ns_to_s(ns: Optional[int]) -> Optional[float]:
+    return None if ns is None else ns / 1e9
+
+
+#: what the watcher gets in place of an output for a step that raised
+_NO_OUTPUT = object()
+
+
+def _watch(items: queue.SimpleQueue, clock: Callable[[], int],
+           lock: threading.Lock) -> None:
+    """The watcher thread: takes (record, one output of the step) in
+    dispatch order, waits for the output and stamps the record; an Event
+    in the queue is set when reached (:meth:`StepTimeline.flush`), None
+    ends the thread."""
+    prev_done: Optional[int] = None
+    while True:
+        item = items.get()
+        if item is None:
+            return
+        if isinstance(item, threading.Event):
+            item.set()
+            continue
+        rec, token = item
+        done = None
+        if token is not _NO_OUTPUT:
+            try:
+                wait = getattr(token, "block_until_ready", None)
+                if wait is not None:
+                    wait()           # releases the GIL while it waits
+                done = clock()
+            except Exception:        # noqa: BLE001 - the step failed on the
+                pass                 # device: the loop's fetch reports it
+        if done is not None:
+            with lock:
+                _stamp(rec, prev_done, done)
+        prev_done = done
+
+
+def _stamp(rec: Dict[str, Any], prev_done: Optional[int], done: int) -> None:
+    enq = rec["end"]
+    base = prev_done
+    if rec["first"]:                 # a drained pipeline: idle since t0
+        base = rec["t0"] if base is None else max(base, rec["t0"])
+    if base is not None:
+        starved = max(0, enq - base)
+        rec["starved"] = starved
+        rec["device"] = done - max(enq, base)
+        if starved:
+            by = {name: _overlap(base, enq, rec[name]) for name in LOOP_SPANS}
+            by["other"] = starved - sum(by.values())
+            rec["starved_by"] = by
+    rec["done"] = done
+
+
+class _Span:
+    """One timed span of the loop: a profiler annotation and, on the same
+    clock, its start and end in the timeline."""
+
+    __slots__ = ("_tl", "_name", "_ann", "_start")
+
+    def __init__(self, tl: "StepTimeline", name: str):
+        self._tl = tl
+        self._name = name
+
+    def __enter__(self):
+        tl = self._tl
+        self._ann = host_span("loop." + self._name, step=tl.steps)
+        self._start = tl._clock()
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._ann.__exit__(*exc)
+        self._tl._close_span(self._name, self._start, self._tl._clock())
+        return False
+
+
 class StepTimeline:
-    """Ring buffer of per-step host timings.
+    """Ring buffer of per-step host spans and completion stamps.
 
     Protocol (driven by the epoch loop):
 
     >>> tl = StepTimeline()
-    >>> for batch in batches:        # `next()` runs the input pipeline
-    ...     tl.batch_ready()         # end of data wait
-    ...     state, m = train_step(state, batch)
-    ...     tl.step_dispatched()     # end of dispatch (async: device runs on)
+    >>> tl.begin_call()                  # one epoch / one loop
+    >>> while True:
+    ...     with tl.span("data_wait"):   # the input pipeline's `next()`
+    ...         batch = next(batches, None)
+    ...     if batch is None:
+    ...         break
+    ...     with tl.span("to_device"):   # host-to-device copy
+    ...         batch = to_device(batch)
+    ...     with tl.span("dispatch"):    # async: the device runs on
+    ...         state, metrics = train_step(state, batch)
+    ...     tl.step_done(metrics)        # hands one output to the watcher
+    >>> with tl.span("fetch"):
+    ...     fetched = jax.device_get(all_metrics)
+    >>> tl.end_call()                    # bounded wait for the stamps
 
-    Each record splits the step into ``data`` (input-pipeline wait),
-    ``dispatch`` (host time to trace-cache-hit + enqueue) and — on sampled
-    steps when ``device_sync_every > 0`` — ``device`` (the drain measured
-    by :func:`tpu_compressed_dp.utils.timer.device_sync`, which bounds the
-    device work outstanding behind the dispatch).  Un-sampled steps carry
-    ``device=None``; their ``total`` is the honest host-visible latency
-    (under async dispatch the device cost surfaces as the NEXT dispatch
-    blocking, so window-level aggregates stay truthful either way).
+    A record is one step: its ordinal ``ord`` (shared by its spans'
+    annotations and its stamp), the ordinal ``call`` of the epoch call it
+    belongs to, the step's start ``t0`` (end of the previous dispatch, or
+    the :meth:`resume` mark), the three :data:`LOOP_SPANS` as ``(start,
+    end)`` and ``done``, the time its outputs became ready.  All times are
+    integer nanoseconds of ``clock`` (default ``time.time_ns``: the clock
+    a captured trace stamps annotations with, less the trace's
+    ``profile_start_time``).  ``done`` comes from one daemon watcher
+    thread that takes (record, one output of the step) in dispatch order
+    and waits on the output with the GIL released; a step that raised, or
+    whose output never became ready, keeps ``done=None``.  With
+    ``enqueued`` the end of the dispatch span and ``base`` the previous
+    step's ``done`` (the step's ``t0`` after a :meth:`resume`):
 
-    Memory is O(``capacity``): the buffer holds the most recent steps only
+      ``starved = max(0, enqueued - base)``   the device had nothing queued
+      ``device  = done - max(enqueued, base)`` the device's time on the step
+      ``starved_by``  the part of the starved interval under each span
+                      (``other``: the loop's own code between spans)
+
+    A late stamp (the watcher waits for the GIL like any thread) only
+    shortens the next step's ``starved``.
+
+    Memory is O(``capacity``): the ring holds the most recent steps only
     (the Timer-unbounded-append lesson, applied from day one).
     """
 
-    def __init__(self, capacity: int = 1024, device_sync_every: int = 0,
-                 clock: Callable[[], float] = time.perf_counter,
-                 sync: Optional[Callable[[], None]] = None):
+    def __init__(self, capacity: int = 1024,
+                 clock: Callable[[], int] = time.time_ns):
         if capacity < 1:
             raise ValueError(f"capacity must be >= 1, got {capacity}")
         self.capacity = capacity
-        self.device_sync_every = device_sync_every
         self._clock = clock
-        if sync is None:
-            from tpu_compressed_dp.utils.timer import device_sync
-
-            sync = device_sync
-        self._sync = sync
         self.records: collections.deque = collections.deque(maxlen=capacity)
         # since last drain(); a ring like `records`, so on overflow both
         # keep the NEWEST spans and drained step_spans stay consistent
         # with the snapshot() computed over the same window
         self._pending: collections.deque = collections.deque(maxlen=capacity)
-        self.steps = 0
-        self._t = clock()   # step start = end of previous dispatch
-        self._mark = self._t
+        self._calls: collections.deque = collections.deque(maxlen=capacity)
+        self.steps = 0                # ordinal of the next step
+        self._spans: Dict[str, Tuple[int, int]] = {}
+        self._first = True            # the next step opens a segment
+        self._t = clock()             # step start = end of previous dispatch
+        # the watcher stamps records the loop's thread has published;
+        # aggregates read them: one lock for the fields both touch
+        self._lock = threading.Lock()
+        self._queue: queue.SimpleQueue = queue.SimpleQueue()
+        self._watcher: Optional[threading.Thread] = None
+
+    # --- the loop's side ------------------------------------------------
+
+    def begin_call(self) -> None:
+        """Open the records of one epoch call (``run_train_epoch``) or one
+        loop; calls are numbered from 0 in the order they begin."""
+        self.resume()
+        self._open_call()
+
+    def _open_call(self) -> Dict[str, Any]:
+        call = self._calls[-1]["call"] + 1 if self._calls else 0
+        self._calls.append({"call": call, "t0": self._t, "t1": None,
+                            "fetch": None, "steps": 0})
+        return self._calls[-1]
+
+    def _call(self) -> Dict[str, Any]:
+        """The open call; a loop that never began one gets call 0."""
+        return self._calls[-1] if self._calls else self._open_call()
 
     def resume(self) -> None:
         """Re-stamp the step-start mark, excluding everything since the
-        last dispatch from the next step's ``data`` split.  Call on entry
-        to a train loop/epoch and after any blocking between-step work
-        (eval, checkpointing, a log-cadence ``device_get`` drain) —
-        otherwise that wall time is billed as input-pipeline wait and
-        corrupts ``data_wait_frac`` / the latency percentiles."""
+        last dispatch from the next step.  Called by :meth:`begin_call`,
+        and after any blocking between-step work (eval, checkpointing, a
+        log-cadence ``device_get`` drain) — otherwise that wall time is
+        billed to the step and corrupts the fractions and percentiles."""
         self._t = self._clock()
-        self._mark = self._t
-        self._data = 0.0
+        self._first = True
+        self._spans = {}
 
-    def batch_ready(self) -> None:
-        now = self._clock()
-        self._data = now - self._t
-        self._mark = now
+    def span(self, name: str) -> _Span:
+        """Context manager around one of :data:`LOOP_SPANS` of the step
+        being built, or around the call's ``fetch``; also a profiler
+        annotation ``tcdp.loop.<name>`` with ``step=`` the step's ordinal."""
+        return _Span(self, name)
 
-    def step_dispatched(self) -> None:
-        now = self._clock()
-        rec: Dict[str, float] = {
-            "t0": self._t,
-            "data": getattr(self, "_data", now - self._t),
-            "dispatch": now - self._mark,
-        }
+    def _close_span(self, name: str, start: int, end: int) -> None:
+        if name == "fetch":
+            self._call()["fetch"] = (start, end)
+        else:
+            self._spans[name] = (start, end)
+
+    def step_done(self, outputs: Any) -> None:
+        """Close the step whose spans were just recorded.  ``outputs`` is
+        what the step returned (any pytree): its first leaf goes to the
+        watcher, which stamps the record when the leaf is ready."""
+        leaves = jax.tree_util.tree_leaves(outputs)
+        self._commit(leaves[0] if leaves else _NO_OUTPUT)
+
+    def step_failed(self) -> None:
+        """Close the step being built when the loop raised inside it: the
+        record keeps the spans it got and ``done=None``.  Nothing when no
+        span of a new step was recorded."""
+        if self._spans:
+            self._commit(_NO_OUTPUT)
+
+    def _commit(self, token: Any) -> None:
+        call = self._call()
+        spans, self._spans = self._spans, {}
+        end = spans["dispatch"][1] if "dispatch" in spans else self._clock()
+        rec = {"ord": self.steps, "call": call["call"],
+               "first": self._first, "t0": self._t, "end": end,
+               "data_wait": spans.get("data_wait"),
+               "to_device": spans.get("to_device"),
+               "dispatch": spans.get("dispatch"),
+               "done": None, "device": None, "starved": None,
+               "starved_by": None}
         self.steps += 1
-        if self.device_sync_every and self.steps % self.device_sync_every == 0:
-            self._sync()
-            now2 = self._clock()
-            rec["device"] = now2 - now
-            now = now2
-        rec["total"] = now - rec["t0"]
-        self._t = now
-        self._data = 0.0
+        call["steps"] += 1
+        self._t = end
+        self._first = False
         self.records.append(rec)
         self._pending.append(rec)
+        if self._watcher is None:
+            # the thread holds the queue, not the timeline: when the
+            # timeline goes, the finalizer's None ends the thread
+            self._watcher = threading.Thread(
+                target=_watch, args=(self._queue, self._clock, self._lock),
+                name="tcdp-step-stamps", daemon=True)
+            self._watcher.start()
+            weakref.finalize(self, self._queue.put, None)
+        self._queue.put((rec, token))
+
+    def end_call(self, timeout: float = FLUSH_TIMEOUT_S) -> bool:
+        """Close the call after its fetch: wait (bounded) until the
+        watcher has stamped every step handed to it, so the call's records
+        are whole.  False when the bound ran out."""
+        whole = self.flush(timeout)
+        self._call()["t1"] = self._clock()
+        return whole
+
+    def flush(self, timeout: float = FLUSH_TIMEOUT_S) -> bool:
+        """Wait until the watcher has passed every step handed to it so
+        far, at most ``timeout`` seconds; False when it has not."""
+        if self._watcher is None:
+            return True
+        passed = threading.Event()
+        self._queue.put(passed)
+        return passed.wait(timeout)
+
+    # --- views ------------------------------------------------------------
+
+    def calls(self) -> List[Dict[str, Any]]:
+        """The last calls, oldest first: ``{"call", "t0", "t1", "fetch",
+        "steps", "records"}`` with ``t0`` the call's begin,
+        ``t1`` its end (None while open, or when the fetch raised),
+        ``steps`` the steps it counted and ``records`` those of them the
+        ring still holds (copies, in dispatch order)."""
+        with self._lock:
+            recs = [dict(r) for r in self.records]
+        by_call: Dict[int, List[Dict[str, Any]]] = {}
+        for r in recs:
+            by_call.setdefault(r["call"], []).append(r)
+        return [dict(c, records=by_call.get(c["call"], []))
+                for c in list(self._calls)]
+
+    @staticmethod
+    def _as_event(rec: Dict[str, Any]) -> Dict[str, Any]:
+        """The exported form of a record: seconds, durations for the
+        spans, absolute ``t0``/``done`` on the timeline's clock."""
+        by = rec["starved_by"]
+        return {"ord": rec["ord"], "call": rec["call"],
+                "t0": rec["t0"] / 1e9,
+                "data": _seconds(rec["data_wait"]),
+                "to_device": _seconds(rec["to_device"]),
+                "dispatch": _seconds(rec["dispatch"]),
+                "total": (rec["end"] - rec["t0"]) / 1e9,
+                "done": _ns_to_s(rec["done"]), "device": _ns_to_s(rec["device"]),
+                "starved": _ns_to_s(rec["starved"]),
+                "starved_in": max(by, key=by.get) if by else None}
+
+    def step_intervals(self) -> List[float]:
+        """Seconds per step over the ring window.  A stamped step's
+        interval runs from the previous step's completion to its own
+        (from its ``t0`` when it opened a segment: the pipeline was
+        drained); a step with no stamp, or none before it, falls back to
+        its host interval, enqueue to enqueue."""
+        with self._lock:
+            recs = [(r["first"], r["t0"], r["end"], r["done"])
+                    for r in self.records]
+        out, prev = [], None
+        for first, t0, end, done in recs:
+            since = t0 if first else prev
+            if done is not None and since is not None:
+                out.append((done - since) / 1e9)
+            else:
+                out.append((end - t0) / 1e9)
+            prev = done
+        return out
 
     # --- aggregates over the ring window --------------------------------
 
-    def percentiles(self) -> Dict[str, float]:
-        totals = sorted(r["total"] for r in self.records)
-        return {"p50": percentile(totals, 0.50),
-                "p95": percentile(totals, 0.95),
-                "p99": percentile(totals, 0.99)}
-
-    def data_wait_frac(self) -> float:
-        tot = sum(r["total"] for r in self.records)
-        if tot <= 0:
-            return 0.0
-        return sum(r["data"] for r in self.records) / tot
-
-    def steps_per_sec(self) -> float:
-        if len(self.records) < 1:
-            return 0.0
-        span = sum(r["total"] for r in self.records)
-        return len(self.records) / span if span > 0 else 0.0
-
     def snapshot(self) -> Dict[str, float]:
         """The registry-named telemetry summary (heartbeat / event stream /
-        Prometheus payload)."""
-        p = self.percentiles()
+        Prometheus payload).  The host fraction and the step rate are over
+        the host loop's own time (enqueue to enqueue); the starved
+        fraction is over the wall time of the window's segments, each from
+        its first step's start to its last completion."""
+        intervals = sorted(self.step_intervals())
+        with self._lock:
+            rows = [(r["first"], r["t0"], r["end"], r["done"] or 0,
+                     _seconds(r["data_wait"]), r["starved"] or 0)
+                    for r in self.records]
+        host = data = starved = wall = 0
+        seg_t0 = seg_end = None
+        for first, t0, end, done, data_wait, starved_ns in rows:
+            host += end - t0
+            data += data_wait
+            starved += starved_ns
+            if first or seg_t0 is None:      # a segment closes, one opens
+                if seg_t0 is not None:
+                    wall += seg_end - seg_t0
+                seg_t0 = seg_end = t0
+            seg_end = max(seg_end, end, done)
+        if seg_t0 is not None:
+            wall += seg_end - seg_t0
+        host /= 1e9
+        over = lambda x, base: x / base if base > 0 else 0.0
         return {
-            "time/step_p50_ms": p["p50"] * 1e3,
-            "time/step_p95_ms": p["p95"] * 1e3,
-            "time/step_p99_ms": p["p99"] * 1e3,
-            "time/data_wait_frac": self.data_wait_frac(),
-            "time/steps_per_sec": self.steps_per_sec(),
+            "time/step_p50_ms": percentile(intervals, 0.50) * 1e3,
+            "time/step_p95_ms": percentile(intervals, 0.95) * 1e3,
+            "time/step_p99_ms": percentile(intervals, 0.99) * 1e3,
+            "time/host_data_wait_frac": over(data, host),
+            "time/device_starved_frac": over(starved, wall),
+            "time/steps_per_sec": over(len(rows), host),
         }
 
-    def drain(self) -> List[Dict[str, float]]:
-        """Per-step records accumulated since the previous drain — the
-        event stream attaches these to epoch/window records so
-        tools/trace_report.py can rebuild the host timeline.  Ring-bounded
-        at ``capacity``: a longer window keeps its NEWEST spans (the same
-        window :meth:`snapshot` summarizes), dropping the head."""
-        out = list(self._pending)
+    def drain(self) -> List[Dict[str, Any]]:
+        """Per-step records (exported form) accumulated since the previous
+        drain — the event stream attaches these to epoch/window records so
+        tools/trace_report.py can rebuild the timeline.  Ring-bounded at
+        ``capacity``: a longer window keeps its NEWEST spans (the same
+        window :meth:`snapshot` summarizes), dropping the head.  Steps the
+        watcher has not passed yet carry ``done=None``: :meth:`flush`
+        first where the device has been drained."""
+        with self._lock:
+            out = [self._as_event(r) for r in self._pending]
         self._pending.clear()
         return out
+
+
+_PROCESS_TIMELINE: Optional[StepTimeline] = None
+
+
+def process_timeline() -> StepTimeline:
+    """The process-wide timeline: where ``run_train_epoch`` records when
+    its caller passes none, so that a caller that knows nothing of
+    timelines (a benchmark's builder) still leaves its spans and stamps
+    behind.  Built on first use."""
+    global _PROCESS_TIMELINE
+    if _PROCESS_TIMELINE is None:
+        _PROCESS_TIMELINE = StepTimeline(capacity=PROCESS_CAPACITY)
+    return _PROCESS_TIMELINE

@@ -1,8 +1,9 @@
-"""Wall-clock timing with device synchronisation.
+"""Wall-clock split timing.
 
 Equivalent of the reference ``Timer`` (`CIFAR10/core.py:14-27`), which was
-instantiated with ``torch.cuda.synchronize`` (`dawn.py:129`); on JAX the sync
-is ``block_until_ready`` on a sentinel device value.
+instantiated with ``torch.cuda.synchronize`` (`dawn.py:129`); the harnesses
+here pass no sync: ``run_train_epoch``'s closing fetch has drained the device
+before each split is taken.
 """
 
 from __future__ import annotations
@@ -10,21 +11,7 @@ from __future__ import annotations
 import time
 from typing import Callable, Optional
 
-import jax
-
-__all__ = ["Timer", "device_sync"]
-
-
-def device_sync() -> None:
-    """Block until all enqueued device work is complete.
-
-    Implemented as a value fetch of a fresh sentinel computation: device
-    queues are FIFO, so fetching the sentinel drains everything enqueued
-    before it.
-    """
-    import jax.numpy as jnp
-
-    jax.device_get(jnp.zeros(()) + 0.0)
+__all__ = ["Timer"]
 
 
 class Timer:
