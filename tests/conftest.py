@@ -42,3 +42,20 @@ def mesh8():
 
     assert len(jax.devices()) >= 8, "expected 8 virtual CPU devices"
     return make_data_mesh(8)
+
+
+@pytest.fixture
+def loopback_exclusive(tmp_path_factory):
+    """Held by every test that runs a multi-process job over the machine's
+    loopback interface or reads that interface's byte counters
+    (`tools/validate_transport.py` reads `/proc/net/dev`, which counts the
+    whole machine's traffic): a file lock shared by the xdist workers, so
+    that no two such tests overlap whichever workers they land on."""
+    import fcntl
+
+    base = tmp_path_factory.getbasetemp()
+    if os.environ.get("PYTEST_XDIST_WORKER"):
+        base = base.parent      # each worker's base is a child of the run's
+    with open(base / "loopback.lock", "w") as f:
+        fcntl.flock(f, fcntl.LOCK_EX)
+        yield

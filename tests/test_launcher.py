@@ -45,6 +45,7 @@ class TestGcloudMode:
 
 class TestLocalMode:
     @pytest.mark.timeout(300)
+    @pytest.mark.usefixtures("loopback_exclusive")
     def test_two_process_dawn_trains(self, tmp_path):
         """2 processes x 2 virtual CPU devices: the dawn harness shards the
         global batch per process (`ShardedBatches`), syncs compressed

@@ -22,6 +22,7 @@ TOOL = os.path.join(ROOT, "tools", "validate_transport.py")
 
 
 @pytest.mark.timeout(600)
+@pytest.mark.usefixtures("loopback_exclusive")
 def test_measured_lo_bytes_track_analytic(tmp_path):
     out = tmp_path / "transport.tsv"
     env = dict(os.environ)

@@ -109,6 +109,9 @@ declare("comm/dense_elems", GAUGE, "elems", "mean", "engine",
         "uncompressed gradient size (the compression denominator)")
 declare("comm/num_collectives", GAUGE, "collectives", "mean", "engine",
         "collectives issued per sync (granularity-dependent)")
+declare("comm/sync_chains", GAUGE, "chains", "mean", "engine",
+        "wire sync: chains traced per sync, one per distinct (flat size, "
+        "dtype, transport) among the reduction groups")
 declare("comm/sync_agree", GAUGE, "bool", "min", "engine",
         "check_sync verdict: 1.0 = every worker selected identical "
         "indices / holds an identical warm start (unanimity -> pmin)")

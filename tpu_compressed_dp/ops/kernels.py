@@ -67,6 +67,7 @@ __all__ = [
     "set_pallas_mode",
     "pallas_mode",
     "topk_threshold",
+    "topk_thresholds",
     "fused_sparsify",
     "use_fused_sparsify",
     "pack_by_threshold",
@@ -225,30 +226,47 @@ def _vma(x: Array):
     return jax.typeof(x).vma
 
 
-def _topk_threshold_pallas(
-    mag: Array, keep: int, *, rounds: int = 7, interpret: bool = False,
+def _topk_threshold_pallas(mag: Array, keep: int, **kw) -> Array:
+    return _topk_thresholds_pallas([mag], keep, **kw)[0]
+
+
+def _topk_thresholds_pallas(
+    mags, keep: int, *, rounds: int = 7, interpret: bool = False,
     sample_init: bool = True,
 ) -> Array:
-    n = mag.shape[0]
+    """Thresholds ``[m]`` of ``m`` magnitude vectors of one size, their
+    refinement rounds in lock-step: ONE loop carries the brackets of all
+    members as vectors and narrows them in one vectorised pass, and a round
+    launches the count kernel once per member on the member's own buffer
+    (no stacked copy).  Per member the arithmetic is the single-vector
+    refinement's, scalar for scalar."""
+    members = range(len(mags))
+    n = mags[0].shape[0]
+    vma = tuple(sorted(_vma(mags[0])))
     # clamp BEFORE the sampled-init rank arithmetic: keep > n would give
     # lo_rank > hi_rank and an IndexError at trace time in sv[rk] (the exact
     # path already clamps via keep_f; mirror it here)
     keep = min(keep, n)
-    x2d, num_chunks = _pad_chunks(mag.astype(jnp.float32), fill=-1.0,
-                                  rows=_HIST_ROWS)
+    x2ds = [_pad_chunks(mag.astype(jnp.float32), fill=-1.0, rows=_HIST_ROWS)[0]
+            for mag in mags]
+    num_chunks = x2ds[0].shape[0] // _HIST_ROWS
 
-    count_ge = pl.pallas_call(
-        _count_ge_kernel,
-        grid=(num_chunks,),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i: (0, 0), memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1), lambda i: (0, 0), memory_space=pltpu.SMEM),
-            pl.BlockSpec((_HIST_ROWS, _LANES), lambda i: (i, 0), memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((1, _LANES), lambda i: (0, 0), memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((1, _LANES), jnp.float32, vma=_vma(mag)),
-        interpret=interpret,
-    )
+    def counts_call(kernel, bracket_specs):
+        return pl.pallas_call(
+            kernel,
+            grid=(num_chunks,),
+            in_specs=bracket_specs + [
+                pl.BlockSpec((_HIST_ROWS, _LANES), lambda i: (i, 0),
+                             memory_space=pltpu.VMEM)],
+            out_specs=pl.BlockSpec((1, _LANES), lambda i: (0, 0),
+                                   memory_space=pltpu.VMEM),
+            out_shape=jax.ShapeDtypeStruct((1, _LANES), jnp.float32,
+                                           vma=_vma(mags[0])),
+            interpret=interpret,
+        )
+
+    scalar = pl.BlockSpec((1, 1), lambda i: (0, 0), memory_space=pltpu.SMEM)
+    count_ge = counts_call(_count_ge_kernel, [scalar, scalar])
 
     keep_f = jnp.float32(min(keep, n))
 
@@ -267,23 +285,16 @@ def _topk_threshold_pallas(
 
     def round_body(_, carry):
         lo, hi, above = carry
-        counts = count_ge(
-            lo.reshape(1, 1).astype(jnp.float32),
-            hi.reshape(1, 1).astype(jnp.float32),
-            x2d,
-        )[0][:_HIST_BINS]
-        return narrow(lo, hi, above, counts)
+        lo2, hi2 = lo.reshape(-1, 1, 1), hi.reshape(-1, 1, 1)
+        counts = jnp.stack([count_ge(lo2[j], hi2[j], x2ds[j])[0][:_HIST_BINS]
+                            for j in members])
+        return jax.vmap(narrow)(lo, hi, above, counts)
 
-    def pcast(vals):
+    def varying(v):
         # carries become device-varying after a count round (counts derive
         # from the varying magnitudes) — pcast replicated values so loop /
         # cond branch types match
-        vma = tuple(sorted(_vma(mag)))
-        if not vma:
-            return vals
-        return tuple(
-            jax.lax.pcast(v, vma, to="varying") if not _vma(v) else v for v in vals
-        )
+        return jax.lax.pcast(v, vma, to="varying") if vma and not _vma(v) else v
 
     # max|g| strictly below hi so the top element always lands in a bin.
     # A non-finite max (guard-vetoed NaN/Inf gradient, or fp32 overflow of
@@ -293,15 +304,15 @@ def _topk_threshold_pallas(
     # lanes (fill -1.0, strictly below every edge >= lo >= 0) still never
     # count, NaNs compare-false out of every bin, and +-Inf sits above every
     # edge exactly like the true max used to.
-    hi_raw = jnp.max(mag).astype(jnp.float32) * 1.0000002 + 1e-30
-    full_init = pcast(
-        (jnp.float32(0.0),
-         jnp.where(jnp.isfinite(hi_raw), hi_raw, jnp.float32(3.4028235e38)),
-         jnp.float32(0.0)))
+    hi_raw = jnp.stack([jnp.max(mag).astype(jnp.float32) for mag in mags]
+                       ) * 1.0000002 + 1e-30
+    zeros = varying(jnp.zeros((len(mags),), jnp.float32))
+    hi0 = varying(jnp.where(jnp.isfinite(hi_raw), hi_raw,
+                            jnp.float32(3.4028235e38)))     # max*(1+eps)
+    full_init = (zeros, hi0, zeros)
 
     if not sample_init or keep < 1 or n < (1 << 18):
-        lo, _, _ = jax.lax.fori_loop(0, rounds, round_body, full_init)
-        return lo
+        return jax.lax.fori_loop(0, rounds, round_body, full_init)[0]
 
     # Sampled init, BRANCHLESS (a lax.cond fallback would run BOTH branches
     # under shard_map — the predicate is device-varying — costing more than
@@ -330,16 +341,11 @@ def _topk_threshold_pallas(
         # mid-size tensors where the sample can't be much smaller than the
         # data: the sample top_k would rival the full histogram — use the
         # exact full-range rounds instead
-        lo, _, _ = jax.lax.fori_loop(0, rounds, round_body, full_init)
-        return lo
-    sample = jax.lax.slice(
-        mag[: nb * C].reshape(nb, C).astype(jnp.float32), (0, 0), (nb, 128)
-    ).reshape(-1)
+        return jax.lax.fori_loop(0, rounds, round_body, full_init)[0]
     r = keep * m / n
     delta = 4.0 * float(r) ** 0.5 + 8.0
     hi_rank = int(min(m - 1, r + delta))
     lo_rank = int(max(0, r - delta))
-    sv = jax.lax.top_k(sample, hi_rank + 1)[0]
     # 15 interior quantile edges spanning [rank r+delta, rank r-delta],
     # ascending in value (17 edges = 16 bins with the 0 and max*(1+eps)
     # brackets); duplicate edges (sample ties) just yield empty bins.
@@ -350,47 +356,39 @@ def _topk_threshold_pallas(
     # bracket: an empty top bin, exactly like a duplicate edge.
     qranks = [int(round(lo_rank + (hi_rank - lo_rank) * i / 14.0))
               for i in range(15)]
-    interior = [sv[rk] for rk in reversed(qranks)]           # ascending
-    hi0 = full_init[1]                                       # max*(1+eps)
-    edges = jnp.stack(
-        [jnp.float32(0.0) if not _vma(mag)
-         else jax.lax.pcast(jnp.float32(0.0), tuple(sorted(_vma(mag))),
-                            to="varying")]
-        + [jnp.where(jnp.isfinite(e), jnp.minimum(e, hi0), hi0)
-           for e in interior] + [hi0]
-    )
 
-    count_edges = pl.pallas_call(
-        _count_edges_kernel,
-        grid=(num_chunks,),
-        in_specs=[
-            pl.BlockSpec((1, _HIST_BINS + 1), lambda i: (0, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((_HIST_ROWS, _LANES), lambda i: (i, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((1, _LANES), lambda i: (0, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((1, _LANES), jnp.float32, vma=_vma(mag)),
-        interpret=interpret,
-    )
-    counts = count_edges(edges.reshape(1, -1), x2d)[0][:_HIST_BINS]
-    # bin selection against the edge ARRAY (narrow()'s arithmetic edges
-    # don't apply to the quantile round)
-    total_ge = counts  # counts[b] already counts >= edges[b] (above == 0)
-    b = jnp.clip(jnp.sum((total_ge >= keep_f).astype(jnp.int32)) - 1,
-                 0, _HIST_BINS - 1)
-    new_lo = edges[b]
-    new_hi = edges[b + 1]
-    counts_ext = jnp.concatenate([counts, jnp.zeros((1,), jnp.float32)])
-    new_above = counts_ext[jnp.clip(b + 1, 0, _HIST_BINS)]
-    carry = (new_lo, new_hi, new_above)
+    def member_edges(mag, hi0):
+        sample = jax.lax.slice(
+            mag[: nb * C].reshape(nb, C).astype(jnp.float32), (0, 0), (nb, 128)
+        ).reshape(-1)
+        sv = jax.lax.top_k(sample, hi_rank + 1)[0]
+        interior = [sv[rk] for rk in reversed(qranks)]       # ascending
+        return jnp.stack(
+            [varying(jnp.float32(0.0))]
+            + [jnp.where(jnp.isfinite(e), jnp.minimum(e, hi0), hi0)
+               for e in interior] + [hi0])
+
+    edges = jnp.stack([member_edges(mags[j], hi0[j]) for j in members])
+    count_edges = counts_call(_count_edges_kernel, [pl.BlockSpec(
+        (1, _HIST_BINS + 1), lambda i: (0, 0), memory_space=pltpu.SMEM)])
+    counts = jnp.stack([count_edges(edges[j:j + 1], x2ds[j])[0][:_HIST_BINS]
+                        for j in members])
+
+    def first_bin(edges, counts):
+        # bin selection against the edge ARRAY (narrow()'s arithmetic edges
+        # don't apply to the quantile round); counts[b] already counts
+        # >= edges[b] (above == 0)
+        b = jnp.clip(jnp.sum((counts >= keep_f).astype(jnp.int32)) - 1,
+                     0, _HIST_BINS - 1)
+        counts_ext = jnp.concatenate([counts, jnp.zeros((1,), jnp.float32)])
+        return edges[b], edges[b + 1], counts_ext[jnp.clip(b + 1, 0, _HIST_BINS)]
+
     # 4 equispaced rounds refine the selected bin by 16^4: tie-level surplus
     # for representative samples, and a few percent even when the whole
     # top-k mass hides from the sample (the degraded worst case — see
     # tests/test_kernels.py adversarial-layout case)
-    lo, _, _ = jax.lax.fori_loop(0, 4, round_body, carry)
-    return lo
+    return jax.lax.fori_loop(0, 4, round_body,
+                             jax.vmap(first_bin)(edges, counts))[0]
 
 
 _INT32_MAX = (1 << 31) - 1
@@ -442,6 +440,16 @@ def _topk_threshold_jnp(mag: Array, keep: int, rounds: int = 7) -> Array:
             counts_next[jnp.clip(b + 1, 0, _HIST_BINS)])
         lo, hi = new_lo, new_hi
     return lo
+
+
+def topk_thresholds(mags, keep: int):
+    """:func:`topk_threshold` of several vectors of one size: where the
+    histogram kernel serves them, their refinement rounds share one loop."""
+    n = mags[0].shape[0]
+    if keep < n and _dispatch_to_pallas(n):
+        ts = _topk_thresholds_pallas(mags, keep, interpret=_auto_interpret())
+        return [ts[j] for j in range(len(mags))]
+    return [topk_threshold(mag, keep) for mag in mags]
 
 
 def topk_threshold(mag: Array, keep: int) -> Array:
