@@ -94,7 +94,7 @@ def test_batched_chain_is_bitwise_the_per_leaf_chain(monkeypatch, world, name,
                                           np.asarray(r_ef[k]))
         assert float(r_stats["sync_chains"][0]) == 1.0
     for stat in ("sent_bits", "sent_elems", "sent_bits_allgather",
-                 "sent_bits_psum", "topk_surplus_dropped"):
+                 "sent_bits_psum", "topk_surplus_dropped", "topk_underfull"):
         assert (stat in stats) == (stat in ref[0][2])
         if stat in stats:
             np.testing.assert_array_equal(
@@ -102,6 +102,8 @@ def test_batched_chain_is_bitwise_the_per_leaf_chain(monkeypatch, world, name,
                 sum(np.asarray(r[2][stat], np.float64) for r in ref))
     if name == "topk":
         assert "topk_surplus_dropped" in stats
+    if name.startswith("topk"):
+        assert float(stats["topk_underfull"][0]) == 0.0     # finite gradients
 
 
 def abstract_params(arch):
@@ -166,3 +168,202 @@ def test_resnet50_sync_has_one_loop_per_large_part(monkeypatch):
     assert 2 * long_leaves <= counts["pallas_call"] <= 3 * long_leaves
     # the short leaves' chains are traced once a part, not once a leaf
     assert counts["cumsum"] <= 2 * (22 - large) + 2 * long_leaves
+
+
+# --- the allgather Top-K residual as one streamed pass, and the payload's
+# --- bucket starts from a scan over the ranks (no keep-sized scatter, gather)
+
+T = 1.0     # the threshold the residual cases are built around
+RESIDUAL_CASES = ["ties_straddle_last", "surplus_after_last", "count_eq_keep",
+                  "underfull", "underfull_first_survives"]
+
+
+def residual_case(case, n, keep, seed):
+    """A vector whose survivors of ``|x| >= T`` are placed for ``case``; the
+    rest is noise under ``T / 2``."""
+    rng = np.random.default_rng(seed)
+    acc = rng.uniform(-0.5, 0.5, n).astype(np.float32)
+    count = {"ties_straddle_last": keep + 3, "surplus_after_last": keep + 7,
+             "count_eq_keep": keep, "underfull": keep - 3,
+             "underfull_first_survives": keep - 3}[case]
+    where = np.sort(rng.choice(np.arange(1, n), count, replace=False))
+    if case == "underfull_first_survives":
+        where[0] = 0
+    acc[where] = rng.uniform(1.25, 2.0, count) * rng.choice([-1.0, 1.0], count)
+    if case == "ties_straddle_last":
+        # survivors keep-2 .. keep+2 by index sit exactly at the threshold:
+        # two of them travel, three stay behind
+        acc[where[keep - 2:]] = T * rng.choice([-1.0, 1.0], 5)
+    return acc, count
+
+
+def residual_oracle(acc, keep):
+    """The first ``keep`` survivors by ascending index zeroed: what the
+    parent's ``acc.at[idx].set(0)`` gives wherever the mask is full, and
+    every survivor zeroed where it is not."""
+    out = acc.copy()
+    out[np.flatnonzero(np.abs(acc) >= T)[:keep]] = 0
+    return out
+
+
+def leaf_sync(accs, keep):
+    """`_leaf_sync_topk` of each row of ``accs`` at threshold T, one worker:
+    (dense, residual, count), and the parent's scatter form of the residual
+    from the same packed indices."""
+    def one(acc):
+        dense, new_ef, _, count = wire._leaf_sync_topk(
+            acc, keep, "data", 1, True, t=jnp.float32(T))
+        mag = jnp.abs(acc).astype(jnp.float32)
+        idx = wire._select_pack(acc, mag, jnp.float32(T), keep)[1]
+        return dense, new_ef, count, acc.at[idx].set(0)
+
+    return jax.jit(shard_map(jax.vmap(one), mesh=make_data_mesh(1),
+                             in_specs=P(), out_specs=P(),
+                             check_vma=False))(jnp.asarray(accs))
+
+
+def check_residuals(accs, keep, cases, outs):
+    dense, new_ef, count, scattered = map(np.asarray, outs)
+    for j, case in enumerate(cases):
+        acc = accs[j]
+        want = residual_oracle(acc, keep)
+        np.testing.assert_array_equal(new_ef[j], want, err_msg=case)
+        survivors = np.flatnonzero(np.abs(acc) >= T)
+        assert count[j] == len(survivors)
+        if len(survivors) >= keep:
+            # the parent's form, bitwise; the surplus stays in the residual
+            np.testing.assert_array_equal(new_ef[j], scattered[j], err_msg=case)
+            np.testing.assert_array_equal(new_ef[j][survivors[keep:]],
+                                          acc[survivors[keep:]])
+            # what travelled and what stayed add up to the gradient
+            np.testing.assert_array_equal(dense[j] + new_ef[j], acc,
+                                          err_msg=case)
+        else:
+            # (the payload's padding slots all name index 0: `dense[0]` is
+            # not the residual's business)
+            assert not np.any(np.abs(new_ef[j]) >= T)
+            assert (new_ef[j][0] == acc[0]) == (abs(acc[0]) < T)
+
+
+@pytest.mark.parametrize("case", RESIDUAL_CASES)
+def test_streamed_residual_long_leaf_is_the_scatter_form(monkeypatch, case):
+    tpu_dispatch(monkeypatch, interpret=True)
+    n, keep = BIG + 4464, 700
+    assert kernels.use_select_pack(n, keep)
+    acc, _ = residual_case(case, n, keep, seed=5)
+    check_residuals(acc[None], keep, [case], leaf_sync(acc[None], keep))
+
+
+@pytest.mark.parametrize("n,keep", [(256, 13), (1000, 10), (4224, 43)])
+def test_streamed_residual_short_stack_is_the_scatter_form(n, keep):
+    # one member a case, all under one `jax.vmap` as `sync` stacks them
+    assert not kernels.use_select_pack(n, keep)
+    accs = np.stack([residual_case(case, n, keep, seed=7 + j)[0]
+                     for j, case in enumerate(RESIDUAL_CASES)])
+    check_residuals(accs, keep, RESIDUAL_CASES, leaf_sync(accs, keep))
+
+
+def bucket_counts(case, buckets, rng):
+    """Survivors a bucket (kernel segment, mask row) for ``case``."""
+    counts = rng.integers(1, 9, buckets)
+    if case == "empty_buckets":
+        counts[rng.choice(buckets, buckets // 2, replace=False)] = 0
+        counts[[3, 4, 5]] = 0           # a run of them, and the last one
+        counts[-1] = 0
+    elif case == "first_bucket_empty":
+        counts[0] = 0
+    elif case == "one_bucket":
+        counts[:] = 0
+        counts[buckets // 2] = 60
+    elif case == "no_survivor":
+        counts[:] = 0
+    return counts.astype(np.int32)
+
+
+BUCKET_CASES = ["empty_buckets", "first_bucket_empty", "one_bucket",
+                "no_survivor", "every_bucket"]
+
+
+@pytest.mark.parametrize("keep_over_count", [False, True])
+@pytest.mark.parametrize("case", BUCKET_CASES)
+def test_select_pack_payload_starts_without_gather(case, keep_over_count):
+    rng = np.random.default_rng(17)
+    nseg, seg = 12, kernels._SEG
+    counts = bucket_counts(case, nseg, rng)
+    total = int(counts.sum())
+    keep = total + 5 if keep_over_count else max(total - 4, 1)
+    # staging buffers as the kernel leaves them: a segment's survivors
+    # compacted to its front, anything behind them
+    vals = rng.standard_normal((nseg, seg)).astype(np.float32)
+    idx = rng.integers(0, 1 << 20, (nseg, seg)).astype(np.int32)
+    want_v = np.concatenate([vals[s, :c] for s, c in enumerate(counts)])[:keep]
+    want_i = np.concatenate([idx[s, :c] for s, c in enumerate(counts)])[:keep]
+    pad = keep - len(want_v)
+    pvals, pidx, got_total = jax.jit(kernels._select_pack_payload, static_argnums=3)(
+        jnp.asarray(vals.reshape(-1, 128)), jnp.asarray(idx.reshape(-1, 128)),
+        jnp.asarray(counts), keep)
+    np.testing.assert_array_equal(np.asarray(pvals), np.pad(want_v, (0, pad)))
+    np.testing.assert_array_equal(np.asarray(pidx), np.pad(want_i, (0, pad)))
+    assert int(got_total) == total
+    # the replaced expression itself, the clamped ranks beyond the count too
+    ends = np.cumsum(counts)
+    seg_of = np.minimum(np.searchsorted(ends, np.arange(1, keep + 1)), nseg - 1)
+    np.testing.assert_array_equal(
+        np.asarray(kernels.run_starts(jnp.asarray(seg_of, jnp.int32))),
+        (ends - counts)[seg_of])
+
+
+@pytest.mark.parametrize("keep_over_count", [False, True])
+@pytest.mark.parametrize("case", BUCKET_CASES)
+def test_packed_indices_from_mask_starts_without_gather(case, keep_over_count):
+    rng = np.random.default_rng(19)
+    rows, n = 12, 12 * 128 - 40          # a last row that is padded
+    counts = bucket_counts(case, rows, rng)
+    mask = np.zeros(rows * 128, bool)
+    for r, c in enumerate(counts):
+        lanes = 128 - 40 if r == rows - 1 else 128
+        mask[r * 128 + rng.choice(lanes, c, replace=False)] = True
+    mask = mask[:n]
+    total = int(mask.sum())
+    keep = total + 5 if keep_over_count else max(total - 4, 1)
+    # under a vmap too, as the short leaves' stacks run it
+    masks = np.stack([mask, mask[::-1]])
+    got = np.asarray(jax.jit(jax.vmap(
+        lambda m: wire.packed_indices_from_mask(m, keep)))(jnp.asarray(masks)))
+    for m, g in zip(masks, got):
+        want = np.flatnonzero(m)[:keep]
+        np.testing.assert_array_equal(g, np.pad(want, (0, keep - len(want))))
+
+
+@pytest.mark.parametrize("underfull", [False, True])
+def test_topk_underfull_counts_the_groups_under_keep(monkeypatch, underfull):
+    from tpu_compressed_dp.obs import registry
+
+    cfg = CompressionConfig(mode="wire", granularity="layerwise", method="topk",
+                            ratio=0.05, error_feedback=True)
+    rng = np.random.default_rng(23)
+    grads = {k: jnp.asarray(rng.standard_normal(s), jnp.float32)
+             for k, s in {"a1": (256,), "a2": (16, 16), "b": (100,)}.items()}
+    resid = jax.tree.map(jnp.zeros_like, grads)
+    keep_b = wire.compressors.topk_keep_count(100, cfg.ratio)
+    mags_b = np.sort(np.abs(np.asarray(grads["b"])))
+    if underfull:
+        # the one group of 100 elements gets a threshold one survivor short
+        real = kernels.topk_thresholds
+        short = jnp.float32(mags_b[-(keep_b - 1)])
+        monkeypatch.setattr(
+            kernels, "topk_thresholds",
+            lambda mags, keep: ([short] * len(mags) if mags[0].shape[0] == 100
+                                else real(mags, keep)))
+    sync = wire.make_wire_grad_sync(cfg, "data")
+    _, new_ef, stats = jax.jit(shard_map(
+        lambda g, e: sync(g, e, jax.random.key(0)), mesh=make_data_mesh(1),
+        in_specs=P(), out_specs=P(), check_vma=False))(grads, resid)
+    assert float(stats["topk_underfull"]) == float(underfull)
+    assert registry.undeclared(["comm/topk_underfull"]) == []
+    assert "topk_surplus_dropped" not in stats       # EF on: reabsorbed
+    b = np.asarray(grads["b"])
+    sent = np.flatnonzero(np.asarray(new_ef["b"]) == 0)
+    assert len(sent) == keep_b - underfull
+    np.testing.assert_array_equal(
+        sent, np.sort(np.argsort(-np.abs(b), kind="stable")[:len(sent)]))

@@ -106,6 +106,9 @@ def worker(args) -> None:
             {"g": g}, {"g": ef} if cfg.error_feedback else (), (), key)
         out = synced["g"]
         nef = new_ef["g"] if cfg.error_feedback else ef
+        # per-worker counters (clips, `topk_underfull`) leave replicated,
+        # as the train steps report them
+        stats = {k: jax.lax.pmean(v, "data") for k, v in stats.items()}
         return out, nef, stats
 
     f = jax.jit(shard_map(

@@ -68,9 +68,10 @@ def _stage_chain(upto: str, n: int, keep: int, axis_name: str = "data"):
         out = out + jnp.sum(dense[:8])
         if upto == "combine":
             return out
-        new_ef = flat.at[idx].set(0, indices_are_sorted=True,
-                                  unique_indices=True,
-                                  mode="promise_in_bounds")
+        # the residual as `_leaf_sync_topk` makes it (the threshold leaves
+        # at least `keep` survivors): one streamed pass, no scatter
+        upto_last = jnp.arange(n, dtype=jnp.int32) <= idx[keep - 1]
+        new_ef = jnp.where((mag >= t) & upto_last, 0, flat)
         out = out + jnp.sum(new_ef[:8])
         return out
 
@@ -78,8 +79,8 @@ def _stage_chain(upto: str, n: int, keep: int, axis_name: str = "data"):
 
 
 def _pack_sub_chain(upto: str, n: int, keep: int):
-    """Sub-stages of the SHIPPED packed_indices_from_mask (pack v2, r5:
-    one fused row-starts gather + bf16 MXU tri-matmul), cumulative from the
+    """Sub-stages of the SHIPPED packed_indices_from_mask (row starts from
+    a scan over the ranks, one row gather + bf16 MXU tri-matmul), cumulative from the
     threshold rung.  Mirrors ops/wire.py — update both together."""
 
     def chain(flat: jax.Array):
@@ -107,10 +108,9 @@ def _pack_sub_chain(upto: str, n: int, keep: int):
         if upto == "p_rowof":
             return out
         ranks = jnp.arange(1, keep + 1, dtype=jnp.int32)
-        row_starts = wire._sorted_gather(row_ends - row_counts, row_of)
-        within = ranks - row_starts
+        within = ranks - kernels.run_starts(row_of)
         out = out + jnp.sum(within[:8].astype(jnp.float32))
-        if upto == "p_startsgather":
+        if upto == "p_starts":
             return out
         rows = wire._sorted_gather(m2, row_of).astype(jnp.bfloat16)
         out = out + jnp.sum(rows[:8].astype(jnp.float32))
@@ -127,7 +127,7 @@ def _pack_sub_chain(upto: str, n: int, keep: int):
     return chain
 
 
-PACK_SUBS = ["p_rowcounts", "p_hist", "p_rowof", "p_startsgather",
+PACK_SUBS = ["p_rowcounts", "p_hist", "p_rowof", "p_starts",
              "p_rowgather", "p_matmul"]
 
 
@@ -492,7 +492,7 @@ def main(argv=None):
         prev = dt
     total = rows[-1][1]
     print(f"# chain total {total:.2f} ms; element-granular random-access "
-          f"stages = gather+combine+ef")
+          f"stages = gather+combine (ef is one streamed pass)")
     if args.subs:
         prev = rows[1][1] / 1e3   # threshold rung is the sub-ladder's base
         print("# pack sub-stages (cumulative from threshold rung):")

@@ -119,6 +119,9 @@ declare("comm/threshold_overflow", COUNTER, "elems", "mean", "engine",
         "threshold-method survivors clipped by the fixed wire capacity")
 declare("comm/topk_surplus_dropped", COUNTER, "elems", "mean", "engine",
         "above-threshold tie survivors beyond keep, truncated (EF off)")
+declare("comm/topk_underfull", COUNTER, "groups", "mean", "engine",
+        "allgather wire Top-K: reduction groups whose threshold left fewer "
+        "survivors than keep (0 on finite gradients)")
 declare("comm/shard_overflow", COUNTER, "elems", "mean", "engine",
         "coordinates clipped by the sharded transport's route/return caps")
 declare("guard/nonfinite", GAUGE, "bool", "max", "engine",

@@ -1132,15 +1132,33 @@ def _select_pack_kernel(n: int, t_ref, x_ref, vals_ref, idx_ref, cnt_ref):
     cnt_ref[:] = r3[:, _SEG_ROWS - 1, :].astype(jnp.int32)
 
 
+def run_starts(bucket_of: Array) -> Array:
+    """``starts[r]``: the position at which the run of equal values holding
+    position ``r`` of the never-decreasing ``bucket_of`` began.  With
+    ``bucket_of`` the bucket (segment, mask row) of each payload rank, a
+    rank's bucket changes exactly at the first rank of every bucket that
+    holds survivors, so this is the bucket's exclusive start
+    ``(ends - counts)[bucket_of]`` — empty buckets, an empty first bucket
+    and the clamped ranks beyond the survivor count included — from one
+    elementwise pass and one scan over the ranks: no ``keep``-sized gather,
+    and no scatter of bucket starts, whose indices repeat wherever a bucket
+    is empty."""
+    pos = jnp.arange(bucket_of.shape[0], dtype=jnp.int32)
+    # position 0 may read as opening a run: its value is 0 either way
+    opens = bucket_of != jnp.pad(bucket_of[:-1], (1, 0))
+    return jax.lax.cummax(jnp.where(opens, pos, 0))
+
+
 def _select_pack_payload(vals_st: Array, idx_st: Array, counts: Array,
                          keep: int):
     """Rank-bucket the per-segment compacted prefixes into the exact
     ``keep``-slot payload (ascending global index).  Segment-granular
     `packed_indices_from_mask`: find each payload rank's segment via a
-    histogram over segment end-counts, then one sorted gather from the
-    staging buffer.  Underfull masks (count < keep — only reachable on
-    poisoned gradients; `topk_threshold` guarantees count >= keep otherwise)
-    pad with value 0 / index 0, scatter-add identities."""
+    histogram over segment end-counts and the segment's first rank via
+    `run_starts`, then one sorted gather from each staging buffer.
+    Underfull masks (count < keep — only reachable on poisoned gradients;
+    `topk_threshold` guarantees count >= keep otherwise) pad with value 0 /
+    index 0, scatter-add identities."""
     nseg = counts.shape[0]
     v = vals_st.reshape(nseg, _SEG)
     ix = idx_st.reshape(nseg, _SEG)
@@ -1154,11 +1172,7 @@ def _select_pack_payload(vals_st: Array, idx_st: Array, counts: Array,
     # clamp to the last segment (not 0) so flat_pos stays monotone and the
     # gather can keep its sorted hint
     seg_of = jnp.where(valid, seg_of, nseg - 1)
-    # one gather of precomputed exclusive starts (the packed_indices_from_mask
-    # trick), not two of ends and counts
-    starts = (ends - counts).at[seg_of].get(indices_are_sorted=True,
-                                            mode="promise_in_bounds")
-    within = jnp.clip(ranks - starts - 1, 0, _SEG - 1)
+    within = jnp.clip(ranks - run_starts(seg_of) - 1, 0, _SEG - 1)
     flat_pos = seg_of * _SEG + within
     gv = v.reshape(-1).at[flat_pos].get(indices_are_sorted=True,
                                         mode="promise_in_bounds")

@@ -272,12 +272,13 @@ def packed_indices_from_mask(mask: Array, keep: int) -> Array:
     (== searchsorted(row_ends, r, left)) — which replaced a binary search's
     serialized gather chain (258ms -> ~25ms at 170M, round 2).
 
-    The per-rank stage is TWO gathers per rank (round 5; was three + an
+    The per-rank stage is ONE gather per rank, of the mask rows (round 5
+    had a second one, of precomputed row starts; before that three + an
     fp32 tri-matmul): per-rank costs are billed per random ACCESS, and the
     round-5 bisect (tools/wire_profile.py --subs) measured ~7 ms per
-    [keep]-sized gather at keep=1.25M — so gathering ``row_ends`` and ``row_counts`` separately
-    just to subtract them was a wasted 8 ms: one precomputed ``row_starts``
-    array halves that stage.  The in-row prefix matmul runs in bf16 (row
+    [keep]-sized gather at keep=1.25M.  A rank's row start is where its run
+    of equal ``row_of`` began (`kernels.run_starts`): a scan over the
+    ranks, no access.  The in-row prefix matmul runs in bf16 (row
     prefix counts are <= 128, exactly representable), halving the gathered
     rows' materialisation traffic vs fp32.  Two rejected redesigns, both
     measured slower: bit-packing rows into uint32 words for a single
@@ -286,6 +287,8 @@ def packed_indices_from_mask(mask: Array, keep: int) -> Array:
     VPU), and a full-tensor scatter formulation emitting (idx, val) pairs
     elementwise (XLA does not stream sorted 125M-update scatters: 2.2 s).
     """
+    from tpu_compressed_dp.ops import kernels
+
     lanes = 128
     n = mask.shape[0]
     pad = (-n) % lanes
@@ -308,10 +311,10 @@ def packed_indices_from_mask(mask: Array, keep: int) -> Array:
     # so the sorted-gather hints stay truthful; the final jnp.where still
     # returns index 0 for invalid ranks
     row_of = jnp.where(valid, row_of, nrows - 1)
-    # rank within the row: global rank minus everything before the row —
-    # ONE gather of the precomputed starts, not two of ends and counts
-    row_starts = _sorted_gather(row_ends - row_counts, row_of)
-    within = ranks - row_starts                            # 1-based in-row rank
+    # rank within the row: global rank minus everything before the row,
+    # which is where the rank's run of equal `row_of` began — a scan over
+    # the ranks, not a third gather of (row_ends - row_counts)
+    within = ranks - kernels.run_starts(row_of)           # 1-based in-row rank
     rows = _sorted_gather(m2, row_of).astype(jnp.bfloat16)  # [keep, 128]
     tri = jnp.tril(jnp.ones((lanes, lanes), jnp.bfloat16))
     # inclusive in-row prefix on the MXU; counts <= 128 are bf16-exact
@@ -331,8 +334,10 @@ def packed_indices_monotone(idx: Array) -> Array:
     The known violation is a non-finite gradient: NaNs compare false
     against the Top-K threshold, the mask underfills, and the pack pads
     trailing ranks with duplicate index 0 — at which point the hinted
-    scatters in `_scatter_combine` and the EF zeroing are undefined rather
-    than benignly degraded.  Run with this check (outside the hot path —
+    scatters in `_scatter_combine` and the sharded transport's EF zeroing
+    are undefined rather than benignly degraded (the allgather Top-K
+    residual scatters nothing and `comm/topk_underfull` counts such groups).
+    Run with this check (outside the hot path —
     it is a debug aid, not a runtime guard) when chasing corruption under
     suspected overflow/NaN gradients; tests/test_wire_sharded.py pins both
     directions of the predicate.
@@ -371,7 +376,10 @@ def _leaf_sync_randomk(flat: Array, key: Array, keep: int, axis_name: str, world
 
 
 def _leaf_sync_topk(flat: Array, keep: int, axis_name: str, world,
-                    want_surplus: bool = False, t=None):
+                    want_ef: bool, t=None):
+    """Element Top-K on the allgather transport: ``(dense, new_ef, bits,
+    count)``, ``count`` the survivors of the threshold (``keep`` of them
+    travel)."""
     # threshold-select + hierarchical pack instead of lax.top_k's full sort;
     # near-threshold membership can differ from exact top-k by a few elements
     # at the histogram's final-bin resolution (error feedback reabsorbs the
@@ -379,6 +387,7 @@ def _leaf_sync_topk(flat: Array, keep: int, axis_name: str, world,
     # packed_indices_from_mask requires.
     from tpu_compressed_dp.ops import kernels
 
+    n = flat.shape[0]
     mag = jnp.abs(flat).astype(jnp.float32)
     if t is None:
         t = kernels.topk_threshold(mag, keep)
@@ -387,11 +396,21 @@ def _leaf_sync_topk(flat: Array, keep: int, axis_name: str, world,
     g_vals = _all_gather(payload, axis_name)       # [W, k]
     g_idx = _all_gather(idx, axis_name)            # [W, k]
     dense = _scatter_combine(flat.shape, flat.dtype, g_idx, g_vals, world)
-    # above-threshold survivors beyond `keep` (histogram bin-resolution ties/
-    # surplus) are truncated by ascending index; with EF off they are silently
-    # dropped — surface the count so callers can see it (ADVICE r2)
-    surplus = jnp.maximum(count - keep, 0) if want_surplus else None
-    return dense, idx, surplus, bits
+    new_ef = None
+    if want_ef:
+        # EF residual = the coordinates that did NOT travel.  Survivors
+        # travel by ascending index and are cut at `keep`, so the sent set
+        # is every survivor up to the last packed index (every survivor of
+        # an underfull mask: a NaN gradient, `comm/topk_underfull`), and
+        # the residual is one streamed pass with the compare that chose the
+        # payload — no `keep`-sized scatter into a copy of `flat`, and no
+        # index row promised unique to XLA that a NaN could break.
+        # Survivors beyond `keep` (ties, the final bin's surplus) lie after
+        # the last packed index and stay in the residual.
+        upto_last = jnp.arange(n, dtype=jnp.int32) <= idx[keep - 1]
+        sent = (mag >= t) & (upto_last | (count < keep))
+        new_ef = jnp.where(sent, 0, flat)
+    return dense, new_ef, bits, count
 
 
 def _leaf_sync_topk_seg(flat: Array, keep: int, axis_name: str, world,
@@ -1146,14 +1165,15 @@ def make_wire_grad_sync(cfg, axis_name: str = "data", *,
                         0.0, agree,
                         {} if want_ef
                         else {"topk_surplus_dropped": dropped}, None)
-            # with EF on the surplus is reabsorbed by the residual; with EF
-            # off it is a real (silent) drop — count and report it
-            dense, idx, surplus, bits = _leaf_sync_topk(
-                acc, keep, axis_name, world, want_surplus=not want_ef, t=t)
-            if surplus is not None:
-                new_ef = None
-                return (dense, new_ef, float(keep), bits, 0.0, agree,
-                        {"topk_surplus_dropped": surplus}, None)
+            dense, new_ef, bits, count = _leaf_sync_topk(
+                acc, keep, axis_name, world, want_ef, t)
+            ovf = {"topk_underfull": count < keep}
+            if not want_ef:
+                # with EF on the surplus is reabsorbed by the residual; with
+                # EF off it is a real (silent) drop — count and report it
+                # (ADVICE r2)
+                ovf["topk_surplus_dropped"] = jnp.maximum(count - keep, 0)
+            return dense, new_ef, float(keep), bits, 0.0, agree, ovf, None
         elif comp.name == "blocktopk":
             if keep >= n:
                 # every block selected (leaves <= block_size always are, and
@@ -1187,22 +1207,14 @@ def make_wire_grad_sync(cfg, axis_name: str = "data", *,
                 acc, key, cfg.resolved_terngrad_chunk, axis_name, world)
         else:  # qsgd
             dense, bits = _leaf_sync_qsgd(acc, key, cfg.qstates, axis_name, world)
-        # EF residual = the coordinates that did NOT travel; zeroing the sent
-        # ones in place of building a dense local reconstruction saves a full
-        # scatter + elementwise pass at model scale.  EF with quantizers is
-        # rejected at build time, so want_ef implies a sparsifier —
-        # and sparsifier idx is ascending-unique (packed_indices_from_mask).
-        # PRECONDITION (ADVICE r5): ascending-unique holds only for FINITE
-        # gradients — the hints here and in _scatter_combine assume
-        # count(mag >= t) >= keep, and NaNs compare false against every
-        # threshold, starving the mask below keep so the pack pads trailing
-        # ranks with duplicate index 0.  The sorted/unique hints then
-        # mis-describe the scatter and its result is undefined rather than
-        # benignly degraded (tests/test_wire_sharded.py pins the predicate
-        # via packed_indices_monotone).  A NaN gradient has already
-        # destroyed the step; the contract here is only that we never
-        # promise XLA an invariant a NaN can silently break without the
-        # debug predicate being able to see it.
+        # What falls through: Random-K and the quantizers.  EF with
+        # quantizers is rejected at build time, so want_ef implies Random-K,
+        # whose indices follow no threshold: its residual zeroes the sent
+        # coordinates by a scatter (in place of building a dense local
+        # reconstruction: a full scatter + elementwise pass at model scale).
+        # Its idx is ascending-unique whatever the gradient holds (the mask
+        # comes from the key with exactly `keep` set bits, `randomk_mask`),
+        # so the hints are truthful.
         new_ef = (acc.at[idx].set(0, indices_are_sorted=True,
                                   unique_indices=True,
                                   mode="promise_in_bounds")
@@ -1241,8 +1253,10 @@ def make_wire_grad_sync(cfg, axis_name: str = "data", *,
         new_ef_leaves = [None] * len(leaves)
         agrees = []
         # per-kind clip counters: threshold_overflow (capacity vs survivor
-        # count), topk_surplus_dropped (EF-off tie surplus), shard_overflow
-        # (sharded-transport route/return clips) — a leaf may report several
+        # count), topk_surplus_dropped (EF-off tie surplus), topk_underfull
+        # (allgather Top-K groups with fewer survivors than keep),
+        # shard_overflow (sharded-transport route/return clips) — a leaf may
+        # report several
         overflows: Dict[str, list] = {}
         sent = 0.0
         bits = 0.0
@@ -1355,7 +1369,10 @@ def make_wire_grad_sync(cfg, axis_name: str = "data", *,
             # threshold_overflow: survivors clipped by the fixed capacity
             # (0 = cap was enough).  topk_surplus_dropped: above-threshold
             # survivors beyond keep, truncated by ascending index (ADVICE
-            # r2).  shard_overflow: coordinates clipped by the sharded
+            # r2).  topk_underfull: groups whose threshold left fewer
+            # survivors than keep (0 on finite gradients; else the residual
+            # zeroed every survivor and the payload is padded).
+            # shard_overflow: coordinates clipped by the sharded
             # transport's route/return capacities (EF reabsorbs them when
             # on; this worker's route clips + this owner's return clips).
             stats[k] = jnp.sum(jnp.concatenate(vs)).astype(jnp.float32)
