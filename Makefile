@@ -8,15 +8,13 @@
 #   make lint-diff   pre-commit path: lint only files changed vs REV
 #   make test-quick  the ~90 s iteration tier (pytest -m quick)
 #   make test        full tier-1 (everything not marked slow)
-#   make perf-gate   re-price benchmarks/perf_pins.json through the
-#                    digital twin; fails on a modeled regression
 #   make postmortem  DIR=<shared run dir>: merge blackbox bundles and
 #                    print the root-cause verdict
 
 PY ?= python
 REV ?= HEAD~1
 
-.PHONY: lint lint-diff test test-quick perf-gate postmortem
+.PHONY: lint lint-diff test test-quick postmortem
 
 lint:
 	$(PY) -m pytest tests/test_lint.py::test_ruff_gate -q
@@ -31,9 +29,6 @@ test-quick:
 
 test:
 	$(PY) -m pytest tests/ -q -m 'not slow'
-
-perf-gate:
-	$(PY) tools/twin_report.py --records . --gate
 
 postmortem:
 	$(PY) tools/postmortem.py $(DIR)

@@ -70,31 +70,6 @@ def test_projection_method_aware_topk_vs_randomk(mesh8):
     assert 25.0 < ratio < 40.0
 
 
-def test_run_point_phase_breakdown(mesh8):
-    """--phase_breakdown: topk wire rows carry per-phase ms columns from
-    the stage ladders (obs/trace.py taxonomy) plus the live pallas_mode
-    column; non-topk rows carry none (the ladder is the topk wire chain).
-    Sixteenth-scale model: the assertions are schema, not timings."""
-    common = dict(model="resnet9", granularity="entiremodel", mode="wire",
-                  ratio=0.01, batch_size=64, steps=2, warmup=1, devices=8,
-                  channels_scale=0.0625, phase_breakdown=True)
-    rec = sweep.run_point(method="topk", **common)
-    for k in ("phase_compress_ms", "phase_reduce_ms", "phase_ef_ms",
-              "phase_update_ms"):
-        assert k in rec and rec[k] >= 0.0
-    assert rec["phase_compress_ms"] > 0.0
-    assert rec["pallas_mode"] in ("auto", "off", "force")
-
-
-@pytest.mark.slow
-def test_run_point_phase_breakdown_skips_non_topk(mesh8):
-    rec_q = sweep.run_point(
-        method="terngrad", model="resnet9", granularity="entiremodel",
-        mode="wire", ratio=0.01, batch_size=64, steps=2, warmup=1,
-        devices=8, channels_scale=0.0625, phase_breakdown=True)
-    assert not any(k.startswith("phase_") for k in rec_q)
-
-
 def test_run_adaptive_point_schema_and_convergence(mesh8):
     """BENCH_r09 protocol: the closed-loop record carries the per-window
     trajectory + per-rung static baselines, and with a budget only the

@@ -202,170 +202,6 @@ def test_topk_threshold_jnp_fallback_guarantee():
         assert cnt <= keep + max(8, int(0.01 * n))
 
 
-class TestPackByThreshold:
-    """Fused wire-pack kernel (VERDICT r2 #4): correct but slower than the
-    unfused chain on this chip — kept in-tree as a measured negative result
-    (benchmarks/pack_kernel_r3.txt), NOT dispatched by the wire path."""
-
-    def _check(self, n, keep, seed=0):
-        import jax
-        import jax.numpy as jnp
-        import numpy as np
-
-        from tpu_compressed_dp.ops import kernels as K
-
-        rng = np.random.default_rng(seed)
-        acc = jnp.asarray(rng.standard_normal(n), jnp.float32)
-        t = jnp.asarray(
-            np.partition(np.abs(np.asarray(acc)), n - keep)[n - keep],
-            jnp.float32)
-        vals, idx, ef, count = K.pack_by_threshold(
-            acc, t, keep, want_ef=True, interpret=True)
-        mask = np.asarray(jnp.abs(acc) >= t)
-        a = np.asarray(acc)
-        dense = np.zeros(n, np.float64)
-        np.add.at(dense, np.asarray(idx), np.asarray(vals, np.float64))
-        np.testing.assert_allclose(dense, np.where(mask, a, 0.0),
-                                   rtol=1e-6, atol=1e-7)
-        np.testing.assert_allclose(np.asarray(ef), np.where(mask, 0.0, a))
-        assert int(count) == mask.sum()
-        nz = np.asarray(vals) != 0
-        assert nz.sum() == mask.sum()
-        assert np.all(np.diff(np.asarray(idx)[nz]) > 0)  # ascending payload
-
-    def test_small_pack(self, monkeypatch):
-        from tpu_compressed_dp.ops import kernels as K
-
-        monkeypatch.setattr(K, "_PACK_ROWS", 16)  # interpreter-tractable
-        self._check(5000, 50)
-
-    def test_multiblock_and_ragged(self, monkeypatch):
-        from tpu_compressed_dp.ops import kernels as K
-
-        monkeypatch.setattr(K, "_PACK_ROWS", 16)
-        self._check(17000, 700)   # multi-block + ragged tail
-        self._check(40000, 350)
-
-    def test_payload_slots_accounting(self):
-        from tpu_compressed_dp.ops import kernels as K
-
-        P = K.pack_payload_slots(5_000_000, 50_000)
-        blocks = -(-5_000_000 // (K._PACK_ROWS * 128))
-        assert P == -(-50_000 // 128) * 128 + blocks * 128
-
-    def test_capacity_truncation_conserves_mass(self, monkeypatch):
-        """Overflow regime (survivors >> capacity): payload + residual must
-        still reconstruct acc exactly — truncated blocks keep ALL their
-        survivors in the residual, the payload carries no garbage, and
-        `count` reports what actually shipped (review r3 findings)."""
-        import jax
-        import jax.numpy as jnp
-        import numpy as np
-
-        from tpu_compressed_dp.ops import kernels as K
-
-        monkeypatch.setattr(K, "_PACK_ROWS", 16)
-        rng = np.random.default_rng(3)
-        n, keep = 8192, 128
-        acc = jnp.asarray(rng.standard_normal(n), jnp.float32)
-        t = jnp.asarray(0.01, jnp.float32)  # ~99% survive: massive overflow
-        vals, idx, ef, count = K.pack_by_threshold(
-            acc, t, keep, want_ef=True, interpret=True)
-        a = np.asarray(acc)
-        dense = np.zeros(n, np.float64)
-        np.add.at(dense, np.asarray(idx), np.asarray(vals, np.float64))
-        # payload + residual == acc for surviving coords; residual == acc
-        # for non-survivors; nothing lost, nothing duplicated
-        np.testing.assert_allclose(dense + np.asarray(ef, np.float64), a,
-                                   rtol=1e-6, atol=1e-7)
-        nz = np.asarray(vals) != 0
-        assert int(count) == nz.sum()          # count == shipped survivors
-        assert nz.sum() < np.count_nonzero(np.abs(a) >= 0.01)  # truncated
-        assert np.all(np.asarray(idx) < n)     # no uninitialised garbage
-
-
-@pytest.mark.quick
-class TestSegPack:
-    """Segmented shift-network pack (round 4, the r3 follow-up): per-4096-
-    element-segment compaction via log-round static rolls — no per-element
-    dynamic stores, no one-hot materialisation (the two measured r3 walls)."""
-
-    def _ref(self, x, t, keep):
-        import numpy as np
-
-        n = len(x)
-        m = np.abs(x) >= t
-        out_v, out_i, elig_mask = [], [], np.zeros(n, bool)
-        for s in range(-(-n // 4096)):
-            seg = slice(s * 4096, min((s + 1) * 4096, n))
-            idx = np.nonzero(m[seg])[0][:128] + s * 4096
-            out_v.extend(x[idx])
-            out_i.extend(idx)
-            elig_mask[idx] = True
-        pad = keep - len(out_v[:keep])
-        sent = np.nonzero(elig_mask)[0][:keep]
-        ef = x.copy()
-        ef[sent] = 0.0
-        return (np.concatenate([out_v[:keep], np.zeros(pad)]),
-                np.concatenate([out_i[:keep], np.zeros(pad, int)]), ef)
-
-    def _check(self, n, t, keep, seed=0):
-        import numpy as np
-
-        from tpu_compressed_dp.ops import kernels as K
-
-        rng = np.random.default_rng(seed)
-        x = rng.standard_normal(n).astype(np.float32)
-        vals, idx, new_ef, elig, counts = K.seg_pack_by_threshold(
-            jnp.asarray(x), jnp.float32(t), keep, interpret=True)
-        pv, pi = K.seg_pack_payload(vals, idx, elig, keep)
-        rv, ri, ref_ef = self._ref(x, t, keep)
-        np.testing.assert_allclose(np.asarray(pv), rv, rtol=1e-6)
-        assert np.array_equal(np.asarray(pi), ri)
-        np.testing.assert_allclose(np.asarray(new_ef), ref_ef, rtol=1e-6)
-        assert np.array_equal(np.asarray(elig),
-                              np.minimum(np.asarray(counts), 128))
-
-    def test_sparse_multi_segment(self):
-        self._check(13000, 2.0, 150)
-
-    def test_cap_overflow_spills_to_ef(self):
-        # t=0.5 -> ~60% survivors, far beyond the 128/4096 cap: overflow must
-        # stay in the residual and later survivors take the payload slots
-        self._check(9000, 0.5, 200, seed=3)
-
-    def test_keep_truncation_and_ragged_tail(self):
-        self._check(4096 * 2 + 777, 1.5, 64, seed=5)
-
-    def test_want_ef_off(self):
-        import numpy as np
-
-        from tpu_compressed_dp.ops import kernels as K
-
-        rng = np.random.default_rng(7)
-        x = rng.standard_normal(6000).astype(np.float32)
-        vals, idx, new_ef, elig, _ = K.seg_pack_by_threshold(
-            jnp.asarray(x), jnp.float32(2.0), 40, want_ef=False,
-            interpret=True)
-        assert new_ef is None
-        pv, pi = K.seg_pack_payload(vals, idx, elig, 40)
-        rv, ri, _ = self._ref(x, 2.0, 40)
-        np.testing.assert_allclose(np.asarray(pv), rv, rtol=1e-6)
-        assert np.array_equal(np.asarray(pi), ri)
-
-    def test_dispatch_gate(self, monkeypatch):
-        from tpu_compressed_dp.ops import kernels as K
-
-        # OFF by default everywhere (round-4 measured tie vs the unfused
-        # chain, with selection degradation on concentrated gradients)
-        assert not K.use_seg_pack(1 << 20, (1 << 20) // 100)
-        monkeypatch.setattr(K, "_SEG_PACK_DISPATCH", True)
-        # density gate: keep/n beyond half the cap ratio -> exact global pack
-        assert not K.use_seg_pack(1 << 20, (1 << 20) // 10)
-        # int32 gate
-        assert not K.use_seg_pack((1 << 31) + 10, 1000)
-
-
 class TestFusedSelectPack:
     """One-pass select+pack vs the XLA mask -> packed_indices_from_mask ->
     sorted-gather chain: the payloads must be BITWISE identical (values,
@@ -403,6 +239,55 @@ class TestFusedSelectPack:
         assert np.array_equal(np.asarray(fi), np.asarray(xi))
         assert np.array_equal(np.asarray(fv), np.asarray(xv))
         assert int(fc) == int(xc)
+        assert fv.dtype == flat.dtype
+
+    T = 2.0     # the threshold the placed cases are built around
+
+    def _placed(self, case, dtype):
+        """A vector whose survivors of ``|x| >= T`` sit where ``case`` puts
+        them, over noise under ``T / 2``; every value exact in bfloat16."""
+        seg = kernels._SEG
+        rng = np.random.default_rng(len(case))
+        n = 12 * seg if case == "empty_segments" else 2 * seg + 777
+        x = rng.integers(-63, 64, n) / 64.0
+        if case == "empty_segments":
+            # a few, many, one and every element of a segment, empty
+            # segments between them and at both ends
+            per_seg = {1: 3, 4: 200, 5: 1, 9: seg, 10: 50}
+            where = np.concatenate([s * seg + rng.choice(seg, c, replace=False)
+                                    for s, c in per_seg.items()])
+        else:
+            count = {"ragged_tail": 64, "ties": 300, "underfull": 40}[case]
+            where = rng.choice(n, count, replace=False)
+            # the first and the last element of the tail beyond the segments
+            where[:2] = [2 * seg, n - 1]
+        keep = {"ties": 100, "underfull": 64}.get(case, len(where))
+        mags = self.T if case == "ties" else self.T + rng.integers(0, 64, len(where)) / 32.0
+        x[where] = mags * rng.choice([-1.0, 1.0], len(where))
+        return jnp.asarray(x, dtype), keep, len(set(where.tolist()))
+
+    # survivors placed by hand around a fixed threshold: the layouts a
+    # random draw with its own top-k threshold never produces
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                             ids=["f32", "bf16"])
+    @pytest.mark.parametrize("case", ["empty_segments", "ragged_tail", "ties",
+                                      "underfull"])
+    def test_bitwise_parity_placed_survivors(self, case, dtype):
+        flat, keep, count = self._placed(case, dtype)
+        mag = jnp.abs(flat).astype(jnp.float32)
+        t = jnp.float32(self.T)
+        fv, fi, fc = kernels.fused_select_pack(flat, t, keep, interpret=True)
+        xv, xi, xc = self._xla(flat, mag, t, keep)
+        assert int(fc) == int(xc) == count
+        assert (count > keep) == (case == "ties")
+        live = min(count, keep)
+        assert (live < keep) == (case == "underfull")
+        assert np.array_equal(np.asarray(fi), np.asarray(xi))
+        assert np.array_equal(np.asarray(fv)[:live], np.asarray(xv)[:live])
+        # the first `keep` survivors by ascending index, nothing else
+        want = np.flatnonzero(np.asarray(mag) >= self.T)[:keep]
+        assert np.array_equal(np.asarray(fi)[:live], want)
+        assert not np.any(np.asarray(fv, np.float32)[live:])
         assert fv.dtype == flat.dtype
 
     def test_blocktopk_scores_parity(self):

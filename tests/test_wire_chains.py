@@ -5,9 +5,9 @@ ones on their own buffers with their threshold searches in one loop.  Every
 member's result must be bitwise what a chain of its own gives, and the number
 of chains is the number of distinct parts, whatever the number of leaves.
 
-Tier-1 (tests/test_wire.py is marked slow as a whole): small trees, virtual
-devices, kernels under the interpreter where a case forces them; the ResNet
-trees are abstract and nothing of them is compiled.
+Tier-1: small trees, virtual devices, kernels under the interpreter where a
+case forces them; the ResNet trees are abstract and nothing of them is
+compiled.
 """
 
 import collections
@@ -367,3 +367,62 @@ def test_topk_underfull_counts_the_groups_under_keep(monkeypatch, underfull):
     assert len(sent) == keep_b - underfull
     np.testing.assert_array_equal(
         sent, np.sort(np.argsort(-np.abs(b), kind="stable")[:len(sent)]))
+
+
+# --- more survivors at the threshold than `keep`: the surplus stays in the
+# --- residual, or is counted where there is none; nothing is lost or doubled
+
+@pytest.mark.parametrize("world", [1, 4])
+@pytest.mark.parametrize("error_feedback", [True, False], ids=["ef", "no-ef"])
+def test_surplus_at_the_threshold_stays_or_is_counted(monkeypatch, world,
+                                                      error_feedback):
+    tpu_dispatch(monkeypatch, interpret=True)
+    cfg = CompressionConfig(mode="wire", granularity="layerwise", method="topk",
+                            ratio=0.01, error_feedback=error_feedback)
+    shapes = {"long": (BIG + 4464,), "short": (1000,)}
+    assert kernels.use_select_pack(BIG + 4464, 700)
+    rng = np.random.default_rng(29)
+
+    def halves():
+        # multiples of 1/2: many coordinates share the keep-th magnitude, and
+        # the workers' sums and their mean over four are exact in float32
+        return {k: jnp.asarray(np.round(2 * rng.standard_normal((world,) + s)) / 2,
+                               jnp.float32) for k, s in shapes.items()}
+
+    grads = halves()
+    resid = halves() if error_feedback else ()
+    sync = wire.make_wire_grad_sync(cfg, "data")
+
+    def one(g, e):
+        out = sync(jax.tree.map(lambda x: x[0], g),
+                   jax.tree.map(lambda x: x[0], e), jax.random.key(0))
+        return jax.tree.map(lambda x: x[None], out)
+
+    spec = jax.tree.map(lambda _: P("data"), (grads, resid))
+    out, new_ef, stats = jax.jit(shard_map(
+        one, mesh=make_data_mesh(world), in_specs=spec, out_specs=P("data"),
+        check_vma=False))(grads, resid)
+
+    surplus = np.zeros(world)
+    for k in shapes:
+        acc = np.asarray(grads[k]) + (np.asarray(resid[k]) if error_feedback else 0)
+        keep = wire.compressors.topk_keep_count(acc.shape[1], cfg.ratio)
+        want_ef = acc.copy()
+        for w in range(world):
+            t = np.sort(np.abs(acc[w]))[-keep]
+            survivors = np.flatnonzero(np.abs(acc[w]) >= t)
+            assert len(survivors) > keep            # the case: ties at t
+            surplus[w] += len(survivors) - keep
+            want_ef[w, survivors[:keep]] = 0        # cut by ascending index
+        # what travelled and what stayed behind add up to the gradient
+        for w in range(world):
+            np.testing.assert_array_equal(np.asarray(out[k])[w],
+                                          (acc - want_ef).sum(0) / world)
+        if error_feedback:
+            np.testing.assert_array_equal(np.asarray(new_ef[k]), want_ef)
+    if error_feedback:
+        assert "topk_surplus_dropped" not in stats      # reabsorbed
+    else:
+        np.testing.assert_array_equal(np.asarray(stats["topk_surplus_dropped"]),
+                                      surplus)
+    np.testing.assert_array_equal(np.asarray(stats["topk_underfull"]), 0.0)

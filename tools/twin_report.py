@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""Digital-twin report: calibration, modeled-vs-measured, scale-out, gate.
+"""Digital-twin report: calibration, modeled-vs-measured, scale-out.
 
 Fits the per-fabric alpha/beta/gamma cost model
 (``tpu_compressed_dp/twin/``) from the repo's committed BENCH/MULTICHIP
@@ -9,23 +9,18 @@ records and renders:
     (ms/hop) per fabric with the row count that identified each, plus
     the per-context compute anchors;
   * the **modeled-vs-measured tables** — every step row and every
-    ``--phase_breakdown`` comm-phase row with its residual, worst first
-    flagged (the tier-1 suite asserts every step row lands within 15%);
+    recorded comm-phase row (the ``phase_<name>_ms`` columns of the
+    committed BENCH rows) with its residual, worst first flagged (the
+    tier-1 suite asserts every step row lands within 15%);
   * the **scale-out projection** — each measured config re-priced at
     W in {64, 256, 1024, 4096} chips (pods = W / pod_size), i.e. the
     digital-twin answer to "what would this run cost on a real pod
-    slice", with a blank where the target fabric has no calibration;
-  * the **perf gate** (``--gate``) — every pin in
-    ``benchmarks/perf_pins.json`` re-priced through the current model,
-    exit 1 on a modeled regression beyond tolerance (the tier-1 perf
-    ratchet); ``--update_pins`` re-mints every pin at the current price.
+    slice", with a blank where the target fabric has no calibration.
 
 Usage::
 
     python tools/twin_report.py                     # full report
     python tools/twin_report.py --json              # machine-readable
-    python tools/twin_report.py --gate              # pin check, rc=1 on fail
-    python tools/twin_report.py --update_pins       # re-mint stale pins
 """
 
 from __future__ import annotations
@@ -41,8 +36,8 @@ if __package__ in (None, ""):  # script run: repo root onto sys.path
     sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from tpu_compressed_dp.twin import (
-    calibration_rows, check_pins, discover_record_paths, fit, load_pins,
-    load_record_file, make_pin, save_calibration,
+    calibration_rows, discover_record_paths, fit, load_record_file,
+    save_calibration,
 )
 
 PROJECTION_WORLDS = (64, 256, 1024, 4096)
@@ -151,36 +146,14 @@ def render_projection(proj: List[Dict[str, Any]]) -> List[str]:
     return lines
 
 
-def render_gate(results) -> List[str]:
-    lines = ["perf gate:"]
-    lines.append(f"  {'pin':<36}{'pinned':>10}{'modeled':>10}"
-                 f"{'change':>9}{'tol':>6}  verdict")
-    for r in results:
-        frac = r.frac_change
-        lines.append(
-            f"  {r.name:<36}{r.pinned_ms:>10.1f}{_f(r.modeled_ms)}"
-            + (f"{frac:>9.1%}" if frac is not None else f"{'-':>9}")
-            + f"{r.tol_frac:>6.0%}  "
-            + ("ok" if r.ok else "FAIL") + f" — {r.note}")
-    n_bad = sum(1 for r in results if not r.ok)
-    lines.append(f"  {len(results)} pin(s), {n_bad} failing")
-    return lines
-
-
 def main(argv: Optional[List[str]] = None) -> int:
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument("--records", default=".",
                    help="dir holding BENCH_r*/MULTICHIP_r* artifacts")
-    p.add_argument("--pins", default="benchmarks/perf_pins.json",
-                   help="perf-pins file for --gate / --update_pins")
     p.add_argument("--pod_size", type=int, default=64,
                    help="chips per pod in the scale-out projection")
     p.add_argument("--json", action="store_true",
                    help="emit the full report as JSON")
-    p.add_argument("--gate", action="store_true",
-                   help="re-price the pins; exit 1 on any regression")
-    p.add_argument("--update_pins", action="store_true",
-                   help="re-mint every pin at the current modeled price")
     p.add_argument("--save_calibration", default=None,
                    help="also write the fitted calibration JSON here")
     args = p.parse_args(argv)
@@ -194,23 +167,6 @@ def main(argv: Optional[List[str]] = None) -> int:
     calib = fit(rows)
     if args.save_calibration:
         save_calibration(calib, args.save_calibration)
-
-    if args.update_pins:
-        doc = load_pins(args.pins)
-        doc["pins"] = [
-            make_pin(pin["name"], pin["point"], pin["context"], calib,
-                     tol_frac=float(pin.get("tol_frac",
-                                            doc.get("tolerance_frac", 0.10))))
-            for pin in doc["pins"]]
-        with open(args.pins, "w") as f:
-            json.dump(doc, f, indent=1, sort_keys=True)
-            f.write("\n")
-        print(f"re-minted {len(doc['pins'])} pin(s) in {args.pins}")
-        return 0
-
-    gate_results = None
-    if args.gate:
-        gate_results = check_pins(load_pins(args.pins), calib)
 
     proj = projection_rows(paths, calib, pod_size=args.pod_size)
 
@@ -226,21 +182,13 @@ def main(argv: Optional[List[str]] = None) -> int:
                           for r in calib.residuals],
             "projection": proj,
         }
-        if gate_results is not None:
-            doc["gate"] = [dict(dataclasses.asdict(r),
-                                frac_change=r.frac_change)
-                           for r in gate_results]
         print(json.dumps(doc, indent=1, sort_keys=True))
-    elif args.gate:
-        print("\n".join(render_gate(gate_results)))
     else:
         lines = render_calibration(calib)
         lines += render_residuals(calib)
         lines += render_projection(proj)
         print("\n".join(lines))
 
-    if gate_results is not None and any(not r.ok for r in gate_results):
-        return 1
     return 0
 
 

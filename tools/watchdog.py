@@ -22,9 +22,8 @@ Checks (see :func:`tpu_compressed_dp.utils.resilience.check_heartbeat`):
     alive and applying updates, but crawling.
   * **slow tail** — telemetry ``step_p95_ms`` above ``--max_step_p95_ms``:
     the mean rate still passes but the tail latency regressed past the
-    run's budget (set it from the digital twin's modeled step time, e.g.
-    the matching ``benchmarks/perf_pins.json`` pin x 1.1 — the perf gate
-    enforced live).
+    run's budget (set it from the run's own steady ``step_p95_ms``, or
+    the digital twin's modeled step time, x 1.1).
   * **checkpoint-stale** — heartbeat ``ckpt_age_s`` (plus the heartbeat's
     own age) exceeds ``--max_ckpt_age``: the run is making progress it
     could not recover — a crash now loses that much work.

@@ -82,13 +82,12 @@ REPLAY_DETERMINISTIC_MODULES = (
     "tpu_compressed_dp/stream/writer.py",
     "tpu_compressed_dp/stream/reader.py",
     "tpu_compressed_dp/stream/rejoin.py",
-    # the digital twin's fit/predict core: calibrations and pin verdicts
+    # the digital twin's fit/predict core: calibrations and predictions
     # must be pure functions of the committed artifacts — same records,
-    # same model, bitwise — so the perf gate is reproducible in CI
+    # same model, bitwise
     "tpu_compressed_dp/twin/model.py",
     "tpu_compressed_dp/twin/records.py",
     "tpu_compressed_dp/twin/calibrate.py",
-    "tpu_compressed_dp/twin/gate.py",
 )
 
 #: modules that write records other processes read over shared storage —

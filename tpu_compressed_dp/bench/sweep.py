@@ -131,77 +131,6 @@ def _build_model(name: str, image_size: int, num_classes: int,
     )
 
 
-def _phase_breakdown_cols(cfg, mesh, n: int, keep: int, opt, params,
-                          iters: int) -> Dict[str, float]:
-    """Per-phase ms columns (`phase_<name>_ms`, obs/trace.py taxonomy) via
-    the tools/wire_profile stage ladders at the model's FLAT gradient size:
-    cumulative prefix chains, per-phase cost = rung difference, so XLA
-    cannot DCE a stage out of a longer rung.  The select+pack and bucket
-    rungs ride the live `kernels.pallas_mode()` dispatch — a BENCH row pair
-    (--pallas off vs auto/force) prices the fused kernels on identical
-    phase boundaries.  The ladders are the element Top-K wire chain, so
-    callers emit these columns for topk wire points only; `update` is the
-    optimizer apply, timed on the real param tree."""
-    from jax.sharding import PartitionSpec as P
-
-    from tools import wire_profile as wp
-    from jax import shard_map
-
-    if cfg.transport == "sharded":
-        stages = wp.SHARDED_STAGES
-        build = lambda st: wp._sharded_chain(st, n, keep, cfg)
-        phase_of = {"mag": "compress", "threshold": "compress",
-                    "select_pack": "compress", "route": "route",
-                    "reduce": "reduce", "return": "return", "ef": "ef"}
-    elif cfg.transport == "hierarchical":
-        stages = wp.HIER_STAGES
-        build = lambda st: wp._hier_chain(st, n, keep, cfg)
-        phase_of = {"mag": "compress", "threshold": "compress",
-                    "pack": "compress", "ici_reduce": "ici_reduce",
-                    "recompress": "recompress", "dcn_route": "route",
-                    "return": "return", "ef": "ef"}
-    else:
-        stages = wp.DISPATCH_STAGES
-        build = lambda st: wp._dispatch_chain(st, n, keep)
-        phase_of = {"mag": "compress", "threshold": "compress",
-                    "select_pack": "compress", "combine": "reduce",
-                    "ef": "ef"}
-
-    x = jax.device_put(jax.random.normal(jax.random.key(7), (n,),
-                                         jnp.float32))
-    cols: Dict[str, float] = {}
-    prev = 0.0
-    for st in stages:
-        fn = jax.jit(shard_map(build(st), mesh=mesh, in_specs=P(),
-                               out_specs=P()))
-        dt = wp.time_fn(fn, x, iters, warmup_s=0.5) * 1e3
-        key = f"phase_{phase_of[st]}_ms"
-        cols[key] = cols.get(key, 0.0) + max(dt - prev, 0.0)
-        prev = dt
-
-    # the measured train steps donated the original param buffers; only
-    # their shape/dtype metadata survives — materialize fresh ones
-    params = jax.tree.map(lambda p: jnp.zeros(p.shape, p.dtype), params)
-    grads = jax.tree.map(
-        lambda p: jax.random.normal(jax.random.key(11), p.shape, p.dtype),
-        params)
-    opt_state = opt.init(params)
-    step_c = jnp.zeros((), jnp.int32)
-
-    def upd(p, g, s):
-        new_p, new_s = opt.apply(p, g, s, step_c)
-        return jax.tree.leaves(new_p)[0].ravel()[:8]
-
-    fn = jax.jit(upd)
-    jax.device_get(fn(params, grads, opt_state))  # compile + warm
-    t0 = time.perf_counter()
-    for _ in range(iters):
-        out = fn(params, grads, opt_state)
-    jax.device_get(out)
-    cols["phase_update_ms"] = (time.perf_counter() - t0) / iters * 1e3
-    return {k: round(v, 4) for k, v in cols.items()}
-
-
 def run_point(
     *,
     model: str = "resnet9",
@@ -231,7 +160,6 @@ def run_point(
     devices: Optional[int] = None,
     project_devices: int = 32,
     channels_scale: float = 1.0,
-    phase_breakdown: bool = False,
 ) -> Dict[str, float]:
     """Measure one grid point; returns a flat record (also JSON-serialisable).
 
@@ -412,22 +340,6 @@ def run_point(
                 "projected_allreduce_gbps_per_chip": round(p_gbps, 6),
                 "projected_dense_allreduce_gbps_per_chip": round(p_dense_gbps, 6),
             })
-    if phase_breakdown:
-        # the stage ladders are the element Top-K wire chain — breakdown
-        # columns exist for topk wire points only (other rows carry none)
-        if method is not None and canonical_name(method) == "topk" \
-                and mode == "wire":
-            from tpu_compressed_dp.ops import kernels
-            from tpu_compressed_dp.ops.compressors import topk_keep_count
-
-            n_flat = sum(l.size for l in jax.tree.leaves(params))
-            record.update(_phase_breakdown_cols(
-                cfg, mesh, n_flat, topk_keep_count(n_flat, ratio), opt,
-                params, max(steps, 2)))
-            record["pallas_mode"] = kernels.pallas_mode()
-        else:
-            print(f"# phase_breakdown: skipped for {method}/{mode} (ladder "
-                  "covers topk wire points)", file=sys.stderr)
     return record
 
 
@@ -708,11 +620,9 @@ def run_sweep(args) -> List[Dict[str, float]]:
         bucket_mb=args.bucket_mb,
         error_feedback=args.error_feedback,
         sync_overlap=args.overlap,
-        phase_breakdown=args.phase_breakdown,
     )
     print(f"# dense baseline: {args.model}", file=sys.stderr)
-    emit(run_point(method=None, **{**common, "error_feedback": False,
-                                   "phase_breakdown": False}))
+    emit(run_point(method=None, **{**common, "error_feedback": False}))
 
     ranks = [int(r) for r in args.ranks.split(",") if r.strip()]
     for method, gran in itertools.product(methods, grans):
@@ -850,15 +760,6 @@ def build_parser() -> argparse.ArgumentParser:
                    help="hierarchical transport inter-pod bucket capacity, "
                         "in units of slab/P")
     p.add_argument("--tsv", type=str, default=None)
-    p.add_argument("--phase_breakdown", action="store_true",
-                   help="add per-phase ms columns (phase_compress_ms / "
-                        "route / reduce / return / ef / update, plus "
-                        "ici_reduce+recompress for hierarchical) to topk "
-                        "wire grid points via the tools/wire_profile stage "
-                        "ladders at the model's flat gradient size; the "
-                        "compress/route rungs ride the live --pallas "
-                        "dispatch, so an off-vs-auto row pair prices the "
-                        "fused kernels")
     p.add_argument("--pallas", default=None,
                    choices=["auto", "off", "force"],
                    help="pin ops/kernels.pallas_mode() for the whole sweep "

@@ -70,10 +70,6 @@ __all__ = [
     "topk_thresholds",
     "fused_sparsify",
     "use_fused_sparsify",
-    "pack_by_threshold",
-    "seg_pack_by_threshold",
-    "seg_pack_payload",
-    "use_seg_pack",
     "fused_select_pack",
     "use_select_pack",
     "pack_ternary_pallas",
@@ -575,264 +571,9 @@ def use_fused_sparsify(n: int) -> bool:
 
 
 # ---------------------------------------------------------------------------
-# Fused threshold-pack (wire-mode Top-K stream compaction)
+# Fused select+pack (wire-mode Top-K: one-pass threshold select -> payload)
 # ---------------------------------------------------------------------------
-
-# block = _PACK_ROWS x 128 elements assembled in a VMEM scratch then DMA'd
-# to the HBM output at the running ROW offset.  Inner compaction vectorises
-# _PACK_SUB rows at a time ([_PACK_SUB, 128, 128] one-hot reduce).
-_PACK_ROWS = 512
-_PACK_SUB = 8
-
-
-def pack_payload_slots(n: int, keep: int) -> int:
-    """Payload capacity of the packed (vals, idx) buffers: survivors pack
-    tightly WITHIN a block, but block bases are 128-aligned in the output
-    (Mosaic supports dynamic addressing at row granularity only), wasting
-    <128 zero slots per 64k-element block — zeros with idx 0, scatter-add
-    identities.  Transport must be billed at this size."""
-    blocks = -(-max(n, 1) // (_PACK_ROWS * _LANES))
-    return -(-keep // _LANES) * _LANES + blocks * _LANES
-
-
-def _pack_kernel(n: int, cap_rows: int, want_ef: bool, t_ref, x_ref, *refs):
-    """One streaming pass over |acc| >= t: emits the packed (values,
-    indices) payload — ascending index, zero-padded at row-alignment gaps —
-    plus (optionally) the EF residual and the survivor count.
-
-    Replaces the r2 chain threshold-mask -> hierarchical rank -> gather ->
-    EF scatter (4+ passes with element-granular gathers at ~25-50 M/s in
-    the round-2 sessions) with: per-row inclusive prefix via a
-    lower-triangular matmul, in-row one-hot compaction with the row's
-    lane-rotation folded into the one-hot destination (Mosaic has no
-    dynamic element-granular stores OR dynamic 1-D rotates), two
-    dynamic-ROW read-modify-write stores per source row into a zeroed
-    scratch, one fixed-size DMA per block at the block's base row.
-    """
-    if want_ef:
-        vals_ref, idx_ref, ef_ref, count_ref = refs[:4]
-        scratch_v, scratch_i, off_ref, sem_v, sem_i = refs[4:]
-    else:
-        vals_ref, idx_ref, count_ref = refs[:3]
-        scratch_v, scratch_i, off_ref, sem_v, sem_i = refs[3:]
-
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _():
-        off_ref[0] = 0   # rows emitted (all blocks)
-        off_ref[1] = 0   # survivors seen (all blocks)
-        off_ref[2] = 0   # survivors SHIPPED
-        off_ref[3] = 0   # shipped rows end (zero-mask boundary)
-
-    t = t_ref[0, 0]
-    x = x_ref[:]                                   # [_PACK_ROWS, 128]
-    base_pos = i * _PACK_ROWS * _LANES
-    pos = (base_pos
-           + jax.lax.broadcasted_iota(jnp.int32, x.shape, 0) * _LANES
-           + jax.lax.broadcasted_iota(jnp.int32, x.shape, 1))
-    mask = jnp.logical_and(jnp.abs(x) >= t, pos < n)
-    maskf = mask.astype(jnp.float32)
-    tri = jnp.tril(jnp.ones((_LANES, _LANES), jnp.float32))
-    prefix = maskf @ tri.T                          # [R,128] inclusive rank
-    c_row_f = prefix[:, _LANES - 1]                  # survivors/row (fp32 —
-    # exact: block totals <= 65536 << 2^24).  Mosaic has no cumsum; the
-    # exclusive running offsets come from another triangular matmul.
-    tri_r = jnp.tril(jnp.ones((_PACK_ROWS, _PACK_ROWS), jnp.float32))
-    incl = tri_r @ c_row_f                           # [R] inclusive
-    excl_f = incl - c_row_f                          # [R] exclusive (fp32)
-    row_off = excl_f.astype(jnp.int32)
-
-    blk_count = incl[_PACK_ROWS - 1].astype(jnp.int32)
-    base_row = off_ref[0]
-    rows_used = (blk_count + _LANES - 1) // _LANES
-    # a block ships only if it fits WHOLE below the capacity (a spilling
-    # block keeps ALL its survivors in the residual — shipping half while
-    # zeroing the residual for all would lose gradient mass); base always
-    # advances, so truncation is sticky and the payload stays ascending
-    shipped = base_row + rows_used <= cap_rows
-    off_ref[0] = base_row + rows_used
-    off_ref[1] = off_ref[1] + blk_count
-    off_ref[2] = off_ref[2] + jnp.where(shipped, blk_count, 0)
-    off_ref[3] = jnp.where(shipped, base_row + rows_used, off_ref[3])
-    count_ref[0, 0] = off_ref[2]   # survivors actually in the payload
-    count_ref[0, 1] = off_ref[1]   # survivors seen (incl. truncated)
-    count_ref[0, 2] = off_ref[3]   # valid payload rows (zero-mask bound)
-
-    if want_ef:
-        # residual = unshipped coordinates
-        ef_ref[:] = jnp.where(jnp.logical_and(mask, shipped), 0.0, x)
-
-    # ---- in-row compaction with the lane rotation folded in -------------
-    # dest lane of a survivor = (rank-1 + row_off%128) mod 128.  Channels
-    # kept f32-exact for the MXU: values, source LANE (< 128), and the
-    # absolute source ROW id (< n/128 <= 2^24) — idx = row*128 + lane is
-    # reassembled in int32 at the end (a single f32 position channel would
-    # round above 2^24).
-    lane_d = jax.lax.broadcasted_iota(
-        jnp.int32, (_PACK_SUB, _LANES, _LANES), 2
-    ).astype(jnp.float32)  # dest lane iota (tpu.iota is integer-only)
-    lane_src = jax.lax.broadcasted_iota(
-        jnp.int32, (_PACK_SUB, _LANES), 1).astype(jnp.float32)
-    q_all = row_off // _LANES                             # [R] int32
-    rem_all_f = (row_off - q_all * _LANES).astype(jnp.float32)
-    comp_v_parts = []
-    comp_l_parts = []
-    comp_ok_parts = []
-    for s in range(_PACK_ROWS // _PACK_SUB):
-        sl = slice(s * _PACK_SUB, (s + 1) * _PACK_SUB)
-        dest = prefix[sl][:, :, None] - 1.0 + rem_all_f[sl][:, None, None]
-        dest = dest - jnp.where(dest >= _LANES, float(_LANES), 0.0)
-        hitf = (jnp.where(dest == lane_d, 1.0, 0.0)
-                * maskf[sl][:, :, None])                  # [S,src,dst]
-        # batched matvec (einsum rsd,rs->rd) crashes Mosaic — VPU
-        # multiply-sum instead; the MXU work is the 2-D placement matmuls
-        comp_v_parts.append(jnp.sum(hitf * x[sl][:, :, None], axis=1))
-        comp_l_parts.append(jnp.sum(hitf * lane_src[:, :, None], axis=1))
-        comp_ok_parts.append(jnp.sum(hitf, axis=1))
-    comp_v = jnp.concatenate(comp_v_parts)                # [R,128]
-    comp_l = jnp.concatenate(comp_l_parts)
-    comp_ok = jnp.concatenate(comp_ok_parts)              # 1.0 at payload
-
-    # ---- block-level row placement as two MXU matmuls -------------------
-    # Row r's (pre-rotated) payload splits into dst rows q_r (lanes >= rem)
-    # and q_r + 1 (lanes < rem); the placement matrices are one-hots over
-    # dst rows, so stage = Q1 @ hi-part + Q2 @ lo-part — no dynamic stores,
-    # no serialized read-modify-write chains (the v1 kernel's 3x loss).
-    rows_d = jax.lax.broadcasted_iota(
-        jnp.int32, (_PACK_ROWS + 8, _PACK_ROWS), 0)
-    q_f = q_all.astype(jnp.float32)
-    rows_d_f = rows_d.astype(jnp.float32)
-    Q1 = jnp.where(rows_d_f == q_f[None, :], 1.0, 0.0)
-    Q2 = jnp.where(rows_d_f == q_f[None, :] + 1.0, 1.0, 0.0)
-    lanes_f = jax.lax.broadcasted_iota(
-        jnp.int32, (_PACK_ROWS, _LANES), 1).astype(jnp.float32)
-    hi = jnp.where(lanes_f >= rem_all_f[:, None], 1.0, 0.0)
-    lo = 1.0 - hi
-
-    def place(c):
-        # HIGHEST precision: the MXU's default rounds operands to bf16 —
-        # fatal for the value channel and for row ids above 256 (the 0/1
-        # COUNT matmuls above are safe: exact operands, f32 accumulation)
-        hi_part = jnp.matmul(Q1, c * hi,
-                             precision=jax.lax.Precision.HIGHEST)
-        lo_part = jnp.matmul(Q2, c * lo,
-                             precision=jax.lax.Precision.HIGHEST)
-        return hi_part + lo_part                          # [R+8, 128]
-
-    row_abs_f = (jnp.float32(i) * _PACK_ROWS
-                 + jax.lax.broadcasted_iota(
-                     jnp.int32, (_PACK_ROWS, _LANES), 0).astype(jnp.float32))
-    stage_v = place(comp_v)
-    stage_l = place(comp_l)
-    stage_row = place(comp_ok * row_abs_f)
-    stage_ok = place(comp_ok)
-    stage_i = jnp.where(
-        stage_ok > 0.0,
-        stage_row.astype(jnp.int32) * _LANES + stage_l.astype(jnp.int32),
-        0)
-    scratch_v[:] = stage_v
-    scratch_i[:] = stage_i
-
-    @pl.when(shipped)
-    def _():
-        dv = pltpu.make_async_copy(
-            scratch_v.at[pl.ds(0, _PACK_ROWS), :],
-            vals_ref.at[pl.ds(base_row, _PACK_ROWS), :], sem_v)
-        di = pltpu.make_async_copy(
-            scratch_i.at[pl.ds(0, _PACK_ROWS), :],
-            idx_ref.at[pl.ds(base_row, _PACK_ROWS), :], sem_i)
-        dv.start()
-        di.start()
-        dv.wait()
-        di.wait()
-
-
-def pack_by_threshold(acc: Array, t: Array, keep: int, *, want_ef: bool = True,
-                      interpret: bool = False):
-    """``(vals [P], idx [P], new_ef|None, count)`` with ``P =
-    pack_payload_slots(n, keep)``: the coordinates with ``|acc| >= t`` by
-    ascending index (the wire-mode Top-K payload), zero-padded at the
-    row-alignment gaps (identities under scatter-add), their values, and
-    the residual, in one fused pass.
-
-    Caller guarantees ``count(|acc| >= t) >= keep`` (the `topk_threshold`
-    structural guarantee); capacity-truncated survivors stay in the
-    residual (whole-block granularity), and the returned ``count`` is the
-    survivors actually in the payload.
-
-    STATUS: correct and tested, but MEASURED SLOWER than the unfused
-    pack chain on TPU v5e (0.32-0.45x; benchmarks/pack_kernel_r3.txt) —
-    deliberately NOT dispatched by the wire path.  Kept as the measured
-    negative result VERDICT r2 #4 asked for, and as the base for the
-    shift-network follow-up sketched in the benchmark notes.
-    """
-    n = acc.shape[0]
-    if n > _INT32_MAX:
-        raise ValueError(f"pack_by_threshold indexes int32; got n={n}")
-    x2d, num_blocks = _pad_chunks(acc.astype(jnp.float32), fill=0.0,
-                                  rows=_PACK_ROWS)
-    vma = _vma(acc)
-    cap_rows = pack_payload_slots(n, keep) // _LANES
-    out_rows = cap_rows + _PACK_ROWS          # slack for the last DMA window
-    out_shape = [
-        jax.ShapeDtypeStruct((out_rows, _LANES), jnp.float32, vma=vma),
-        jax.ShapeDtypeStruct((out_rows, _LANES), jnp.int32, vma=vma),
-    ]
-    out_specs = [
-        pl.BlockSpec(memory_space=pl.ANY),
-        pl.BlockSpec(memory_space=pl.ANY),
-    ]
-    if want_ef:
-        out_shape.append(jax.ShapeDtypeStruct(x2d.shape, jnp.float32, vma=vma))
-        out_specs.append(pl.BlockSpec((_PACK_ROWS, _LANES), lambda i: (i, 0),
-                                      memory_space=pltpu.VMEM))
-    out_shape.append(jax.ShapeDtypeStruct((1, 3), jnp.int32, vma=vma))
-    out_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
-    outs = pl.pallas_call(
-        functools.partial(_pack_kernel, n, cap_rows, want_ef),
-        grid=(num_blocks,),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i: (0, 0), memory_space=pltpu.SMEM),
-            pl.BlockSpec((_PACK_ROWS, _LANES), lambda i: (i, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=[
-            # 8 spare rows (sublane-aligned staging): the last source row's
-            # wrapped placement lands in row R; the DMA copies rows [0, R)
-            pltpu.VMEM((_PACK_ROWS + 8, _LANES), jnp.float32),
-            pltpu.VMEM((_PACK_ROWS + 8, _LANES), jnp.int32),
-            pltpu.SMEM((4,), jnp.int32),
-            pltpu.SemaphoreType.DMA,
-            pltpu.SemaphoreType.DMA,
-        ],
-        interpret=pltpu.InterpretParams() if interpret else False,
-        compiler_params=pltpu.CompilerParams(
-            has_side_effects=True,
-            # the unrolled one-hot sub-blocks keep several [S,128,128]
-            # temporaries live; the default 16M scoped-vmem limit is too
-            # tight for the block size (v5e has 128M physical VMEM)
-            vmem_limit_bytes=96 * 1024 * 1024,
-        ),
-    )(t.reshape(1, 1).astype(jnp.float32), x2d)
-    P = cap_rows * _LANES
-    counts = outs[-1]
-    # rows past the last SHIPPED block are uninitialised HBM — zero them
-    # (zeros/idx-0 are scatter-add identities, like the alignment gaps)
-    valid = jnp.arange(P, dtype=jnp.int32) < counts[0, 2] * _LANES
-    vals = jnp.where(valid, outs[0].reshape(-1)[:P], 0.0)
-    idx = jnp.where(valid, outs[1].reshape(-1)[:P], 0)
-    new_ef = outs[2].reshape(-1)[:n] if want_ef else None
-    count = counts[0, 0]   # survivors actually in the payload
-    return vals, idx, new_ef, count
-
-
-# ---------------------------------------------------------------------------
-# Segmented shift-network pack (the r3 follow-up: log-round static rolls)
-# ---------------------------------------------------------------------------
-
+#
 # Segment = _SEG_ROWS x 128 elements compacted independently; _SEG_PER_BLOCK
 # segments per grid step amortise grid overhead.  Per segment the kernel
 # computes in-segment survivor ranks (one tri-matmul in-row prefix + a
@@ -842,18 +583,25 @@ def pack_by_threshold(acc: Array, t: Array, keep: int, *, want_ef: bool = True,
 # 2^b).  Distances are monotone non-decreasing in position, which makes the
 # LSB->MSB schedule collision-free: an arrival can only land on a dead slot
 # or a slot simultaneously vacated (fuzz-verified; tests).  No per-element
-# dynamic stores, no one-hot materialisation — exactly the two walls the r3
-# kernel measured (benchmarks/pack_kernel_r3.txt).
+# dynamic stores, no one-hot materialisation — the two walls a one-hot
+# placement kernel measured (benchmarks/pack_kernel_r3.txt).
+#
+# Each segment is FULLY left-compacted (capacity = segment size, so no
+# survivor is ever clipped: a 128-slot cap per segment dropped sent mass on
+# concentrated LM gradients, benchmarks/pack_kernel_r4.txt), staging
+# compacted (value, global-index) pairs plus a per-segment survivor count in
+# ONE pass over the gradient.  A small XLA epilogue (cumsum over nseg counts
+# + one rank-bucketed gather of exactly `keep` slots) then assembles the wire
+# payload — the `packed_indices_from_mask` trick at segment granularity, ~32x
+# fewer buckets than the per-128-lane-row XLA chain, and without the chain's
+# full-width mask materialisation, row-count pass, and element gather over n.
+# Within-segment compaction preserves ascending order and segments are
+# ascending, so the payload is bitwise identical to the unfused
+# mask -> packed_indices_from_mask -> _sorted_gather pipeline (parity-gated
+# in tier-1 under the interpreter).
 _SEG_ROWS = 32                    # 4096 elements per segment
 _SEG = _SEG_ROWS * _LANES
 _SEG_PER_BLOCK = 16               # 512 rows / grid step
-_SEG_CAP = _LANES                 # payload slots per segment (one lane row)
-
-
-def seg_pack_slots(n: int) -> int:
-    """Payload capacity of the segmented layout: cap slots per segment."""
-    nseg = -(-n // _SEG)
-    return nseg * _SEG_CAP
 
 
 def _roll_flat(a: Array, s: int, seg_rows: int):
@@ -873,205 +621,6 @@ def _roll_flat(a: Array, s: int, seg_rows: int):
                      jnp.roll(a1, -lane_part, axis=1))
 
 
-def _seg_pack_kernel(n: int, keep: int, want_ef: bool, t_ref, x_ref,
-                     start_ref, cnt_ref, *out_refs):
-    if want_ef:
-        vals_ref, idx_ref, ef_ref = out_refs
-    else:
-        vals_ref, idx_ref = out_refs
-        ef_ref = None
-    rows = x_ref.shape[0]                        # _SEG_PER_BLOCK * _SEG_ROWS
-    x = x_ref[:]
-    base = pl.program_id(0) * rows * _LANES
-    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, _LANES), 1)
-    row = jax.lax.broadcasted_iota(jnp.int32, (rows, _LANES), 0)
-    gpos = base + row * _LANES + lane
-    seg_row = row % _SEG_ROWS                    # row index within the segment
-    spos = seg_row * _LANES + lane               # flat position within segment
-    m = jnp.logical_and(jnp.abs(x) >= t_ref[0, 0], gpos < n)
-
-    # in-segment 1-based survivor rank: in-row inclusive prefix (tri matmul,
-    # rows are segment-local by construction) + exclusive row prefix within
-    # the segment (Hillis-Steele over sublanes, masked at segment boundaries)
-    mf = m.astype(jnp.float32)
-    tri = (jax.lax.broadcasted_iota(jnp.int32, (_LANES, _LANES), 0)
-           <= jax.lax.broadcasted_iota(jnp.int32, (_LANES, _LANES), 1)
-           ).astype(jnp.float32)
-    inrow = jax.lax.dot_general(mf, tri, (((1,), (0,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-    rowcnt = jnp.broadcast_to(inrow[:, _LANES - 1:], (rows, _LANES))
-    rowpfx = rowcnt                              # inclusive over segment rows
-    s = 1
-    while s < _SEG_ROWS:
-        shifted = jnp.roll(rowpfx, s, axis=0)
-        rowpfx = jnp.where(seg_row >= s, rowpfx + shifted, rowpfx)
-        s *= 2
-    rank = (rowpfx - rowcnt + inrow).astype(jnp.int32)   # 1-based, survivors
-
-    eligible = jnp.logical_and(m, rank <= _SEG_CAP)
-    if ef_ref is not None:
-        # start_ref: [rows, 1] per-ROW copy of the segment's exclusive
-        # eligible-prefix — [., 1] so the in-kernel broadcast is lane-only
-        # (Mosaic has no sublane+lane broadcast)
-        sent = jnp.logical_and(eligible, start_ref[:] + rank <= keep)
-        ef_ref[:] = jnp.where(sent, 0.0, x)
-
-    # route eligible survivors left by d = spos - (rank-1); d == 0 is dead
-    d = jnp.where(eligible, spos - (rank - 1), 0)
-    vals = x
-    gidx = gpos
-    b = 0
-    while (1 << b) < _SEG:
-        sft = 1 << b
-        rd = _roll_flat(d, sft, _SEG_ROWS)
-        rv = _roll_flat(vals, sft, _SEG_ROWS)
-        ri = _roll_flat(gidx, sft, _SEG_ROWS)
-        # arrivals: source element (at spos+sft, same segment) moving now
-        move_in = jnp.logical_and(((rd >> b) & 1) == 1, spos < _SEG - sft)
-        my_move = ((d >> b) & 1) == 1
-        vals = jnp.where(move_in, rv, vals)
-        gidx = jnp.where(move_in, ri, gidx)
-        d = jnp.where(move_in, rd - sft, jnp.where(my_move, 0, d))
-        b += 1
-
-    # segment s_local's compacted payload = its first _SEG_CAP slots (row 0)
-    v3 = vals.reshape(rows // _SEG_ROWS, _SEG_ROWS, _LANES)
-    i3 = gidx.reshape(rows // _SEG_ROWS, _SEG_ROWS, _LANES)
-    # mask dead tail slots (rank beyond count): their lanes carry stale
-    # values — zero value / index 0 are scatter-add identities.  cnt_ref is
-    # the per-segment survivor count [_SEG_PER_BLOCK, 1] (computed outside;
-    # [., 1] keeps the comparison's broadcast lane-only)
-    live = (jax.lax.broadcasted_iota(
-        jnp.int32, (rows // _SEG_ROWS, _LANES), 1) < cnt_ref[:])
-    vals_ref[:] = jnp.where(live, v3[:, 0, :], 0.0)
-    idx_ref[:] = jnp.where(live, i3[:, 0, :], 0)
-
-
-def seg_pack_by_threshold(acc: Array, t: Array, keep: int, *,
-                          want_ef: bool = True, interpret: bool = False):
-    """``(vals [nseg, 128], idx [nseg, 128], new_ef [n] | None,
-    elig [nseg], counts [nseg])``: per-segment left-compacted survivors
-    (``|acc| >= t``), their global indices, and the EF residual, in one
-    fused pass per element.
-
-    Wire semantics: each 4096-element segment contributes at most 128
-    survivors (ascending index); the epilogue (:func:`seg_pack_payload`)
-    concatenates the per-segment prefixes and truncates to ``keep`` — when a
-    segment overflows its cap, the overflow stays in the residual and later
-    survivors take the freed payload slots (same capacity discipline as the
-    wire thresholdv path, segment-granular instead of global).  ``counts``
-    is the raw per-segment survivor count (for overflow reporting),
-    ``elig = min(counts, 128)``.
-    """
-    n = acc.shape[0]
-    if n > _INT32_MAX:
-        raise ValueError(f"seg_pack_by_threshold indexes int32; got n={n}")
-    rows_blk = _SEG_PER_BLOCK * _SEG_ROWS
-    x2d, num_blocks = _pad_chunks(acc.astype(jnp.float32), fill=0.0,
-                                  rows=rows_blk)
-    nseg = x2d.shape[0] // _SEG_ROWS
-    vma = _vma(acc)
-    # per-segment eligible-prefix (exclusive): counts need one cheap mask
-    # pass (the kernel recomputes the mask in-VMEM; this pass is linear and
-    # XLA-fused, ~1 read of n)
-    tf = jnp.asarray(t, jnp.float32)
-    m2 = jnp.logical_and(jnp.abs(x2d) >= tf,
-                         jnp.arange(x2d.size, dtype=jnp.int32)
-                         .reshape(x2d.shape) < n)
-    counts = jnp.sum(m2.reshape(nseg, _SEG_ROWS * _LANES), axis=1,
-                     dtype=jnp.int32)
-    elig = jnp.minimum(counts, _SEG_CAP)
-    starts = (jnp.cumsum(elig) - elig).astype(jnp.int32)   # exclusive
-    start_rows = jnp.repeat(starts, _SEG_ROWS)[:, None]    # [rows, 1]
-    blk = pl.BlockSpec((rows_blk, _LANES), lambda i: (i, 0),
-                       memory_space=pltpu.VMEM)
-    seg_out = pl.BlockSpec((_SEG_PER_BLOCK, _LANES), lambda i: (i, 0),
-                           memory_space=pltpu.VMEM)
-    out_specs = [seg_out, seg_out] + ([blk] if want_ef else [])
-    out_shape = [
-        jax.ShapeDtypeStruct((nseg, _LANES), jnp.float32, vma=vma),
-        jax.ShapeDtypeStruct((nseg, _LANES), jnp.int32, vma=vma),
-    ] + ([jax.ShapeDtypeStruct(x2d.shape, jnp.float32, vma=vma)]
-         if want_ef else [])
-    outs = pl.pallas_call(
-        functools.partial(_seg_pack_kernel, n, int(keep), want_ef),
-        grid=(num_blocks,),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i: (0, 0), memory_space=pltpu.SMEM),
-            blk,
-            pl.BlockSpec((rows_blk, 1), lambda i: (i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((_SEG_PER_BLOCK, 1), lambda i: (i, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=out_specs,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(jnp.asarray(t).reshape(1, 1).astype(jnp.float32), x2d, start_rows,
-      counts[:, None])
-    new_ef = outs[2].reshape(-1)[:n] if want_ef else None
-    return outs[0], outs[1], new_ef, elig, counts
-
-
-def seg_pack_payload(vals: Array, idx: Array, elig: Array, keep: int):
-    """Concatenate per-segment compacted prefixes into the exact ``keep``-slot
-    wire payload: slot ``j`` holds eligible survivor ``j+1`` in ascending
-    global order (rank bucketing over segment ends — the
-    `packed_indices_from_mask` trick at segment granularity, ~32x fewer
-    buckets than per-128-lane rows).  Slots past the eligible total are
-    zero/index-0 (scatter-add identities)."""
-    nseg = vals.shape[0]
-    ends = jnp.cumsum(elig)                                # inclusive
-    ranks = jnp.arange(1, keep + 1, dtype=jnp.int32)
-    hist = jnp.zeros((keep + 1,), jnp.int32).at[
-        jnp.minimum(ends, keep)].add(1)
-    seg_of = jnp.cumsum(hist)[:keep]
-    valid = seg_of < nseg
-    seg_of = jnp.where(valid, seg_of, 0)
-    within = ranks - (ends[seg_of] - elig[seg_of]) - 1     # 0-based slot
-    flat_pos = seg_of * _LANES + within
-    pvals = jnp.where(valid, vals.reshape(-1)[flat_pos], 0.0)
-    pidx = jnp.where(valid, idx.reshape(-1)[flat_pos], 0)
-    return pvals, pidx
-
-
-_SEG_PACK_DISPATCH = False
-
-
-def use_seg_pack(n: int, keep: int) -> bool:
-    """Whether the wire Top-K path should take the segmented shift-network
-    kernel.  OFF by default (round-4 measured result: at the 125M-param LM
-    config the kernel ties the unfused chain end-to-end — 45.0k vs 45.9k
-    tok/s — while segment-cap overflow on concentrated LM gradients drops
-    the effective sent fraction to ~0.5%; benchmarks/pack_kernel_r4.txt).
-    The structural gates remain for forced/experimental use: TPU,
-    int32-indexable, keep density comfortably under the per-segment cap
-    (128/4096 = 3.125%)."""
-    return (_SEG_PACK_DISPATCH and _dispatch_to_pallas(n)
-            and n <= _INT32_MAX and keep * 2 * _SEG <= n * _SEG_CAP)
-
-
-# ---------------------------------------------------------------------------
-# Fused select+pack (wire-mode Top-K: one-pass threshold select -> payload)
-# ---------------------------------------------------------------------------
-#
-# The r4 seg-pack postmortem identified the per-segment CAP as the killer
-# (concentrated LM gradients overflow 128 slots/4096 elements and drop sent
-# mass), not the shift network itself.  This kernel removes the cap: each
-# 4096-element segment is FULLY left-compacted (capacity = segment size, so
-# no survivor is ever clipped), staging compacted (value, global-index)
-# pairs plus a per-segment survivor count in ONE pass over the gradient.  A
-# small XLA epilogue (cumsum over nseg counts + one rank-bucketed gather of
-# exactly `keep` slots) then assembles the wire payload — the
-# `packed_indices_from_mask` trick at segment granularity, ~32x fewer
-# buckets than the per-128-lane-row XLA chain, and without the chain's
-# full-width mask materialisation, row-count pass, and element gather over n.
-# Within-segment compaction preserves ascending order and segments are
-# ascending, so the payload is bitwise identical to the unfused
-# mask -> packed_indices_from_mask -> _sorted_gather pipeline (parity-gated
-# in tier-1 under the interpreter).
-
-
 def _select_pack_kernel(n: int, t_ref, x_ref, vals_ref, idx_ref, cnt_ref):
     rows = x_ref.shape[0]                        # _SEG_PER_BLOCK * _SEG_ROWS
     x = x_ref[:]
@@ -1087,8 +636,9 @@ def _select_pack_kernel(n: int, t_ref, x_ref, vals_ref, idx_ref, cnt_ref):
     m = jnp.logical_and(jnp.abs(x.astype(jnp.float32)) >= t_ref[0, 0],
                         gpos < n)
 
-    # in-segment 1-based survivor rank: same tri-matmul in-row prefix +
-    # Hillis-Steele row scan as _seg_pack_kernel
+    # in-segment 1-based survivor rank: in-row inclusive prefix (tri matmul,
+    # rows are segment-local by construction) + exclusive row prefix within
+    # the segment (Hillis-Steele over sublanes, masked at segment boundaries)
     mf = m.astype(jnp.float32)
     tri = (jax.lax.broadcasted_iota(jnp.int32, (_LANES, _LANES), 0)
            <= jax.lax.broadcasted_iota(jnp.int32, (_LANES, _LANES), 1)
@@ -1104,9 +654,10 @@ def _select_pack_kernel(n: int, t_ref, x_ref, vals_ref, idx_ref, cnt_ref):
         s *= 2
     rank = (rowpfx - rowcnt + inrow).astype(jnp.int32)   # 1-based, survivors
 
-    # route EVERY survivor left by d = spos - (rank-1); no eligibility cap,
-    # so distances stay monotone non-decreasing in position and the LSB->MSB
-    # schedule stays collision-free for the full log2(_SEG) rounds
+    # route EVERY survivor left by d = spos - (rank-1); d == 0 is dead.  No
+    # eligibility cap, so distances stay monotone non-decreasing in position
+    # and the LSB->MSB schedule stays collision-free for the full log2(_SEG)
+    # rounds
     d = jnp.where(m, spos - (rank - 1), 0)
     vals = x
     gidx = gpos
@@ -1116,6 +667,7 @@ def _select_pack_kernel(n: int, t_ref, x_ref, vals_ref, idx_ref, cnt_ref):
         rd = _roll_flat(d, sft, _SEG_ROWS)
         rv = _roll_flat(vals, sft, _SEG_ROWS)
         ri = _roll_flat(gidx, sft, _SEG_ROWS)
+        # arrivals: source element (at spos+sft, same segment) moving now
         move_in = jnp.logical_and(((rd >> b) & 1) == 1, spos < _SEG - sft)
         my_move = ((d >> b) & 1) == 1
         vals = jnp.where(move_in, rv, vals)
@@ -1228,9 +780,8 @@ def fused_select_pack(flat: Array, t: Array, keep: int, *,
 
 def use_select_pack(n: int, keep: int) -> bool:
     """Whether the wire Top-K select+pack should take the fused kernel.
-    Unlike the capped seg-pack (measured tie, off), full per-segment
-    compaction has no overflow pathology, so it dispatches on the standard
-    gates; the epilogue gather is O(keep)."""
+    Full per-segment compaction has no overflow pathology, so it dispatches
+    on the standard gates; the epilogue gather is O(keep)."""
     return _dispatch_to_pallas(n) and n <= _INT32_MAX and keep >= 1
 
 

@@ -275,8 +275,8 @@ def packed_indices_from_mask(mask: Array, keep: int) -> Array:
     The per-rank stage is ONE gather per rank, of the mask rows (round 5
     had a second one, of precomputed row starts; before that three + an
     fp32 tri-matmul): per-rank costs are billed per random ACCESS, and the
-    round-5 bisect (tools/wire_profile.py --subs) measured ~7 ms per
-    [keep]-sized gather at keep=1.25M.  A rank's row start is where its run
+    round-5 bisect of the pack's sub-stages (ROUND5_NOTES.md) measured ~7 ms
+    per [keep]-sized gather at keep=1.25M.  A rank's row start is where its run
     of equal ``row_of`` began (`kernels.run_starts`): a scan over the
     ranks, no access.  The in-row prefix matmul runs in bf16 (row
     prefix counts are <= 128, exactly representable), halving the gathered
@@ -411,44 +411,6 @@ def _leaf_sync_topk(flat: Array, keep: int, axis_name: str, world,
         sent = (mag >= t) & (upto_last | (count < keep))
         new_ef = jnp.where(sent, 0, flat)
     return dense, new_ef, bits, count
-
-
-def _leaf_sync_topk_seg(flat: Array, keep: int, axis_name: str, world,
-                        want_ef: bool, t=None):
-    """Element Top-K wire sync via the segmented shift-network pack kernel
-    (`kernels.seg_pack_by_threshold`): one fused pass computes per-segment
-    compacted (values, indices) AND the EF residual elementwise — replacing
-    the mask->rank->gather chain plus the k-sized EF scatter.
-
-    Selection diverges from `_leaf_sync_topk` only when a 4096-element
-    segment holds >128 survivors: the overflow stays in the residual and the
-    freed payload slots go to later survivors (capacity discipline like the
-    wire thresholdv path).  Returns ``(dense, new_ef, sent_count, bits,
-    dropped)``; ``dropped`` counts cap-overflow + beyond-keep survivors
-    (reported when EF is off, reabsorbed by the residual otherwise).
-    """
-    from tpu_compressed_dp.ops import kernels
-
-    mag = jnp.abs(flat).astype(jnp.float32)
-    if t is None:
-        t = kernels.topk_threshold(mag, keep)
-    vals, idx2, new_ef, elig, counts = kernels.seg_pack_by_threshold(
-        flat, t, keep, want_ef=want_ef)
-    pvals, pidx = kernels.seg_pack_payload(vals, idx2, elig, keep)
-    pvals = pvals.astype(flat.dtype)
-    bits = _payload_bits(pvals, pidx)
-    g_vals = _all_gather(pvals, axis_name)         # [W, k]
-    g_idx = _all_gather(pidx, axis_name)           # [W, k]
-    dense = (
-        jnp.zeros(flat.shape, flat.dtype)
-        .at[g_idx.reshape(-1)]
-        .add(g_vals.reshape(-1))
-        / world
-    )
-    total_elig = jnp.sum(elig, dtype=jnp.int32)
-    sent_count = jnp.minimum(total_elig, keep)
-    dropped = jnp.sum(counts, dtype=jnp.int32) - sent_count
-    return dense, new_ef, sent_count, bits, dropped
 
 
 def _leaf_sync_blocktopk(flat: Array, keep_blocks: int, block_size: int,
@@ -1135,8 +1097,6 @@ def make_wire_grad_sync(cfg, axis_name: str = "data", *,
             dense, idx, agree, bits = _leaf_sync_randomk(
                 acc, key, keep, axis_name, world, check)
         elif comp.name == "topk":
-            from tpu_compressed_dp.ops import kernels
-
             if hier:
                 dense, new_ef, fabric, overflow, surplus = (
                     _leaf_sync_topk_hier(acc, keep, axis_name, world, cfg,
@@ -1155,16 +1115,6 @@ def make_wire_grad_sync(cfg, axis_name: str = "data", *,
                     ovf["topk_surplus_dropped"] = surplus
                 return (dense, new_ef, sent_count.astype(jnp.float32), bits,
                         bits_route, agree, ovf, None)
-            if kernels.use_seg_pack(n, keep):
-                # the seg-pack fused EF/pack kernel assumes every packed slot
-                # travels — an allgather-path contract; sharded groups take
-                # the mask->rank->gather chain above instead
-                dense, new_ef, sent_count, bits, dropped = _leaf_sync_topk_seg(
-                    acc, keep, axis_name, world, want_ef, t)
-                return (dense, new_ef, sent_count.astype(jnp.float32), bits,
-                        0.0, agree,
-                        {} if want_ef
-                        else {"topk_surplus_dropped": dropped}, None)
             dense, new_ef, bits, count = _leaf_sync_topk(
                 acc, keep, axis_name, world, want_ef, t)
             ovf = {"topk_underfull": count < keep}

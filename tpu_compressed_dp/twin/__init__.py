@@ -9,8 +9,6 @@ fitted from the repo's own BENCH/MULTICHIP acceptance artifacts.
     calibration rows
   * :mod:`~tpu_compressed_dp.twin.calibrate`  least-squares fitter with
     per-row residuals
-  * :mod:`~tpu_compressed_dp.twin.gate`       the tier-1 modeled-perf
-    ratchet over ``benchmarks/perf_pins.json``
 
 Every module is replay-deterministic (hostlint TCDP101): fits and
 predictions are pure functions of the committed artifacts.
@@ -18,9 +16,6 @@ predictions are pure functions of the committed artifacts.
 
 from tpu_compressed_dp.twin.calibrate import (        # noqa: F401
     Calibration, Residual, fit, load_calibration, save_calibration,
-)
-from tpu_compressed_dp.twin.gate import (             # noqa: F401
-    PinResult, check_pins, load_pins, make_pin, price_pin,
 )
 from tpu_compressed_dp.twin.model import (            # noqa: F401
     Collective, CostModel, FabricParams, TwinPoint,
@@ -33,8 +28,7 @@ from tpu_compressed_dp.twin.records import (          # noqa: F401
 
 __all__ = [
     "Calibration", "Residual", "fit", "load_calibration",
-    "save_calibration", "PinResult", "check_pins", "load_pins", "make_pin",
-    "price_pin", "Collective", "CostModel", "FabricParams", "TwinPoint",
+    "save_calibration", "Collective", "CostModel", "FabricParams", "TwinPoint",
     "UncalibratedFabricError", "predict_step_ms", "schedule_for_point",
     "CalibRow", "RecordFile", "calibration_rows", "discover_record_paths",
     "load_record_file",

@@ -9,7 +9,7 @@ The files come in five shapes, all produced by the repo's own tooling:
     (no step/payload decomposition to fit against)
   * **step** (r06): ``parsed`` is one full ``bench/sweep.py`` step record
   * **sweep** (r07/r08/r10/r11): ``records`` is a list of step records,
-    optionally with ``phase_<name>_ms`` columns (``--phase_breakdown``)
+    optionally with recorded ``phase_<name>_ms`` columns (r11's rows)
   * **adaptive** (r09): ``records`` carry ``static_rungs`` /
     ``window_trace`` from the closed-loop controller runs — the timed
     ``static_rungs`` become step rows; ``window_trace`` rows are
@@ -26,8 +26,8 @@ billed payload columns through the same schedule arithmetic the engines
 use; its *context key* (model x method x knob x transport x topology x
 pallas mode) gives the fitter a per-context compute term so rows that
 differ only in repeat noise share one.  A **phase row** is a pure comm
-equation — a ``--phase_breakdown`` comm phase's wall time against that
-one collective's features, no compute term — and is what actually
+equation — a recorded ``phase_<name>_ms`` comm phase's wall time against
+that one collective's features, no compute term — and is what actually
 identifies alpha/beta/gamma per fabric (``pallas off`` rows only: the
 ``force`` column times the Pallas interpreter, not the wire).
 

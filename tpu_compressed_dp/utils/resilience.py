@@ -308,10 +308,9 @@ def check_heartbeat(path: str, *, max_age_s: float = 60.0,
       crawling (data stall, thrashing input pipeline).
     * **slow tail** — the telemetry snapshot's ``step_p95_ms`` (the
       timeline window's tail latency) exceeds ``max_step_p95_ms``: the
-      MEAN rate still looks fine but the tail regressed — the perf-gate
-      bound (``benchmarks/perf_pins.json``) enforced live instead of at
-      test time, and the first symptom of a degrading interconnect or a
-      periodic stall the mean averages away.
+      MEAN rate still looks fine but the tail regressed — the run's step
+      budget enforced live, and the first symptom of a degrading
+      interconnect or a periodic stall the mean averages away.
     * **checkpoint-stale** — ``ckpt_age_s`` (written from
       ``Checkpointer.heartbeat_fields``) plus the heartbeat's own age
       exceeds ``max_ckpt_age_s``: training advances but nothing durable is
