@@ -246,9 +246,11 @@ class TestFusedSelectPack:
     def _placed(self, case, dtype):
         """A vector whose survivors of ``|x| >= T`` sit where ``case`` puts
         them, over noise under ``T / 2``; every value exact in bfloat16."""
-        seg = kernels._SEG
+        seg, lanes = kernels._SEG, kernels._LANES
+        block = kernels._SEG_PER_BLOCK * seg
         rng = np.random.default_rng(len(case))
-        n = 12 * seg if case == "empty_segments" else 2 * seg + 777
+        n = {"empty_segments": 12 * seg,
+             "ragged_block": block + seg + 777}.get(case, 2 * seg + 777)
         x = rng.integers(-63, 64, n) / 64.0
         if case == "empty_segments":
             # a few, many, one and every element of a segment, empty
@@ -256,6 +258,28 @@ class TestFusedSelectPack:
             per_seg = {1: 3, 4: 200, 5: 1, 9: seg, 10: 50}
             where = np.concatenate([s * seg + rng.choice(seg, c, replace=False)
                                     for s, c in per_seg.items()])
+        elif case == "all_survive":
+            # nothing in the segment moves (every d == 0), its neighbours do
+            where = np.concatenate([rng.choice(seg, 40, replace=False),
+                                    seg + np.arange(seg),
+                                    2 * seg + rng.choice(777, 9, replace=False)])
+        elif case == "lone_last_slot":
+            # d == _SEG - 1: the one survivor moves in every round
+            where = np.array([seg - 1, 2 * seg - 1])
+        elif case == "row_boundary":
+            # runs across the end of a 128-lane row, which travel by shifts
+            # with a lane part, behind enough survivors that the compacted
+            # run crosses the end of the segment's first row too
+            across = np.arange(-3, 3)
+            where = seg + np.concatenate([rng.choice(3 * lanes, lanes - 3, replace=False),
+                                          5 * lanes + across, 20 * lanes + across,
+                                          [seg - lanes - 1, seg - lanes, seg - 1]])
+        elif case == "ragged_block":
+            # the second block ends inside its second segment: survivors at
+            # its first slot, across its one whole segment's end, at n - 1
+            where = np.concatenate([rng.choice(block, 30, replace=False),
+                                    block + np.array([0, seg - 1, seg, seg + 776]),
+                                    block + rng.choice(np.arange(1, seg - 1), 20, replace=False)])
         else:
             count = {"ragged_tail": 64, "ties": 300, "underfull": 40}[case]
             where = rng.choice(n, count, replace=False)
@@ -271,7 +295,9 @@ class TestFusedSelectPack:
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                              ids=["f32", "bf16"])
     @pytest.mark.parametrize("case", ["empty_segments", "ragged_tail", "ties",
-                                      "underfull"])
+                                      "underfull", "all_survive",
+                                      "lone_last_slot", "row_boundary",
+                                      "ragged_block"])
     def test_bitwise_parity_placed_survivors(self, case, dtype):
         flat, keep, count = self._placed(case, dtype)
         mag = jnp.abs(flat).astype(jnp.float32)
@@ -289,6 +315,25 @@ class TestFusedSelectPack:
         assert np.array_equal(np.asarray(fi)[:live], want)
         assert not np.any(np.asarray(fv, np.float32)[live:])
         assert fv.dtype == flat.dtype
+
+    @pytest.mark.parametrize("dtype", [jnp.int32, jnp.float32],
+                             ids=["i32", "f32"])
+    @pytest.mark.parametrize("shift", [1 << b for b in range(kernels._SEG_BITS)])
+    def test_roll_flat_is_a_flattened_left_roll(self, shift, dtype):
+        # the network's every shift on the kernel's block, wrap inside it
+        from jax.experimental import pallas as pl
+
+        shape = (kernels._SEG_PER_BLOCK * kernels._SEG_ROWS, kernels._LANES)
+        a = jax.random.randint(jax.random.key(shift), shape, -1 << 20,
+                               1 << 20).astype(dtype)
+
+        def body(a_ref, out_ref):
+            out_ref[:] = kernels._roll_flat(a_ref[:], shift)
+
+        got = pl.pallas_call(body, out_shape=jax.ShapeDtypeStruct(shape, dtype),
+                             interpret=True)(a)
+        want = jnp.roll(a.reshape(-1), -shift).reshape(shape)
+        assert np.array_equal(np.asarray(got), np.asarray(want))
 
     def test_blocktopk_scores_parity(self):
         # block scores are non-negative and serve as their own magnitudes

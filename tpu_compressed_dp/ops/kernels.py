@@ -586,6 +586,14 @@ def use_fused_sparsify(n: int) -> bool:
 # dynamic stores, no one-hot materialisation — the two walls a one-hot
 # placement kernel measured (benchmarks/pack_kernel_r3.txt).
 #
+# Vector work bounds the kernel, not bytes, and the cross-lane moves lead it,
+# so the network routes as little as it can, on the native rotates
+# (`pltpu.roll`; `jnp.roll` is a slice pair and a concatenate in Mosaic): TWO
+# 32-bit words a round, the value and `w = (spos << 12) | d`, the element's
+# in-segment source position above its remaining distance (both under
+# _SEG = 2^12).  The global index is put back after the last round from the
+# slot's own segment base; 16-bit values ride as fp32 (exact both ways).
+#
 # Each segment is FULLY left-compacted (capacity = segment size, so no
 # survivor is ever clipped: a 128-slot cap per segment dropped sent mass on
 # concentrated LM gradients, benchmarks/pack_kernel_r4.txt), staging
@@ -601,40 +609,48 @@ def use_fused_sparsify(n: int) -> bool:
 # in tier-1 under the interpreter).
 _SEG_ROWS = 32                    # 4096 elements per segment
 _SEG = _SEG_ROWS * _LANES
+_SEG_BITS = _SEG.bit_length() - 1
 _SEG_PER_BLOCK = 16               # 512 rows / grid step
 
 
-def _roll_flat(a: Array, s: int, seg_rows: int):
-    """Flattened-order left roll by static ``s`` on a [R, 128] block, with
-    row wrap INSIDE the block (callers mask cross-segment wraps).
+def _roll_flat(a: Array, s: int):
+    """Flattened-order left roll by static ``s`` on a [R, 128] block of
+    32-bit words, with the wrap INSIDE the block (what crosses a segment's
+    end is the caller's to rule out):
+    ``out[r, l] = a.reshape(-1)[(r * 128 + l + s) % a.size]``.
 
-    NB roll-by-0 must short-circuit: Mosaic lowers jnp.roll to a slice pair
-    and rejects the zero-size half."""
+    One lane rotate, then the two sublane rotates of THAT result a slot can
+    read from (the row ``s // 128`` down, and the one after it for the lanes
+    that ran off the row's end); `pltpu.roll` rotates towards higher indices,
+    so a left roll by k of an axis of size m is a roll by ``m - k``.  A roll
+    by 0 is no operation at all."""
+    rows = a.shape[0]
     row_part, lane_part = divmod(s, _LANES)
-    a0 = a if row_part == 0 else jnp.roll(a, -row_part, axis=0)
+
+    def up(x, r):
+        return x if r == 0 else pltpu.roll(x, rows - r, 0)
+
     if lane_part == 0:
-        return a0
-    a1 = jnp.roll(a, -(row_part + 1), axis=0)
+        return up(a, row_part)
+    here = pltpu.roll(a, _LANES - lane_part, 1)
     lane = jax.lax.broadcasted_iota(jnp.int32, a.shape, 1)
     return jnp.where(lane < _LANES - lane_part,
-                     jnp.roll(a0, -lane_part, axis=1),
-                     jnp.roll(a1, -lane_part, axis=1))
+                     up(here, row_part), up(here, row_part + 1))
 
 
 def _select_pack_kernel(n: int, t_ref, x_ref, vals_ref, idx_ref, cnt_ref):
     rows = x_ref.shape[0]                        # _SEG_PER_BLOCK * _SEG_ROWS
-    x = x_ref[:]
+    # the rotates are 32-bit: bf16 rides as fp32, exact there and back, and
+    # the fp32 magnitude compare below is the wire path's
+    # `jnp.abs(flat).astype(f32) >= t` bit for bit (abs is exact)
+    x = x_ref[:].astype(jnp.float32)
     base = pl.program_id(0) * rows * _LANES
     lane = jax.lax.broadcasted_iota(jnp.int32, (rows, _LANES), 1)
     row = jax.lax.broadcasted_iota(jnp.int32, (rows, _LANES), 0)
     gpos = base + row * _LANES + lane
     seg_row = row % _SEG_ROWS
     spos = seg_row * _LANES + lane
-    # fp32 magnitude compare regardless of input dtype — matches the wire
-    # path's `jnp.abs(flat).astype(f32) >= t` bit for bit (abs is exact, and
-    # upcast-then-compare equals compare-after-promotion for bf16 inputs)
-    m = jnp.logical_and(jnp.abs(x.astype(jnp.float32)) >= t_ref[0, 0],
-                        gpos < n)
+    m = jnp.logical_and(jnp.abs(x) >= t_ref[0, 0], gpos < n)
 
     # in-segment 1-based survivor rank: in-row inclusive prefix (tri matmul,
     # rows are segment-local by construction) + exclusive row prefix within
@@ -649,7 +665,7 @@ def _select_pack_kernel(n: int, t_ref, x_ref, vals_ref, idx_ref, cnt_ref):
     rowpfx = rowcnt
     s = 1
     while s < _SEG_ROWS:
-        shifted = jnp.roll(rowpfx, s, axis=0)
+        shifted = pltpu.roll(rowpfx, s, 0)
         rowpfx = jnp.where(seg_row >= s, rowpfx + shifted, rowpfx)
         s *= 2
     rank = (rowpfx - rowcnt + inrow).astype(jnp.int32)   # 1-based, survivors
@@ -657,26 +673,27 @@ def _select_pack_kernel(n: int, t_ref, x_ref, vals_ref, idx_ref, cnt_ref):
     # route EVERY survivor left by d = spos - (rank-1); d == 0 is dead.  No
     # eligibility cap, so distances stay monotone non-decreasing in position
     # and the LSB->MSB schedule stays collision-free for the full log2(_SEG)
-    # rounds
-    d = jnp.where(m, spos - (rank - 1), 0)
+    # rounds.  A slot nothing arrives at keeps its own spos above a zero d.
+    w = (spos << _SEG_BITS) | jnp.where(m, spos - (rank - 1), 0)
     vals = x
-    gidx = gpos
-    b = 0
-    while (1 << b) < _SEG:
+    for b in range(_SEG_BITS):
         sft = 1 << b
-        rd = _roll_flat(d, sft, _SEG_ROWS)
-        rv = _roll_flat(vals, sft, _SEG_ROWS)
-        ri = _roll_flat(gidx, sft, _SEG_ROWS)
-        # arrivals: source element (at spos+sft, same segment) moving now
-        move_in = jnp.logical_and(((rd >> b) & 1) == 1, spos < _SEG - sft)
-        my_move = ((d >> b) & 1) == 1
+        rw = _roll_flat(w, sft)
+        rv = _roll_flat(vals, sft)
+        # arrivals: the element sft slots to the right, if it moves now.  It
+        # is of this segment, with no mask for the rolls' wrap: an element's
+        # remaining distance never exceeds its in-segment position (it ends
+        # at rank-1 >= 0), so one within sft of its segment's start, which is
+        # all a wrap can bring, has no bit b left to move by
+        move_in = (rw & sft) != 0
+        my_move = (w & sft) != 0
         vals = jnp.where(move_in, rv, vals)
-        gidx = jnp.where(move_in, ri, gidx)
-        d = jnp.where(move_in, rd - sft, jnp.where(my_move, 0, d))
-        b += 1
+        # bit b of an arriving d is set: taking 2^b off borrows nothing from
+        # the spos above it
+        w = jnp.where(move_in, rw - sft, jnp.where(my_move, w & ~(_SEG - 1), w))
 
-    vals_ref[:] = vals
-    idx_ref[:] = gidx
+    vals_ref[:] = vals.astype(vals_ref.dtype)
+    idx_ref[:] = gpos - spos + (w >> _SEG_BITS)
     # per-segment survivor totals: rowpfx at each segment's last row is the
     # inclusive count (identical across lanes) — full 128-lane row writes,
     # the reader takes lane 0
