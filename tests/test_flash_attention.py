@@ -29,9 +29,12 @@ def exact(q, k, v):
         (1, 2, 128, 64),    # padded head_dim (lse rides the pad lanes)
         (2, 1, 256, 128),   # unpadded head_dim (lse gets its own tile)
         (1, 1, 384, 64),    # seq needs the reduced 128 block
+        (1, 2, 512, 128),   # Ouro's head size at one whole 512 block, two heads
+        (1, 1, 1024, 128),  # ... and over two blocks: the causal block skip
     ],
 )
 def test_forward_and_grads_match_exact(shape):
+    """Against the masked softmax over the whole [T, T] of every head."""
     B, H, T, D = shape
     ks = jax.random.split(jax.random.key(0), 4)
     q, k, v = (jax.random.normal(kk, shape, jnp.float32) * 0.5
@@ -51,7 +54,8 @@ def test_forward_and_grads_match_exact(shape):
                                    err_msg=f"d{nm}")
 
 
-def test_streamed_dkv_matches_resident(monkeypatch):
+@pytest.mark.parametrize("shape", [(1, 2, 256, 64), (1, 1, 256, 128)])
+def test_streamed_dkv_matches_resident(monkeypatch, shape):
     """The DMA/double-buffered dkv kernel (`_dkv_kernel_streamed`) against
     the VMEM-resident form, both under interpret: the streamed path is the
     only one real TPU runs take for the backward, but interpret mode (the
@@ -59,8 +63,8 @@ def test_streamed_dkv_matches_resident(monkeypatch):
     explicit-DMA machinery had zero off-chip coverage (ADVICE r5).
     `TPU_CDP_FORCE_STREAMED_DKV=1` runs it under the Pallas interpreter;
     the two must agree to fp32 roundoff (identical math via
-    `_dkv_block_math`, different operand staging)."""
-    shape = (1, 2, 256, 64)
+    `_dkv_block_math`, different operand staging).  At a head size of 128
+    the packed cotangent it streams is two lane tiles wide."""
     ks = jax.random.split(jax.random.key(3), 4)
     q, k, v = (jax.random.normal(kk, shape, jnp.float32) * 0.5
                for kk in ks[:3])

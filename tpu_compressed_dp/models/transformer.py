@@ -6,6 +6,17 @@ stretch config "Llama-3-8B pretrain — entire-model Top-K grad compression
 over ICI".  Architecture: RMSNorm pre-norm, rotary position embeddings,
 grouped-query attention, SwiGLU MLP, untied LM head.
 
+One decoder, by settings of :class:`LlamaConfig`: the Llama family above;
+Switch-style routed experts (``n_experts``); and looped language models
+(Ouro, arXiv:2510.25741): ``n_passes`` runs of the whole stack over its own
+output with one set of weights (a ``lax.scan`` over passes, so the compiled
+step holds one stack's layer bodies and each weight's gradient sums over
+its uses in the backward scan's carry), ``sandwich_norm`` (a second RMSNorm
+on each branch's output, four a block), and ``exit_gate`` (a per-token
+sigmoid gate after every pass, from which :func:`exit_weighted_loss` makes
+the exit distribution that weights the passes' cross-entropies).  One pass
+with neither is the plain decoder, bit for bit.
+
 Parallelism design (TPU-first, megatron-style over a named mesh):
   * ``tensor`` axis — attention heads and MLP hidden are column-sharded, the
     output projections row-sharded (one ``psum`` each per layer); the LM head
@@ -36,12 +47,16 @@ import jax.numpy as jnp
 
 from jax.sharding import PartitionSpec as P
 
+from tpu_compressed_dp.obs import trace as obs_trace
 from tpu_compressed_dp.ops.ring_attention import ring_attention
 
 Array = jax.Array
 
-__all__ = ["LlamaConfig", "llama3_8b", "tiny_llama", "init_llama",
-           "param_specs", "apply_llama", "vocab_parallel_xent"]
+__all__ = ["LlamaConfig", "llama3_8b", "ouro_2p6b", "tiny_llama", "init_llama",
+           "param_specs", "apply_llama", "vocab_parallel_xent",
+           "vocab_parallel_xent_tokens",
+           "fused_head_xent", "fused_head_xent_tokens", "exit_log_probs",
+           "exit_weighted_loss"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -68,6 +83,16 @@ class LlamaConfig:
     # memory drops from O(L) to O(1) layers at ~1/3 extra FLOPs — the knob
     # that buys long-context training headroom
     remat: bool = False
+    # Looped language model (module docstring): the stack and the final norm
+    # run n_passes times with tied weights, each pass on the normed output
+    # of the one before; sandwich_norm adds the post-branch RMSNorms;
+    # exit_gate adds the per-token exit gate, whose distribution over the
+    # passes weights their losses (exit_beta: weight of its entropy bonus)
+    n_passes: int = 1
+    sandwich_norm: bool = False
+    exit_gate: bool = False
+    exit_beta: float = 0.1
+    init_std: Optional[float] = None  # None: 1/sqrt(fan_in) matrices
 
     @property
     def head_dim(self) -> int:
@@ -108,6 +133,16 @@ def llama3_8b() -> LlamaConfig:
                        n_kv_heads=8, ffn_hidden=14336, rope_theta=500000.0)
 
 
+def ouro_2p6b() -> LlamaConfig:
+    """Ouro-2.6B (huggingface.co/ByteDance/Ouro-2.6B config.json): 48 layers
+    run four times with tied weights, 16 heads of 128, SwiGLU 5632.  Four
+    passes deep over one pass of parameters, so remat is not a knob here."""
+    return LlamaConfig(vocab_size=49152, dim=2048, n_layers=48, n_heads=16,
+                       n_kv_heads=16, ffn_hidden=5632, rope_theta=1e6,
+                       norm_eps=1e-6, remat=True, n_passes=4,
+                       sandwich_norm=True, exit_gate=True, init_std=0.02)
+
+
 def tiny_llama(vocab: int = 256, dim: int = 64, layers: int = 2) -> LlamaConfig:
     """Smoke/test scale."""
     return LlamaConfig(vocab_size=vocab, dim=dim, n_layers=layers, n_heads=4,
@@ -117,7 +152,10 @@ def tiny_llama(vocab: int = 256, dim: int = 64, layers: int = 2) -> LlamaConfig:
 def init_llama(cfg: LlamaConfig, key: Array) -> Dict[str, Any]:
     """fp32 master parameters (cast to ``cfg.dtype`` at use)."""
     def dense(key, fan_in, shape):
-        return (jax.random.normal(key, shape, jnp.float32) / math.sqrt(fan_in))
+        w = jax.random.normal(key, shape, jnp.float32)
+        if cfg.init_std is not None:
+            return w * cfg.init_std
+        return w / math.sqrt(fan_in)
 
     keys = jax.random.split(key, cfg.n_layers + 3)
     hd = cfg.head_dim
@@ -132,6 +170,9 @@ def init_llama(cfg: LlamaConfig, key: Array) -> Dict[str, Any]:
             "wo": dense(k[3], cfg.n_heads * hd, (cfg.n_heads * hd, cfg.dim)),
             "mlp_norm": jnp.ones((cfg.dim,), jnp.float32),
         }
+        if cfg.sandwich_norm:
+            layer["attn_post_norm"] = jnp.ones((cfg.dim,), jnp.float32)
+            layer["mlp_post_norm"] = jnp.ones((cfg.dim,), jnp.float32)
         if cfg.is_moe_layer(i):
             e = cfg.n_experts
             layer.update({
@@ -147,12 +188,16 @@ def init_llama(cfg: LlamaConfig, key: Array) -> Dict[str, Any]:
                 "w_down": dense(k[6], cfg.ffn, (cfg.ffn, cfg.dim)),
             })
         layers.append(layer)
-    return {
+    params = {
         "embed": jax.random.normal(keys[-3], (cfg.vocab_size, cfg.dim), jnp.float32) * 0.02,
         "layers": layers,
         "final_norm": jnp.ones((cfg.dim,), jnp.float32),
         "lm_head": dense(keys[-2], cfg.dim, (cfg.dim, cfg.vocab_size)),
     }
+    if cfg.exit_gate:
+        params["exit_gate"] = {"w": dense(keys[-1], cfg.dim, (cfg.dim,)),
+                               "b": jnp.zeros((1,), jnp.float32)}
+    return params
 
 
 def param_specs(cfg: LlamaConfig, tensor_axis: str = "tensor") -> Dict[str, Any]:
@@ -171,6 +216,8 @@ def param_specs(cfg: LlamaConfig, tensor_axis: str = "tensor") -> Dict[str, Any]
             "wq": P(None, t), "wk": P(None, t), "wv": P(None, t),
             "wo": P(t, None),
         }
+        if cfg.sandwich_norm:
+            layer.update({"attn_post_norm": P(), "mlp_post_norm": P()})
         if cfg.is_moe_layer(i):
             # expert parallelism: the leading expert dim shards over the
             # tensor axis (router replicated — every rank routes all tokens)
@@ -185,12 +232,15 @@ def param_specs(cfg: LlamaConfig, tensor_axis: str = "tensor") -> Dict[str, Any]
                 "w_down": P(t, None),
             })
         layers.append(layer)
-    return {
+    specs = {
         "embed": P(),
         "layers": layers,
         "final_norm": P(),
         "lm_head": P(None, t),
     }
+    if cfg.exit_gate:
+        specs["exit_gate"] = {"w": P(), "b": P()}
+    return specs
 
 
 def _rms_norm(x: Array, w: Array, eps: float) -> Array:
@@ -273,6 +323,7 @@ def apply_llama(
     seq_axis: Optional[str] = None,
     with_aux: bool = False,
     return_hidden: bool = False,
+    all_passes: bool = False,
 ):
     """Per-device forward: ``tokens`` [B_local, T_local] -> logits
     [B_local, T_local, V_local] (vocab-sharded when ``tensor_axis`` is set).
@@ -284,6 +335,13 @@ def apply_llama(
     dense configs).  ``return_hidden`` skips the head and yields the
     final-normed hidden states instead of logits — the input
     :func:`fused_head_xent` wants (it owns the head matmul).
+
+    A looped config (``cfg.n_passes`` > 1) returns its last pass's output:
+    without an exit gate that pass is the model.  With ``all_passes`` the
+    output gains a leading axis of ``n_passes`` and
+    the return becomes ``(out, gate[, aux])``: ``gate`` [n_passes, B, T] are
+    the exit gate's float32 logits (None without ``cfg.exit_gate``), the
+    inputs of :func:`exit_weighted_loss`.
     """
     dt = cfg.dtype
     hd = cfg.head_dim
@@ -295,8 +353,6 @@ def apply_llama(
         pos = jnp.arange(tokens.shape[1])
 
     h = params["embed"].astype(dt)[tokens]  # [B, T, D]
-    aux_total = jnp.zeros((), jnp.float32)
-    n_moe = 0
 
     def layer_fn(h, lp, is_moe):
         x = _rms_norm(h, lp["attn_norm"], cfg.norm_eps)
@@ -309,9 +365,12 @@ def apply_llama(
         v = v.reshape(b, t, -1, hd).transpose(0, 2, 1, 3)
         q = _rope(q, pos, cfg.rope_theta)
         k = _rope(k, pos, cfg.rope_theta)
-        o = ring_attention(q, k, v, axis_name=seq_axis)  # [B, Hl, T, hd]
+        with obs_trace.phase("attn"):
+            o = ring_attention(q, k, v, axis_name=seq_axis)  # [B, Hl, T, hd]
         o = o.transpose(0, 2, 1, 3).reshape(b, t, -1)
         attn_out = _psum_if(o @ lp["wo"].astype(dt), tensor_axis)  # row-parallel
+        if cfg.sandwich_norm:
+            attn_out = _rms_norm(attn_out, lp["attn_post_norm"], cfg.norm_eps)
         h = h + attn_out
 
         x = _rms_norm(h, lp["mlp_norm"], cfg.norm_eps)
@@ -322,26 +381,86 @@ def apply_llama(
             up = x @ lp["w_up"].astype(dt)
             mlp_out = _psum_if((gate * up) @ lp["w_down"].astype(dt), tensor_axis)
             aux = jnp.zeros((), jnp.float32)
+        if cfg.sandwich_norm:
+            mlp_out = _rms_norm(mlp_out, lp["mlp_post_norm"], cfg.norm_eps)
         return h + mlp_out, aux
 
     if cfg.remat:
         layer_fn = jax.checkpoint(layer_fn, static_argnums=(2,))
 
-    for li, lp in enumerate(params["layers"]):
-        is_moe = cfg.is_moe_layer(li)
-        h, aux = layer_fn(h, lp, is_moe)
-        if is_moe:
-            aux_total = aux_total + aux
-            n_moe += 1
+    def stack_fn(h):
+        """One pass: the layers in order, then the final norm."""
+        aux_total = jnp.zeros((), jnp.float32)
+        n_moe = 0
+        for li, lp in enumerate(params["layers"]):
+            is_moe = cfg.is_moe_layer(li)
+            h, aux = layer_fn(h, lp, is_moe)
+            if is_moe:
+                aux_total = aux_total + aux
+                n_moe += 1
+        return (_rms_norm(h, params["final_norm"], cfg.norm_eps),
+                aux_total / max(n_moe, 1))
 
-    h = _rms_norm(h, params["final_norm"], cfg.norm_eps)
-    if return_hidden:
-        out = h
-    else:
-        out = h @ params["lm_head"].astype(dt)  # [B, T, V_local]
+    with obs_trace.phase("stack"):
+        if cfg.n_passes == 1:
+            h, aux = stack_fn(h)
+            hs = h[None]
+        else:
+            # the weights are constants of the scan: its backward sums each
+            # one's gradient over the passes in the carry
+            def one_pass(h, _):
+                h, aux = stack_fn(h)
+                return h, (h, aux)
+
+            h, (hs, auxs) = jax.lax.scan(one_pass, h, None,
+                                        length=cfg.n_passes)
+            aux = jnp.mean(auxs)
+
+    out = hs if all_passes else h
+    if not return_hidden:
+        out = out @ params["lm_head"].astype(dt)  # [..., B, T, V_local]
+    ret = (out,)
+    if all_passes:
+        gate = None
+        if cfg.exit_gate:
+            with obs_trace.phase("exit"):
+                g = params["exit_gate"]
+                gate = jnp.einsum("rbtd,d->rbt", hs.astype(jnp.float32),
+                                  g["w"]) + g["b"]
+        ret += (gate,)
     if with_aux:
-        return out, aux_total / max(n_moe, 1)
-    return out
+        ret += (aux,)
+    return ret if len(ret) > 1 else out
+
+
+def exit_log_probs(gate: Array) -> Array:
+    """Log of a looped model's exit distribution from its gate's logits
+    ``gate`` [R, ...]: with ``lambda_r = sigmoid(gate_r)`` a token exits
+    after pass r with probability ``p_r = lambda_r prod_{j<r} (1 -
+    lambda_j)``, the last pass taking what is left (its own gate is not
+    read).  Kept in logs, so a saturated gate gives 0 and not NaN."""
+    stayed = jnp.cumsum(jax.nn.log_sigmoid(-gate[:-1]), axis=0)  # log prod(1-l)
+    zero = jnp.zeros_like(gate[:1])
+    return (jnp.concatenate([zero, stayed], axis=0)
+            + jnp.concatenate([jax.nn.log_sigmoid(gate[:-1]), zero], axis=0))
+
+
+def exit_weighted_loss(nll: Array, gate: Array, beta: float):
+    """The looped model's loss from its passes' per-token cross-entropies
+    ``nll`` [R, ...] and gate logits ``gate`` [R, ...] (float32): per token
+    ``sum_r p_r nll_r + beta sum_r p_r log p_r`` with ``p`` of
+    :func:`exit_log_probs`, the expected loss less ``beta`` times the exit
+    distribution's entropy; the mean over tokens.  Returns ``(loss,
+    stats)``, the stats being what the step reports and keeps: every pass's
+    mean cross-entropy, mean exit mass, and the mean entropy."""
+    log_p = exit_log_probs(gate)
+    p = jnp.exp(log_p)
+    plogp = jnp.sum(p * log_p, axis=0)
+    tokens = tuple(range(1, nll.ndim))
+    stats = {"pass_loss": jnp.mean(nll, axis=tokens),
+             "exit_mass": jnp.mean(p, axis=tokens),
+             "exit_entropy": -jnp.mean(plogp)}
+    return jnp.mean(jnp.sum(p * nll, axis=0) + beta * plogp), stats
 
 
 # Fused head+xent defaults by SHAPE (r5).  Measured on chip:
@@ -385,23 +504,34 @@ def _fhx_chunks(v_local: int, chunk: int):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def fused_head_xent_tokens(h: Array, w: Array, targets: Array,
+                           tensor_axis: Optional[str] = None,
+                           chunk: int = 2048) -> Array:
+    """Per-token next-token cross-entropy STRAIGHT from hidden states — the
+    LM head matmul and the softmax-xent fused through a running logsumexp
+    over vocab chunks, so the [N, V] logits (and AD's saved probabilities —
+    at the r4 LM config ~0.5-1.5 GB/step of HBM traffic) never materialise.
+
+    ``h`` [..., D], ``w`` [D, V_local] (vocab-sharded under
+    ``tensor_axis``), ``targets`` [...] global ids; returns float32 losses
+    shaped like ``targets``, so a caller can weight them token by token (the
+    looped model's exit-weighted loss stacks its passes' hidden states into
+    one call, and the head's weight gradient sums over them in the
+    backward's float32 accumulation).  Numerically equal to the per-token
+    terms of ``vocab_parallel_xent(h @ w, targets)`` (same max-shift, same
+    psum structure); the hand-written VJP recomputes each chunk's logits in
+    the backward (flash-attention discipline: trade one extra matmul pass
+    for the activation storage).
+    """
+    nll, _ = _fhx_fwd(h, w, targets, tensor_axis, chunk)
+    return nll
+
+
 def fused_head_xent(h: Array, w: Array, targets: Array,
                     tensor_axis: Optional[str] = None,
                     chunk: int = 2048) -> Array:
-    """Mean next-token cross-entropy STRAIGHT from hidden states — the LM
-    head matmul and the softmax-xent fused through a running logsumexp over
-    vocab chunks, so the [N, V] logits (and AD's saved probabilities — at
-    the r4 LM config ~0.5-1.5 GB/step of HBM traffic) never materialise.
-
-    ``h`` [..., D], ``w`` [D, V_local] (vocab-sharded under
-    ``tensor_axis``), ``targets`` [...] global ids.  Numerically equal to
-    ``vocab_parallel_xent(h @ w, targets)`` (same max-shift, same psum
-    structure); the hand-written VJP recomputes each chunk's logits in the
-    backward (flash-attention discipline: trade one extra matmul pass for
-    the activation storage).
-    """
-    loss, _ = _fhx_fwd(h, w, targets, tensor_axis, chunk)
-    return loss
+    """Mean of :func:`fused_head_xent_tokens` over the tokens."""
+    return jnp.mean(fused_head_xent_tokens(h, w, targets, tensor_axis, chunk))
 
 
 def _fhx_scan_stats(h2, w, targets1, off, v_local, c, nc):
@@ -464,8 +594,7 @@ def _fhx_fwd(h, w, targets, tensor_axis, chunk):
         zt = jax.lax.psum(zt, tensor_axis)
         m = m_g
     lse = m + jnp.log(l)
-    loss = jnp.mean(lse - zt)
-    return loss, (h, w, targets, lse)
+    return (lse - zt).reshape(targets.shape), (h, w, targets, lse)
 
 
 def _fhx_bwd(tensor_axis, chunk, res, g):
@@ -485,7 +614,7 @@ def _fhx_bwd(tensor_axis, chunk, res, g):
     # but that feeds dh only through w_c == 0 (inert) and dw only in the
     # sliced-off pad columns; the onehot never lands there (targets are
     # within the true vocab)
-    dnll = (g / n).astype(jnp.float32)
+    dnll = g.reshape(-1).astype(jnp.float32)[:, None]   # a token's own
     w3 = w_p.reshape(d, nc, c).transpose(1, 0, 2)
 
     def body(dh, xs):
@@ -539,18 +668,18 @@ def _fhx_bwd(tensor_axis, chunk, res, g):
             dt_ct.reshape(targets.shape))
 
 
-fused_head_xent.defvjp(_fhx_fwd, _fhx_bwd)
+fused_head_xent_tokens.defvjp(_fhx_fwd, _fhx_bwd)
 
 
-def vocab_parallel_xent(
+def vocab_parallel_xent_tokens(
     local_logits: Array,
     targets: Array,
     *,
     tensor_axis: Optional[str] = None,
 ) -> Array:
-    """Mean next-token cross-entropy from vocab-sharded logits.
+    """Per-token next-token cross-entropy from vocab-sharded logits.
 
-    ``local_logits`` [B, T, V_local], ``targets`` [B, T] global token ids.
+    ``local_logits`` [..., V_local], ``targets`` [...] global token ids.
     The three reductions (max, sum-exp, target logit) psum over the tensor
     axis — megatron's vocab-parallel loss, sized O(B*T) on the wire instead
     of O(B*T*V).
@@ -575,5 +704,15 @@ def vocab_parallel_xent(
     if tensor_axis is not None:
         sumexp = jax.lax.psum(sumexp, tensor_axis)
         zt = jax.lax.psum(zt, tensor_axis)
-    nll = jnp.log(sumexp) + zmax - zt
-    return jnp.mean(nll)
+    return jnp.log(sumexp) + zmax - zt
+
+
+def vocab_parallel_xent(
+    local_logits: Array,
+    targets: Array,
+    *,
+    tensor_axis: Optional[str] = None,
+) -> Array:
+    """Mean of :func:`vocab_parallel_xent_tokens` over the tokens."""
+    return jnp.mean(vocab_parallel_xent_tokens(
+        local_logits, targets, tensor_axis=tensor_axis))

@@ -137,6 +137,13 @@ declare("count", COUNTER, "examples", "sum", "step",
         "examples this step (global)")
 declare("tokens", COUNTER, "tokens", "sum", "step",
         "tokens this step (global)")
+for _r in range(1, 9):      # a looped LM's passes (models/transformer.py)
+    declare(f"loss/pass{_r}", GAUGE, "nats", "mean", "step",
+            f"mean cross-entropy of pass {_r}'s logits")
+    declare(f"model/exit_mass{_r}", GAUGE, "ratio", "mean", "step",
+            f"mean over tokens of the probability of exiting after pass {_r}")
+declare("model/exit_entropy", GAUGE, "nats", "mean", "step",
+        "mean over tokens of the exit distribution's entropy")
 declare("guard/loss_scale", GAUGE, "scale", "mean", "step",
         "live dynamic loss scale (replicated)")
 declare("guard/skipped", COUNTER, "steps", "max", "step",

@@ -54,8 +54,12 @@ __all__ = ["PHASES", "LOOP_SPANS", "phase", "chunk", "host_span",
 #:             contribution-in and combined-partial-out hops)
 #:   recompress  hierarchical transport: pack + slice the pod-reduced
 #:             gradient's nonzero union for the inter-pod exchange
+#:   stack / attn / head_xent / exit   inside ``grad`` of the LM step: the
+#:             decoder's passes over its layers, the attention calls in
+#:             them, the head with its cross-entropies, and a looped
+#:             model's exit gate and loss weighting
 PHASES = ("grad", "ef", "compress", "route", "reduce", "return", "update",
-          "ici_reduce", "recompress")
+          "ici_reduce", "recompress", "stack", "attn", "head_xent", "exit")
 
 
 def phase(name: str):

@@ -22,8 +22,11 @@ Mosaic-shaped storage: per-row scalars (lse, delta) cannot leave a kernel as
 they ride the LANE dimension of the tensors that already flow: the forward
 packs ``lse`` into lane ``d`` of the (lane-padded) output block, and the
 backward wrapper packs ``delta``/``lse`` into lanes ``d``/``d+1`` of the
-incoming cotangent.  At the LM head_dim of 64 the pad lanes exist anyway —
-the stats travel free.
+incoming cotangent.  At a head_dim of 64 the pad lanes exist anyway and the
+stats travel free.  At 128 (Ouro-2.6B) the data fills its tile, so the stats
+take a second 128-lane tile: the forward's packed output and the backward's
+packed cotangent are 256 lanes wide, float32, and the kernels that write and
+read them move twice the bytes of ``o`` and ``do`` for two lanes of stats.
 
 Layout: [B, H, T, D]; causal only (the framework's LM decoders); D padded to
 the 128-lane tile in the wrapper (zero columns are inert through qk/pv and
@@ -256,7 +259,9 @@ def _pick_blocks(t: int) -> tuple:
 
 def _d_store(d: int) -> int:
     d_pad = d + (-d) % 128
-    # lse/delta ride lanes d, d+1 — need two spare lanes past the data
+    # lse/delta ride lanes d, d+1 — need two spare lanes past the data.  A
+    # d that fills its tile (128) pays a whole further tile for them: the
+    # packed o / do are then [T, 256] float32, 2 KB a row where 1 KB is data
     return d_pad if d_pad - d >= 2 else d_pad + 128
 
 
@@ -290,6 +295,7 @@ def _fwd(q, k, v, scale, blk, interpret, d):
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attn_fwd",
     )(qs, ks, vs)
     return o_packed.reshape(b, h, t, ds)
 
@@ -318,6 +324,7 @@ def _bwd(q, k, v, dop, scale, blk, interpret, out_dtype, d):
         out_shape=jax.ShapeDtypeStruct((b * h, t, d_pad), out_dtype, vma=vma),
         scratch_shapes=[pltpu.VMEM((bq, d_pad), jnp.float32)],
         interpret=interpret,
+        name="flash_attn_dq",
     )(qs, ks, vs, dops)
     kv_block = pl.BlockSpec((1, bk, d_pad), lambda bh, kj: (bh, kj, 0),
                             memory_space=pltpu.VMEM)
@@ -362,6 +369,7 @@ def _bwd(q, k, v, dop, scale, blk, interpret, out_dtype, d):
             pltpu.VMEM((bk, d_pad), jnp.float32),
         ] + extra_scratch,
         interpret=interpret,
+        name="flash_attn_dkv",
     )(qs, ks, vs, dops)
     rs = lambda x: x.reshape(b, h, t, d_pad)
     return rs(dq), rs(dk), rs(dv)

@@ -56,11 +56,16 @@ _FUSED_ATTN = os.environ.get("TPU_CDP_FUSED_ATTN", "1") != "0"
 
 def use_fused_attention(q_shape, k_shape, itemsize: int = 2) -> bool:
     """True when the single-block causal path should hit the fused kernel
-    (:mod:`tpu_compressed_dp.ops.flash_attention`): TPU backend, seq a lane
-    multiple, head_dim MXU-friendly, K/V small enough to stream through
-    VMEM whole."""
+    (:mod:`tpu_compressed_dp.ops.flash_attention`): TPU backend and shapes
+    the kernel takes (:func:`fused_attention_fits`)."""
     if not _FUSED_ATTN or jax.default_backend() != "tpu":
         return False
+    return fused_attention_fits(q_shape, k_shape, itemsize)
+
+
+def fused_attention_fits(q_shape, k_shape, itemsize: int = 2) -> bool:
+    """Shapes the fused kernel takes: seq a lane multiple, head_dim
+    MXU-friendly, K/V small enough to stream through VMEM whole."""
     b, h, t, d = q_shape
     d_pad = d + (-d) % 128
     # Binding constraint since the r5 streamed dkv backward (which keeps its

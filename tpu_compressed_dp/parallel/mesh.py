@@ -117,10 +117,11 @@ def make_data_mesh(num_devices: Optional[int] = None, devices: Optional[Sequence
     return Mesh(np.asarray(devices), (DATA_AXIS,))
 
 
-def make_mesh(axis_sizes: Sequence[int], axis_names: Sequence[str]) -> Mesh:
+def make_mesh(axis_sizes: Sequence[int], axis_names: Sequence[str],
+              devices: Optional[Sequence[jax.Device]] = None) -> Mesh:
     """General N-D mesh for composed parallelism (dp x tp x pp x sp ...)."""
     n = int(np.prod(axis_sizes))
-    available = jax.devices()
+    available = jax.devices() if devices is None else list(devices)
     if n > len(available):
         raise ValueError(
             f"mesh {tuple(axis_sizes)} needs {n} devices but only "

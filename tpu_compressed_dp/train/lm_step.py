@@ -36,10 +36,13 @@ from jax.sharding import Mesh, PartitionSpec as P
 from tpu_compressed_dp.models.transformer import (
     LlamaConfig,
     apply_llama,
+    exit_weighted_loss,
     fused_head_xent,
+    fused_head_xent_tokens,
     param_specs,
     use_fused_head_xent,
     vocab_parallel_xent,
+    vocab_parallel_xent_tokens,
 )
 from tpu_compressed_dp.obs import trace as obs_trace
 from tpu_compressed_dp.parallel.dp import (
@@ -57,15 +60,28 @@ from tpu_compressed_dp.utils import chaos as chaos_mod
 Array = jax.Array
 
 __all__ = ["make_lm_train_step", "init_lm_ef_state", "init_lm_comp_state",
-           "lm_state_specs", "make_lm_mesh"]
+           "init_lm_model_aux", "lm_state_specs", "make_lm_mesh"]
 
 LM_AXES = ("data", "seq", "tensor")
 
 
-def make_lm_mesh(data: int, seq: int = 1, tensor: int = 1) -> Mesh:
+def make_lm_mesh(data: int, seq: int = 1, tensor: int = 1,
+                 devices=None) -> Mesh:
     from tpu_compressed_dp.parallel.mesh import make_mesh
 
-    return make_mesh((data, seq, tensor), LM_AXES)
+    return make_mesh((data, seq, tensor), LM_AXES, devices=devices)
+
+
+def init_lm_model_aux(cfg: LlamaConfig) -> Dict[str, Array]:
+    """The state's auxiliary slot (``TrainState.batch_stats``) for the LM
+    step: a config with an exit gate keeps its last step's per-pass losses,
+    mean exit masses and exit entropy there; every other config keeps
+    nothing."""
+    if not cfg.exit_gate:
+        return {}
+    return {"pass_loss": jnp.zeros((cfg.n_passes,), jnp.float32),
+            "exit_mass": jnp.zeros((cfg.n_passes,), jnp.float32),
+            "exit_entropy": jnp.zeros((), jnp.float32)}
 
 
 def init_lm_ef_state(cfg: LlamaConfig, params: Any, comp: CompressionConfig,
@@ -225,32 +241,58 @@ def make_lm_train_step(
 
         def loss_fn(params):
             # per-worker logits buffer: local tokens x vocab shard (V/tp)
-            # at the config's logits width (bf16 OR fp32 — ADVICE r5)
-            if use_fused_head_xent(x.shape[0] * x.shape[1],
-                                   cfg.vocab_size // mesh.shape["tensor"],
-                                   jnp.dtype(cfg.dtype).itemsize):
+            # at the config's logits width (bf16 OR fp32 — ADVICE r5); an
+            # exit gate makes one of every pass
+            fused = use_fused_head_xent(
+                (cfg.n_passes if cfg.exit_gate else 1) * x.shape[0] * x.shape[1],
+                cfg.vocab_size // mesh.shape["tensor"],
+                jnp.dtype(cfg.dtype).itemsize)
+            model_aux = {}
+            if cfg.exit_gate:
+                # every pass's hidden states through the one head, each
+                # token's losses weighted by its exit distribution
+                out, gate, aux = apply_llama(
+                    cfg, params, x, tensor_axis="tensor", seq_axis="seq",
+                    with_aux=True, return_hidden=fused, all_passes=True)
+                ys = jnp.broadcast_to(y, (cfg.n_passes,) + y.shape)
+                with obs_trace.phase("head_xent"):
+                    if fused:
+                        nll = fused_head_xent_tokens(
+                            out, params["lm_head"].astype(cfg.dtype), ys,
+                            "tensor")
+                    else:
+                        nll = vocab_parallel_xent_tokens(
+                            out, ys, tensor_axis="tensor")
+                with obs_trace.phase("exit"):
+                    xent, model_aux = exit_weighted_loss(nll, gate,
+                                                         cfg.exit_beta)
+            elif fused:
                 # head matmul + softmax-xent fused through a chunked running
                 # logsumexp: the [B,T,V] logits (and AD's saved softmax
                 # inputs) never materialise in HBM
                 h, aux = apply_llama(cfg, params, x, tensor_axis="tensor",
                                      seq_axis="seq", with_aux=True,
                                      return_hidden=True)
-                xent = fused_head_xent(
-                    h, params["lm_head"].astype(cfg.dtype), y, "tensor")
+                with obs_trace.phase("head_xent"):
+                    xent = fused_head_xent(
+                        h, params["lm_head"].astype(cfg.dtype), y, "tensor")
             else:
                 logits, aux = apply_llama(cfg, params, x,
                                           tensor_axis="tensor",
                                           seq_axis="seq", with_aux=True)
-                xent = vocab_parallel_xent(logits, y, tensor_axis="tensor")
+                with obs_trace.phase("head_xent"):
+                    xent = vocab_parallel_xent(logits, y,
+                                               tensor_axis="tensor")
             # backprop at loss_scale x (identity unguarded/fp32); the raw
             # xent rides along for metrics/vote
-            return (xent + cfg.moe_aux_weight * aux) * ls_scale, xent
+            return ((xent + cfg.moe_aux_weight * aux) * ls_scale,
+                    (xent, model_aux))
 
         varying = jax.tree.map(
             lambda p: jax.lax.pcast(p, sync_axes, to="varying"), state.params
         )
         with obs_trace.phase("grad"):
-            (_, loss), grads = jax.value_and_grad(
+            (_, (loss, model_aux)), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(varying)
         if inject:
             loss, grads = chaos_mod.inject(
@@ -294,6 +336,15 @@ def make_lm_train_step(
             "tokens": jax.lax.psum(ntok, sync_axes),
             "lr": optimizer_lr(optimizer, sched_step),
         }
+        # a looped model's per-pass numbers: step metrics, and kept in the
+        # state's auxiliary slot (where a CNN keeps its batch statistics)
+        model_aux = jax.tree.map(lambda v: jax.lax.pmean(v, sync_axes),
+                                 model_aux)
+        for r in range(cfg.n_passes if model_aux else 0):
+            metrics[f"loss/pass{r + 1}"] = model_aux["pass_loss"][r]
+            metrics[f"model/exit_mass{r + 1}"] = model_aux["exit_mass"][r]
+        if model_aux:
+            metrics["model/exit_entropy"] = model_aux["exit_entropy"]
         if guarded:
             metrics.update(guard_mod.guard_metrics(new_guard))
         for k, v in comm.items():
@@ -302,6 +353,7 @@ def make_lm_train_step(
 
         return dataclasses.replace(
             state, step=new_step, params=new_params, opt_state=new_opt,
+            batch_stats=model_aux or state.batch_stats,
             ef=new_ef, comp=new_comp, guard=new_guard,
         ), metrics
 

@@ -251,6 +251,12 @@ def make_pp_train_step(
         raise NotImplementedError(
             "powersgd is not yet supported with pipeline parallelism; "
             "run it on a (data[, seq]) mesh")
+    if cfg.n_passes > 1 or cfg.sandwich_norm or cfg.exit_gate:
+        # the stages' layer body is its own copy of the plain block, and a
+        # looped model sends the last stage's output round to the first
+        raise NotImplementedError(
+            "looped passes, sandwich norms and the exit gate run on the "
+            "(data, seq, tensor) step only")
     stages = mesh.shape["pipe"]
     tp = mesh.shape.get("tensor", 1)
     sp = mesh.shape.get("seq", 1)
