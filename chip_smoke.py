@@ -16,7 +16,7 @@ size against the XLA chain it replaces.  Phases:
   memory_balance   per-device peak bytes within 2x of each other
   kernels          topk_threshold, fused_sparsify, fused_select_pack,
                    fused_bucket_route, terngrad/qsgd pack + quantize, the
-                   hardware-PRNG uniform fill, flash attention fwd/dq/dkv
+                   hardware-PRNG uniform fill, flash attention fwd/bwd
 
 It fails, with a non-zero exit code and no result line, unless JAX reports a
 TPU, and when any phase fails; a failed phase does not stop the later ones,
@@ -425,8 +425,8 @@ def kernel_checks() -> list:
         ("qsgd_pack leaf", lambda: k_qsgd_pack(LEAF_N)),
         ("terngrad/qsgd_quantize leaf", lambda: k_quantize_levels(LEAF_N)),
         ("uniform flat", lambda: k_uniform(FLAT_N)),
-        ("flash fwd/dq/dkv f32", lambda: k_flash(f32, 5e-3)),
-        ("flash fwd/dq/dkv bf16", lambda: k_flash(bf16, 3e-2)),
+        ("flash fwd/bwd f32", lambda: k_flash(f32, 5e-3)),
+        ("flash fwd/bwd bf16", lambda: k_flash(bf16, 3e-2)),
     ]
 
 

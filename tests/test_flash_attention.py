@@ -4,11 +4,14 @@ the kernel is the dispatched single-block attention path of the LM step, so
 a sign/transpose slip in the hand-written VJP would corrupt training
 gradients silently."""
 
+import os
+
 import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
 
 import tpu_compressed_dp.ops.ring_attention as ra_mod
 from tpu_compressed_dp.ops.flash_attention import flash_causal_attention
@@ -31,6 +34,8 @@ def exact(q, k, v):
         (1, 1, 384, 64),    # seq needs the reduced 128 block
         (1, 2, 512, 128),   # Ouro's head size at one whole 512 block, two heads
         (1, 1, 1024, 128),  # ... and over two blocks: the causal block skip
+        (1, 2, 1536, 64),   # three 512-blocks: dq block 2 sums three kv steps
+        (1, 1, 2048, 128),  # four, Ouro's head size: the skip is 0, 1, 2, 3 blocks
     ],
 )
 def test_forward_and_grads_match_exact(shape):
@@ -54,17 +59,20 @@ def test_forward_and_grads_match_exact(shape):
                                    err_msg=f"d{nm}")
 
 
-@pytest.mark.parametrize("shape", [(1, 2, 256, 64), (1, 1, 256, 128)])
-def test_streamed_dkv_matches_resident(monkeypatch, shape):
-    """The DMA/double-buffered dkv kernel (`_dkv_kernel_streamed`) against
-    the VMEM-resident form, both under interpret: the streamed path is the
-    only one real TPU runs take for the backward, but interpret mode (the
-    only CI-runnable path) defaulted to the resident kernel — so the
+@pytest.mark.parametrize(
+    "shape", [(1, 2, 256, 64), (1, 1, 256, 128), (1, 1, 1536, 64)])
+def test_streamed_bwd_matches_resident(monkeypatch, shape):
+    """The backward kernel's DMA/double-buffered staging of Q and the packed
+    cotangent against its VMEM-resident staging, both under interpret: the
+    streamed staging is the only one real TPU runs take, but interpret mode
+    (the only CI-runnable path) defaults to the resident one — so the
     explicit-DMA machinery had zero off-chip coverage (ADVICE r5).
     `TPU_CDP_FORCE_STREAMED_DKV=1` runs it under the Pallas interpreter;
     the two must agree to fp32 roundoff (identical math via
-    `_dkv_block_math`, different operand staging).  At a head size of 128
-    the packed cotangent it streams is two lane tiles wide."""
+    `_bwd_block_math`, different operand staging).  At a head size of 128
+    the packed cotangent it streams is two lane tiles wide; at three blocks
+    the first q block of a kv step lands in either buffer slot and the dq
+    accumulator carries over the kv axis."""
     ks = jax.random.split(jax.random.key(3), 4)
     q, k, v = (jax.random.normal(kk, shape, jnp.float32) * 0.5
                for kk in ks[:3])
@@ -80,3 +88,78 @@ def test_streamed_dkv_matches_resident(monkeypatch, shape):
     for a, b, nm in zip(g_streamed, g_resident, "qkv"):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-6,
                                    err_msg=f"d{nm} streamed vs resident")
+
+
+def _grad_of_sum(shape, dtype, **aval):
+    """`jax.grad` of a sum through the kernel as dispatched (not interpreted),
+    and its abstract operand: for tests that trace or compile and never run."""
+    assert ra_mod.fused_attention_fits(shape, shape, jnp.dtype(dtype).itemsize)
+    loss = lambda q, k, v: jnp.sum(
+        flash_causal_attention(q, k, v).astype(jnp.float32))
+    return jax.grad(loss, (0, 1, 2)), jax.ShapeDtypeStruct(shape, dtype, **aval)
+
+
+def _pallas_calls(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn.params["name"]
+            continue
+        for val in eqn.params.values():
+            for sub in val if isinstance(val, (list, tuple)) else (val,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _pallas_calls(sub)
+
+
+@pytest.mark.parametrize(
+    "shape, dtype",
+    [
+        ((1, 2, 256, 64), jnp.float32),
+        ((1, 16, 4096, 128), jnp.bfloat16),   # the LM cell's attention call
+        ((1, 16, 8192, 128), jnp.bfloat16),   # the longest admitted sequence
+    ],
+)
+def test_grad_is_one_forward_and_one_backward_kernel(shape, dtype):
+    """The mechanism, not the numbers: differentiating through the kernel
+    launches the forward and ONE backward `pallas_call`, for every shape the
+    dispatcher admits — no second backward kernel, no chooser between forms.
+    Traced only (shapes in, jaxpr out): nothing compiles or runs."""
+    grad, x = _grad_of_sum(shape, dtype)
+    jaxpr = jax.make_jaxpr(grad)(x, x, x)
+    assert list(_pallas_calls(jaxpr.jaxpr)) == [
+        "flash_attn_fwd", "flash_attn_bwd"]
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """A described (not attached) v5e chip: the TPU compiler refuses here
+    what it would refuse on the chip.  Described inside the fixture, never at
+    import: only the worker that runs this file loads libtpu."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize(
+    "shape, dtype",
+    [
+        ((1, 16, 4096, 128), jnp.bfloat16),   # the LM cell's attention call
+        ((1, 2, 8192, 128), jnp.bfloat16),    # longest T: dq accumulator 4 MB
+        ((1, 2, 4096, 256), jnp.bfloat16),    # widest head: 4 MB at 512-blocks
+        ((1, 2, 4096, 128), jnp.float32),     # fp32 operands, 256-lane cotangent
+    ],
+)
+def test_backward_compiles_for_v5e_at_admitted_extremes(one_chip, shape, dtype):
+    """Forward and the one backward kernel pass Mosaic for a v5e at the
+    extremes `fused_attention_fits` admits: the [T, d_pad] float32 dq
+    accumulator, the streamed blocks and the [blk, blk] temporaries fit the
+    scoped-VMEM ceiling, so no shape needs a second form of the backward.
+    A compile, not a run: nothing here is a time."""
+    grad, x = _grad_of_sum(shape, dtype, sharding=one_chip)
+    compiled = jax.jit(grad).lower(x, x, x).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 2

@@ -13,9 +13,13 @@ engines run inside ``shard_map`` with replication checking on: every
 (``_vma`` plumbing, like ops/kernels.py), which stock kernels do not thread.
 
 Backward follows the flash-attention recipe: save (o, lse) from forward,
-precompute ``delta = rowsum(do * o)``, then one kernel accumulates dq over
-K/V blocks and a second accumulates (dk, dv) over Q blocks — each recomputes
-its score block in VMEM instead of reading a saved [T, T].
+precompute ``delta = rowsum(do * o)``, then ONE kernel walks the live
+(q block, kv block) pairs of a head, recomputes each pair's score block in
+VMEM instead of reading a saved [T, T], and feeds s, p, dP and ds — computed
+once a pair — to all three gradients: dk/dv of the grid step's kv block
+accumulate over its q blocks, dq of the whole head accumulates over the kv
+axis in a float32 [T, d_pad] VMEM scratch (5 products a pair; a kernel for
+dq and one for dk/dv would each recompute s and dP: 7, and the chain twice).
 
 Mosaic-shaped storage: per-row scalars (lse, delta) cannot leave a kernel as
 ``[1, block_q]`` blocks (block last-two-dims must be 8/128-divisible), so
@@ -106,48 +110,14 @@ def _fwd_kernel(scale: float, blk_q: int, blk_k: int, n_k: int, d: int,
     o_ref[0] = out.astype(o_ref.dtype)
 
 
-def _dq_kernel(scale: float, blk_q: int, blk_k: int, n_k: int, d: int,
-               q_ref, k_ref, v_ref, dop_ref, dq_ref, acc_ref):
-    qi = pl.program_id(1)
-    acc_ref[:] = jnp.zeros_like(acc_ref)
-    q = q_ref[0]
-    d_pad = q.shape[-1]
-    dop = dop_ref[0]                                 # packed: do | delta | lse
+def _bwd_block_math(scale, blk_q, blk_k, d, kj, qi, q, dop, k, v,
+                    dq_acc, dk_acc, dv_acc):
+    """One (q block) x (kv block) pair of the backward: s, p, dP and ds are
+    computed once and feed all three accumulators — shared by the
+    VMEM-resident and the HBM-streamed stagings of the kernel."""
+    d_pad = k.shape[-1]
     # re-pad do to d_pad lanes so contractions align with the padded k/v
     # (zero lanes are inert through every product)
-    do = jnp.concatenate(
-        [dop[:, :d], jnp.zeros((blk_q, d_pad - d), dop.dtype)],
-        axis=1).astype(jnp.float32) if d_pad > d else dop[:, :d].astype(jnp.float32)
-    delta = dop[:, d:d + 1].astype(jnp.float32)
-    lse = dop[:, d + 1:d + 2].astype(jnp.float32)
-
-    def body(kj, _):
-        k = k_ref[0, pl.ds(kj * blk_k, blk_k)]
-        v = v_ref[0, pl.ds(kj * blk_k, blk_k)]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        p = jnp.where(_causal_pos(qi, kj, blk_q, blk_k),
-                      jnp.exp(s - lse), 0.0)
-        dp = jax.lax.dot_general(
-            do, v.astype(jnp.float32), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * scale
-        acc_ref[:] += jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return 0
-
-    n_live = jnp.minimum(((qi + 1) * blk_q + blk_k - 1) // blk_k, n_k)
-    jax.lax.fori_loop(0, n_live, body, 0)
-    dq_ref[0] = acc_ref[:].astype(dq_ref.dtype)
-
-
-def _dkv_block_math(scale, blk_q, blk_k, d, kj, qi, q, dop, k, v,
-                    dk_acc, dv_acc):
-    """One (q block) x (kv block) accumulation of dk/dv — shared by the
-    VMEM-resident and the HBM-streamed dkv kernels."""
-    d_pad = k.shape[-1]
     do = jnp.concatenate(
         [dop[:, :d], jnp.zeros((blk_q, d_pad - d), dop.dtype)],
         axis=1).astype(jnp.float32) if d_pad > d else dop[:, :d].astype(jnp.float32)
@@ -168,89 +138,92 @@ def _dkv_block_math(scale, blk_q, blk_k, d, kj, qi, q, dop, k, v,
     dk_acc[:] += jax.lax.dot_general(
         ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
+    dq_acc[pl.ds(qi * blk_q, blk_q)] += jax.lax.dot_general(
+        ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
 
 
-def _dkv_kernel(scale: float, blk_q: int, blk_k: int, n_q: int, d: int,
-                q_ref, k_ref, v_ref, dop_ref, dk_ref, dv_ref,
-                dk_acc, dv_acc):
-    kj = pl.program_id(1)
-    dk_acc[:] = jnp.zeros_like(dk_acc)
-    dv_acc[:] = jnp.zeros_like(dv_acc)
-    k = k_ref[0]                                     # [blk_k, d_pad]
-    v = v_ref[0]
+def _bwd_kernel(scale: float, blk_q: int, blk_k: int, n_q: int, d: int,
+                q_ref, k_ref, v_ref, dop_ref, dq_ref, dk_ref, dv_ref,
+                dq_acc, dk_acc, dv_acc, *stream):
+    """The whole backward of one head, one kv block a grid step.  dk/dv of
+    the step's kv block accumulate over the q blocks at or below the
+    diagonal; dq of the whole head accumulates in ``dq_acc`` [T, d_pad],
+    which stays in VMEM across the (sequential) kv axis: q block ``qi``
+    receives its kv blocks in ascending ``kj``, and q rows inside kv block
+    ``kj`` attend nothing past it, so their dq is final when step ``kj``
+    ends and leaves as that step's output block.
 
-    def body(qi, _):
-        q = q_ref[0, pl.ds(qi * blk_q, blk_q)]
-        dop = dop_ref[0, pl.ds(qi * blk_q, blk_q)]
-        _dkv_block_math(scale, blk_q, blk_k, d, kj, qi, q, dop, k, v,
-                        dk_acc, dv_acc)
-        return 0
-
-    # q blocks qi >= kj*blk_k // blk_q can contain positions >= this kv block
-    first = kj * blk_k // blk_q
-    jax.lax.fori_loop(first, n_q, body, 0)
-    dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
-    dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
-
-
-def _dkv_kernel_streamed(scale: float, blk_q: int, blk_k: int, n_q: int,
-                         d: int, q_hbm, k_ref, v_ref, dop_hbm,
-                         dk_ref, dv_ref, dk_acc, dv_acc,
-                         q_buf, dop_buf, q_sem, dop_sem):
-    """dkv with the full-T operands (Q and the packed cotangent) left in
-    HBM and double-buffered per q-block via explicit DMA.  At T=8192/d=128
-    the VMEM-resident form's q (bf16, 2 MB) + packed f32 cotangent (8 MB),
+    Two stagings of the full-T operands (Q and the packed cotangent).  With
+    ``stream`` empty they are whole VMEM blocks (interpret mode's default).
+    Otherwise they stay in HBM and ``stream`` = (q_buf, dop_buf, q_sem,
+    dop_sem) double-buffers them per q block via explicit DMA: at
+    T=8192/d=128 the resident q (bf16, 2 MB) + packed f32 cotangent (8 MB),
     Mosaic-double-buffered, blow the 16 MB scoped-vmem ceiling (measured
-    17.5 MB, r5); streaming keeps residency at 2 q-blocks + 2 dop-blocks
-    (~1 MB) regardless of T, so long single-chip sequences are bounded by
-    HBM, not scoped VMEM."""
+    17.5 MB, r5); streamed, residency is 2 q-blocks + 2 dop-blocks (~1 MB)
+    plus the dq accumulator (T * d_pad * 4 bytes, 4 MB at most under
+    ``ring_attention.fused_attention_fits``)."""
     bh = pl.program_id(0)
     kj = pl.program_id(1)
     dk_acc[:] = jnp.zeros_like(dk_acc)
     dv_acc[:] = jnp.zeros_like(dv_acc)
-    k = k_ref[0]
+
+    @pl.when(kj == 0)
+    def _():
+        dq_acc[:] = jnp.zeros_like(dq_acc)
+
+    k = k_ref[0]                                     # [blk_k, d_pad]
     v = v_ref[0]
-
-    def q_dma(qi, slot):
-        return pltpu.make_async_copy(
-            q_hbm.at[bh, pl.ds(qi * blk_q, blk_q)], q_buf.at[slot],
-            q_sem.at[slot])
-
-    def dop_dma(qi, slot):
-        return pltpu.make_async_copy(
-            dop_hbm.at[bh, pl.ds(qi * blk_q, blk_q)], dop_buf.at[slot],
-            dop_sem.at[slot])
-
+    rows = lambda qi: pl.ds(qi * blk_q, blk_q)
+    # q blocks qi >= kj*blk_k // blk_q can contain positions >= this kv block
     first = kj * blk_k // blk_q
-    q_dma(first, jax.lax.rem(first, 2)).start()
-    dop_dma(first, jax.lax.rem(first, 2)).start()
+
+    if not stream:
+        fetch = lambda qi: (q_ref[0, rows(qi)], dop_ref[0, rows(qi)])
+    else:
+        q_buf, dop_buf, q_sem, dop_sem = stream
+
+        def dmas(qi):
+            slot = jax.lax.rem(qi, 2)
+            return (
+                pltpu.make_async_copy(q_ref.at[bh, rows(qi)], q_buf.at[slot],
+                                      q_sem.at[slot]),
+                pltpu.make_async_copy(dop_ref.at[bh, rows(qi)],
+                                      dop_buf.at[slot], dop_sem.at[slot]))
+
+        for dma in dmas(first):
+            dma.start()
+
+        def fetch(qi):
+            @pl.when(qi + 1 < n_q)
+            def _():
+                for dma in dmas(qi + 1):
+                    dma.start()
+
+            for dma in dmas(qi):
+                dma.wait()
+            slot = jax.lax.rem(qi, 2)
+            return q_buf[slot], dop_buf[slot]
 
     def body(qi, _):
-        slot = jax.lax.rem(qi, 2)
-        nxt = jax.lax.rem(qi + 1, 2)
-
-        @pl.when(qi + 1 < n_q)
-        def _():
-            q_dma(qi + 1, nxt).start()
-            dop_dma(qi + 1, nxt).start()
-
-        q_dma(qi, slot).wait()
-        dop_dma(qi, slot).wait()
-        _dkv_block_math(scale, blk_q, blk_k, d, kj, qi, q_buf[slot],
-                        dop_buf[slot], k, v, dk_acc, dv_acc)
+        q, dop = fetch(qi)
+        _bwd_block_math(scale, blk_q, blk_k, d, kj, qi, q, dop, k, v,
+                        dq_acc, dk_acc, dv_acc)
         return 0
 
     jax.lax.fori_loop(first, n_q, body, 0)
     dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
     dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+    dq_ref[0] = dq_acc[pl.ds(kj * blk_k, blk_k)].astype(dq_ref.dtype)
 
 
 def _pick_blocks(t: int) -> tuple:
-    # smaller streamed blocks at long T: the full-T resident operands (K/V in
-    # the dq kernel; Q + packed cotangent in dkv) grow with T and the dkv
-    # kernel sits within ~1.5 MB of the 16 MB scoped-vmem ceiling at T=8192 —
-    # halving the block buffers buys that margin (r5; grid-step overhead is
-    # amortised by the larger per-step loop trip count at these T)
+    # smaller streamed blocks at long T: the operands resident for the whole
+    # head (K + V in the forward; the float32 dq accumulator in the backward)
+    # grow with T — halving the block buffers and the [blk, blk] score
+    # temporaries buys the margin under the 16 MB scoped-vmem ceiling (r5;
+    # grid-step overhead is amortised by the larger per-step loop trip count
+    # at these T)
     bq = min(256 if t >= 8192 else 512, t)
     while t % bq:
         bq //= 2
@@ -307,69 +280,44 @@ def _bwd(q, k, v, dop, scale, blk, interpret, out_dtype, d):
     ds = dop.shape[-1]
     qs, ks, vs = (x.reshape(b * h, t, d_pad) for x in (q, k, v))
     dops = dop.reshape(b * h, t, ds)
-    full = lambda w: pl.BlockSpec((1, t, w), lambda bh, i: (bh, 0, 0),
-                                  memory_space=pltpu.VMEM)
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, scale, bq, bk, t // bk, d),
-        grid=(b * h, t // bq),
-        in_specs=[
-            pl.BlockSpec((1, bq, d_pad), lambda bh, qi: (bh, qi, 0),
-                         memory_space=pltpu.VMEM),
-            full(d_pad), full(d_pad),
-            pl.BlockSpec((1, bq, ds), lambda bh, qi: (bh, qi, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((1, bq, d_pad), lambda bh, qi: (bh, qi, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((b * h, t, d_pad), out_dtype, vma=vma),
-        scratch_shapes=[pltpu.VMEM((bq, d_pad), jnp.float32)],
-        interpret=interpret,
-        name="flash_attn_dq",
-    )(qs, ks, vs, dops)
     kv_block = pl.BlockSpec((1, bk, d_pad), lambda bh, kj: (bh, kj, 0),
                             memory_space=pltpu.VMEM)
-    # Streamed dkv off-interpret: Q and the packed cotangent stay in HBM,
-    # the kernel DMAs per-q-block slices itself (see _dkv_kernel_streamed).
-    # Interpret mode (CPU tests) keeps the VMEM-resident form — identical
-    # math via _dkv_block_math — unless TPU_CDP_FORCE_STREAMED_DKV=1, which
-    # runs the DMA/double-buffer machinery under the Pallas interpreter so
-    # the streamed path has off-chip parity coverage (ADVICE r5;
-    # tests/test_flash_attention.py::test_streamed_dkv_matches_resident).
+    # Streamed off-interpret: Q and the packed cotangent stay in HBM, the
+    # kernel DMAs per-q-block slices itself (see _bwd_kernel).  Interpret
+    # mode (CPU tests) keeps them as whole VMEM blocks — identical math via
+    # _bwd_block_math — unless TPU_CDP_FORCE_STREAMED_DKV=1, which runs the
+    # DMA/double-buffer machinery under the Pallas interpreter so the
+    # streamed staging has off-chip parity coverage (ADVICE r5;
+    # tests/test_flash_attention.py::test_streamed_bwd_matches_resident).
     if interpret and os.environ.get("TPU_CDP_FORCE_STREAMED_DKV") != "1":
-        dkv_kernel = functools.partial(_dkv_kernel, scale, bq, bk, t // bq, d)
-        qd_specs = [full(d_pad), kv_block, kv_block, full(ds)]
-        extra_scratch = []
+        full = lambda w: pl.BlockSpec((1, t, w), lambda bh, kj: (bh, 0, 0),
+                                      memory_space=pltpu.VMEM)
+        q_spec, dop_spec = full(d_pad), full(ds)
+        stream_scratch = []
     else:
-        dkv_kernel = functools.partial(
-            _dkv_kernel_streamed, scale, bq, bk, t // bq, d)
-        qd_specs = [pl.BlockSpec(memory_space=pl.ANY), kv_block, kv_block,
-                    pl.BlockSpec(memory_space=pl.ANY)]
-        extra_scratch = [
+        q_spec = dop_spec = pl.BlockSpec(memory_space=pl.ANY)
+        stream_scratch = [
             pltpu.VMEM((2, bq, d_pad), qs.dtype),
             pltpu.VMEM((2, bq, ds), dops.dtype),
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA((2,)),
         ]
-    dk, dv = pl.pallas_call(
-        dkv_kernel,
+    # the kv axis carries dq_acc from step to step: it must stay sequential
+    # (Mosaic's default for an axis nobody declares parallel)
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(_bwd_kernel, scale, bq, bk, t // bq, d),
         grid=(b * h, t // bk),
-        in_specs=[qd_specs[0], qd_specs[1], qd_specs[2], qd_specs[3]],
-        out_specs=[
-            pl.BlockSpec((1, bk, d_pad), lambda bh, kj: (bh, kj, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bk, d_pad), lambda bh, kj: (bh, kj, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b * h, t, d_pad), out_dtype, vma=vma),
-            jax.ShapeDtypeStruct((b * h, t, d_pad), out_dtype, vma=vma),
-        ],
+        in_specs=[q_spec, kv_block, kv_block, dop_spec],
+        out_specs=[kv_block] * 3,
+        out_shape=[jax.ShapeDtypeStruct((b * h, t, d_pad), out_dtype,
+                                        vma=vma)] * 3,
         scratch_shapes=[
+            pltpu.VMEM((t, d_pad), jnp.float32),
             pltpu.VMEM((bk, d_pad), jnp.float32),
             pltpu.VMEM((bk, d_pad), jnp.float32),
-        ] + extra_scratch,
+        ] + stream_scratch,
         interpret=interpret,
-        name="flash_attn_dkv",
+        name="flash_attn_bwd",
     )(qs, ks, vs, dops)
     rs = lambda x: x.reshape(b, h, t, d_pad)
     return rs(dq), rs(dk), rs(dv)

@@ -68,16 +68,18 @@ def fused_attention_fits(q_shape, k_shape, itemsize: int = 2) -> bool:
     MXU-friendly, K/V small enough to stream through VMEM whole."""
     b, h, t, d = q_shape
     d_pad = d + (-d) % 128
-    # Binding constraint since the r5 streamed dkv backward (which keeps its
-    # full-T operands in HBM): the fwd/dq kernels' Mosaic-managed full-T
-    # K + V blocks, held at input dtype (bf16 in practice) and
-    # double-buffered, must fit the TPU's ~16 MB scoped-vmem ceiling with
-    # room for the streamed q/do blocks.  Cap the single-buffered K+V set
-    # at 4 MB (= 8 MB doubled + block buffers, comfortably under 16 MB):
-    # admits the chip-verified T=8192 at d=128 exactly; T=16384 (8 MB
-    # single, ~18+ doubled) would hit the same scoped-vmem wall the r5 dkv
-    # fix removed — long-context's designed path is the seq-axis ring
-    # sharding T_local below this gate.
+    # What is resident for a whole head, and so grows with T.  Forward:
+    # Mosaic-managed full-T K + V blocks, held at input dtype (bf16 in
+    # practice) and double-buffered.  Backward (one kernel): the float32 dq
+    # accumulator, T * d_pad * 4 bytes — never more than the K + V set below
+    # (equal at bf16, half at fp32) and single-buffered — beside per-block
+    # K/V and the streamed q/do blocks; its full-T operands stay in HBM.
+    # Both must fit the TPU's ~16 MB scoped-vmem ceiling.  Cap the
+    # single-buffered K+V set at 4 MB (= 8 MB doubled + block buffers,
+    # comfortably under 16 MB): admits the chip-verified T=8192 at d=128
+    # exactly; T=16384 (8 MB single, ~18+ doubled) would hit the scoped-vmem
+    # wall — long-context's designed path is the seq-axis ring sharding
+    # T_local below this gate.
     resident = t * 2 * d_pad * itemsize   # K + V at input dtype
     return (t == k_shape[2] and t >= 128 and t % 128 == 0 and d % 64 == 0
             and resident <= 4 * 1024 * 1024)
