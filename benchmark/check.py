@@ -19,7 +19,7 @@ import math
 
 import numpy as np
 
-from reference.steps import leaf_norms
+from reference.steps import diff_norms, leaf_norms
 
 
 def leaf_gaps(prog: np.ndarray, refr: np.ndarray) -> np.ndarray:
@@ -28,18 +28,26 @@ def leaf_gaps(prog: np.ndarray, refr: np.ndarray) -> np.ndarray:
     return np.abs(prog - refr) / np.maximum(refr, np.median(refr))
 
 
-def program_readings(optim, sync, opt_cfg: dict, p0, probe1: dict, p3, losses) -> dict:
-    """Per-leaf norms of what the step left in its state.  ``p0``/``p3`` are
-    the parameters before the first and after the last step, ``probe1`` what
-    the builder's ``probe`` read after the first: optimizer state, residual
-    ([world, ...] leaves or None).  Also returns the trees the sync semantics'
-    exact checks read (``_g1``, ``_ef1``)."""
-    g1 = optim.first_gradient(p0, probe1["opt"], opt_cfg)
-    out = {"loss": list(losses), "grad1": leaf_norms(g1), "mean_grad1": leaf_norms(g1),
-           "dparam": leaf_norms([a - b for a, b in zip(p3, p0)]),
-           "_g1": g1, "_ef1": probe1["ef"]}
+def program_readings(optim, sync, opt_cfg: dict, compression: dict, raw: dict,
+                     sizes) -> dict:
+    """What the step left in its state, reduced to per-leaf norms, the sync
+    semantics' exact counts (``exact``) and the model's statistics (``aux1``).
+    ``raw`` is what ``drive_first_steps`` read: ``p0`` the parameters before
+    the first step, ``p3`` after the last, ``probe1`` what the builder's
+    ``probe`` read after the first (optimizer state, residual: [world, ...]
+    leaves or None).  ``p3`` and ``probe1`` are taken out of ``raw`` and each
+    list is let go once it is reduced, so that the reference starts with no
+    list of the program's beside ``p0``: a side is reduced once."""
+    p0, probe1 = raw["p0"], raw.pop("probe1")
+    out = {"loss": list(raw["loss"]), "aux1": probe1["aux"],
+           "dparam": diff_norms(raw.pop("p3"), p0)}
+    # in place: the optimizer state's list becomes the gradient's
+    g1 = optim.first_gradient(p0, probe1.pop("opt"), opt_cfg)
+    out["grad1"] = out["mean_grad1"] = leaf_norms(g1)
     for kind, tree in sync.program_trees(g1, probe1["ef"]).items():
         out[kind] = leaf_norms(tree)
+    out["exact"] = sync.exact_checks(g1, probe1["ef"], compression,
+                                     raw["counters"], sizes)
     return out
 
 

@@ -1,7 +1,8 @@
 """Device time of the attention kernels per step (device trace): the Pallas
-calls under the ``tcdp.attn`` scope (``flash_attn_fwd``, ``flash_attn_dq``,
-``flash_attn_dkv``; a forward run again under rematerialisation counts, it
-took the time).  A program without the scope or the kernels reads nothing."""
+calls under the ``tcdp.attn`` scope, whatever their names (today
+``flash_attn_fwd`` and the one ``flash_attn_bwd``; a forward run again under
+rematerialisation counts, it took the time).  A program without the scope or
+the kernels reads nothing."""
 
 UNIT = "ms"
 

@@ -119,13 +119,14 @@ def build(cfg: dict, traffic: dict, devices, model) -> Program:
                                 seed=seed, workers=f["workers"])
 
     def probe(state, params_only=False):
-        # copies: on a host backend device_get may alias a buffer the step donates
-        get = lambda tree: [np.array(l, copy=True)
+        # copies: on a host backend device_get may alias a buffer the step donates.
+        # In C order whatever layout the device's copy came in: the comparison
+        # works on these lists in place
+        get = lambda tree: [np.array(l, order="C")
                             for l in jax.device_get(jax.tree.leaves(tree))]
         if params_only:
             return {"params": get(state.params)}
-        return {"params": get(state.params),
-                "opt": get(state.opt_state["momentum"]),
+        return {"opt": get(state.opt_state["momentum"]),
                 "aux": get(state.batch_stats),
                 "ef": get(state.ef) if state.ef != () else None}
 

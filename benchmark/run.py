@@ -141,41 +141,48 @@ def drive_first_steps(prog, state, epoch, feed, mark=lambda name: None):
     return state, raw
 
 
-def both_sides(cell, raw, precision="float32"):
-    """(the program's readings, the reference's): the first steps of both.
-    ``precision`` other than float32 puts the reference computed in that lower
-    precision in the program's place: the control."""
+def follow(cell, raw, precision="float32"):
+    """The reference's first steps from the program's seeded weights, on the
+    program's first batches: norms, losses and the model's statistics."""
     import jax
 
-    import check
     from reference import steps
 
-    cfg, comp = cell.cfg, cell.traffic["compression"]
-    treedef = jax.tree.structure(cell.model.param_shapes(cfg),
+    treedef = jax.tree.structure(cell.model.param_shapes(cell.cfg),
                                  is_leaf=lambda s: isinstance(s, tuple))
-    follow = lambda prec: steps.train_steps(
-        cell.model, cell.optim, cell.sync, cfg, comp,
-        jax.tree.unflatten(treedef, raw["p0"]), raw["first"], cell.chips, prec)
-    refr = follow("float32")
-    if precision != "float32":
-        return follow(precision), refr
-    return check.program_readings(cell.optim, cell.sync, cfg["optimizer"], raw["p0"],
-                                  raw["probe1"], raw["p3"], raw["loss"]), refr
+    return steps.train_steps(
+        cell.model, cell.optim, cell.sync, cell.cfg, cell.traffic["compression"],
+        jax.tree.unflatten(treedef, raw["p0"]), raw["first"], cell.chips, precision)
 
 
-def judge(cell, raw, counts, precision="float32"):
-    """[(name, value, limit, ok)] for every number compared."""
+def both_sides(cell, raw, precision="float32"):
+    """(the program's readings, the reference's): the first steps of both.
+    The program's side is reduced first and what it was read from leaves
+    ``raw``, so the reference's lists come where the program's were and not
+    beside them.  ``precision`` other than float32 puts the reference computed
+    in that lower precision in the program's place: the control, followed
+    after the float32 reference, of which only the readings are left."""
     import check
     import flops
 
-    prog, refr = both_sides(cell, raw, precision)
+    if precision != "float32":
+        refr = follow(cell, raw)
+        return follow(cell, raw, precision), refr
+    prog = check.program_readings(
+        cell.optim, cell.sync, cell.cfg["optimizer"], cell.traffic["compression"],
+        raw, flops.leaf_sizes(cell.model, cell.cfg))
+    return prog, follow(cell, raw)
+
+
+def compared_numbers(cell, prog, refr, counts, precision="float32") -> dict:
+    """Every number that has a limit, from the two sides' readings."""
+    import check
+
     numbers = check.gap_numbers(prog, refr, cell.sync.KINDS)
     if precision == "float32":
         numbers.update(cell.model.model_numbers(
-            raw["probe1"]["aux"], refr["aux1"], cell.cfg, cell.check_params))
-        numbers.update(cell.sync.exact_checks(
-            prog["_g1"], prog["_ef1"], cell.traffic["compression"], raw["counters"],
-            flops.leaf_sizes(cell.model, cell.cfg)))
+            prog["aux1"], refr["aux1"], cell.cfg, cell.check_params))
+        numbers.update(prog["exact"])
         numbers.update(counts)
     else:
         # the control has no state or counters of its own; its auxiliary
@@ -184,7 +191,20 @@ def judge(cell, raw, counts, precision="float32"):
             cell.model.aux_as_probed(prog["aux1"], cell.cfg), refr["aux1"],
             cell.cfg, cell.check_params))
         numbers.update({k: 0 for k in cell.limits if k not in numbers})
-    return check.compare(numbers, cell.limits)
+    return numbers
+
+
+def judge(cell, raw, counts, precision="float32"):
+    """[(name, value, limit, ok)] for every number compared."""
+    import check
+
+    prog, refr = both_sides(cell, raw, precision)
+    return check.compare(compared_numbers(cell, prog, refr, counts, precision),
+                         cell.limits)
+
+
+def compare_line(name, value, limit, ok) -> str:
+    return f"compare {name} = {value:.6g} (limit {limit:g}) {'ok' if ok else 'FAIL'}"
 
 
 def spread_ms(seconds) -> list:
@@ -336,8 +356,8 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *,
     jax.clear_caches()      # the step's executable gives back what it reserved
     rows = judge(cell, raw, {"compiles_in_window": compiles_in_window,
                              "failed_steps": failed})
-    for name, value, limit, ok in rows:
-        print(f"compare {name} = {value:.6g} (limit {limit:g}) {'ok' if ok else 'FAIL'}")
+    for row in rows:
+        print(compare_line(*row))
     correct = all(ok for *_, ok in rows)
     check_s = time.perf_counter() - t_check
 
@@ -379,6 +399,9 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *,
         device["window_s"] = trace_reduce.window_seconds(extract)
         result["breakdown"] = {"device_ops": trace_reduce.top_device_ops(extract),
                                "idle_gaps": trace_reduce.idle_gaps(extract)}
+    # last in the line: every number compared beside its limit
+    result["compared"] = {name: [value if np.isfinite(value) else str(value), limit, ok]
+                          for name, value, limit, ok in rows}
     return result
 
 
@@ -393,6 +416,11 @@ def main(argv=None) -> int:
                       bool(args.trace))
     sys.stdout.flush()
     print(json.dumps(result), flush=True)
+    # and as the last lines of standard error, which a record of a run that
+    # was not correct keeps
+    for name, (value, limit, ok) in result["compared"].items():
+        print(compare_line(name, float(value), limit, ok), file=sys.stderr)
+    sys.stderr.flush()
     return 0
 
 

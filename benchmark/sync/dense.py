@@ -29,8 +29,12 @@ def init(leaves, world: int, compression: dict):
 
 
 def exchange(grads, state, compression: dict):
-    """``grads[w][i]``: worker w's gradient of leaf i.  -> (applied, state)."""
+    """``grads[w][i]``: worker w's gradient of leaf i.  -> (applied, state).
+    An exchange may release ``grads[w][i]`` once it is done with it (a list of
+    the model's size is host memory): the caller reads ``grads`` before."""
     world = len(grads)
+    if world == 1:      # the mean of one is itself: no second list
+        return grads[0], state
     return [sum(g[i] for g in grads) / np.float32(world)
             for i in range(len(grads[0]))], state
 

@@ -52,26 +52,32 @@ def init(leaves, world: int, compression: dict):
 
 
 def exchange(grads, state, compression: dict):
+    # leaf by leaf, each worker's gradient leaf released once it is split, so
+    # the applied list grows as the gradient lists go
     world, ratio = len(grads), compression["ratio"]
-    applied = [np.zeros_like(l) for l in grads[0]]
-    for w, g in enumerate(grads):
-        for i, gl in enumerate(g):
+    applied = []
+    for i in range(len(grads[0])):
+        acc = np.zeros_like(grads[0][i])
+        for w, g in enumerate(grads):
+            gl, g[i] = g[i], None
             sent, res = split((gl + state[w][i]).ravel(), ratio)
             state[w][i] = res.reshape(gl.shape)
-            applied[i] += sent.reshape(gl.shape) / np.float32(world)
+            acc += sent.reshape(gl.shape) / np.float32(world)
+        applied.append(acc)
     return applied, state
 
 
 def reference_trees(state) -> dict:
+    # generators: the norms are taken a leaf at a time, and no list is made
     world = len(state)
-    return {"resid1": [sum(state[w][i] for w in range(world)) / world
-                       for i in range(len(state[0]))]}
+    return {"resid1": (sum(state[w][i] for w in range(world)) / world
+                       for i in range(len(state[0])))}
 
 
 def program_trees(g1, ef1) -> dict:
     # what travelled plus what stayed behind is the mean local gradient
-    return {"mean_grad1": [g + e.mean(axis=0) for g, e in zip(g1, ef1)],
-            "resid1": [e.mean(axis=0) for e in ef1]}
+    return {"mean_grad1": (g + e.mean(axis=0) for g, e in zip(g1, ef1)),
+            "resid1": (e.mean(axis=0) for e in ef1)}
 
 
 def wire_bits(sizes, compression: dict) -> int:

@@ -1,12 +1,12 @@
 """Read, on the chip and at the cell's own size, the numbers that the limits
 of ``correct`` are set from: the program's gaps from the reference on many
-seeds, and the lower-precision control's gaps on a few.  One process, so the
-step and the reference compile once.  ``read_limits.py`` hands the comparison
-no counts and computes the float32 reference twice on a control seed; at half
-a billion parameters a reference pass is minutes of the chip, so this one
-follows each side once and puts the numbers together as ``run.judge`` does.
-It also says where the host's memory and the time go, phase by phase: the
-comparison keeps whole host copies of the model.
+seeds, and the lower-precision control's gaps on a few.  One reader for both
+builders (it took in ``read_limits.py``, which handed ``run.judge`` no counts
+and followed the float32 reference twice on a control seed).  One process, so
+the step and the reference compile once; each side is followed once and the
+numbers are put together as ``run.judge`` does.  It also says where the host's
+memory and the time go, phase by phase.  Training's readings need no measured
+window.
 
     python3 benchmark/tests/read_limits_lm.py --workload W --seeds 1,2,3 --control 1
 """
@@ -19,6 +19,8 @@ import os
 import resource
 import sys
 import time
+
+import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -36,9 +38,7 @@ def main() -> int:
 
     import jax
 
-    import check
     from feed import Feed
-    from reference import steps
     from tpu_compressed_dp.parallel.mesh import setup_compile_cache
 
     setup_compile_cache()
@@ -46,16 +46,17 @@ def main() -> int:
     devices = jax.devices()
     if devices[0].platform != "tpu" or len(devices) < cell.chips:
         raise SystemExit("limits are read on the chip")
-    cfg, comp = cell.cfg, cell.traffic["compression"]
-    prog = cell.builder.build(cfg, cell.traffic, devices[:cell.chips], cell.model)
-    treedef = jax.tree.structure(cell.model.param_shapes(cfg),
-                                 is_leaf=lambda s: isinstance(s, tuple))
+    prog = cell.builder.build(cell.cfg, cell.traffic, devices[:cell.chips], cell.model)
     t_last = [time.perf_counter()]
+    page = os.sysconf("SC_PAGE_SIZE")
 
     def phase(name, seed):
         now = time.perf_counter()
+        with open("/proc/self/statm") as f:
+            resident = int(f.read().split()[1]) * page
         print("PHASE " + json.dumps({
             "seed": seed, "after": name, "seconds": round(now - t_last[0], 1),
+            "host_resident_gb": round(resident / 1e9, 2),
             "host_peak_gb_so_far": round(resource.getrusage(
                 resource.RUSAGE_SELF).ru_maxrss * 1024 / 1e9, 2)}), flush=True)
         t_last[0] = now
@@ -75,29 +76,23 @@ def main() -> int:
         del state
         feed.release()
         phase("program's three steps and probes", seed)
-        follow = lambda prec: steps.train_steps(
-            cell.model, cell.optim, cell.sync, cfg, comp,
-            jax.tree.unflatten(treedef, raw["p0"]), raw["first"], cell.chips, prec)
-        refr = follow("float32")
-        phase("reference's three steps", seed)
-        sides = [("program", check.program_readings(
-            cell.optim, cell.sync, cfg["optimizer"], raw["p0"], raw["probe1"],
-            raw["p3"], raw["loss"]), raw["probe1"]["aux"])]
+        got, refr = run.both_sides(cell, raw)
+        phase("program's side reduced, reference's three steps", seed)
+        sides = [("program", got, "float32")]
         if seed in control:
-            got = follow(args.precision)
+            sides.append(("control", run.follow(cell, raw, args.precision),
+                          args.precision))
             phase("control's three steps", seed)
-            sides.append(("control", got, cell.model.aux_as_probed(got["aux1"], cfg)))
-        for who, got, aux in sides:
-            numbers = check.gap_numbers(got, refr, cell.sync.KINDS)
-            numbers.update(cell.model.model_numbers(aux, refr["aux1"], cfg,
-                                                    cell.check_params))
+        for who, got, precision in sides:
             line = {"workload": args.workload, "seed": seed, "who": who,
-                    "numbers": numbers, "aux": [list(map(float, a.ravel()))
-                                                for a in map(jax.numpy.asarray, aux)]}
-            for k in ("loss", "grad1", "dparam"):
+                    "numbers": run.compared_numbers(cell, got, refr, {}, precision)}
+            aux = [np.asarray(a, np.float64).ravel() for a in got["aux1"]]
+            if sum(a.size for a in aux) <= 64:      # the LM's per-pass numbers
+                line["aux"] = [list(map(float, a)) for a in aux]
+            for k in ("loss",) + tuple(cell.sync.KINDS):
                 line[k] = [list(map(float, got[k])), list(map(float, refr[k]))]
             print("READING " + json.dumps(line), flush=True)
-        del raw, refr, sides
+        del raw, refr, sides, got
         phase("numbers", seed)
     return 0
 
