@@ -170,6 +170,18 @@ def render_report(events: List[Dict[str, Any]]) -> str:
             f"{_fmt(r['mfu'], '8.4f')}{_fmt(r['tflops'], '10.3f')}"
             f"{_fmt(r['comm_mb_s'], '11.3f')}{_fmt(r['skipped'], '9.0f')}")
 
+    # the model's own step metrics (a looped model's passes, a hybrid one's
+    # second loss and routing), as the last window reported them
+    model = {}
+    for e in events:
+        if e.get("kind") in WINDOW_KINDS:
+            model = {k: v for k, v in (e.get("metrics") or {}).items()
+                     if k.startswith(("loss/", "model/"))} or model
+    if model:
+        lines.append("")
+        lines.append("model metrics (last window): " + ", ".join(
+            f"{k} {v:.4g}" for k, v in sorted(model.items())))
+
     guard = [e for e in events if e.get("kind") == "guard"]
     if guard:
         lines.append("")

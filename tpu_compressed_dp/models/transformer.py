@@ -32,6 +32,11 @@ Parallelism design (TPU-first, megatron-style over a named mesh):
 a test on the virtual CPU mesh, and a pod run share one implementation.
 Parameters are a plain nested dict with a parallel tree of
 ``PartitionSpec``s from :func:`param_specs`.
+
+A stack whose layers each hold one mixer alone (a Mamba-2 mixer, attention
+without position embedding, or a drop-less latent expert layer, by a per-layer
+pattern, with a multi-token-prediction module) is another module,
+:mod:`tpu_compressed_dp.models.hybrid`; it trains through the same LM step.
 """
 
 from __future__ import annotations
@@ -125,6 +130,35 @@ class LlamaConfig:
     def is_moe_layer(self, i: int) -> bool:
         return bool(self.n_experts) and (i % max(self.moe_every, 1) ==
                                          max(self.moe_every, 1) - 1)
+
+    # What the LM step (train/lm_step.py) asks of a model's settings; the
+    # hybrid decoder's (models/hybrid.py) answer the same four and bring
+    # their loss too, where this decoder's is the step module's ``llama_loss``.
+    def init(self, key: Array) -> Dict[str, Any]:
+        return init_llama(self, key)
+
+    def param_specs(self) -> Dict[str, Any]:
+        return param_specs(self)
+
+    def init_aux(self) -> Dict[str, Array]:
+        """The state's auxiliary slot: a config with an exit gate keeps its
+        last step's per-pass losses, mean exit masses and exit entropy
+        there; every other config keeps nothing."""
+        if not self.exit_gate:
+            return {}
+        return {"pass_loss": jnp.zeros((self.n_passes,), jnp.float32),
+                "exit_mass": jnp.zeros((self.n_passes,), jnp.float32),
+                "exit_entropy": jnp.zeros((), jnp.float32)}
+
+    def aux_metrics(self, aux: Dict[str, Array]) -> Dict[str, Array]:
+        """A looped model's per-pass numbers as step metrics."""
+        metrics = {}
+        for r in range(self.n_passes if aux else 0):
+            metrics[f"loss/pass{r + 1}"] = aux["pass_loss"][r]
+            metrics[f"model/exit_mass{r + 1}"] = aux["exit_mass"][r]
+        if aux:
+            metrics["model/exit_entropy"] = aux["exit_entropy"]
+        return metrics
 
 
 def llama3_8b() -> LlamaConfig:

@@ -144,6 +144,20 @@ for _r in range(1, 9):      # a looped LM's passes (models/transformer.py)
             f"mean over tokens of the probability of exiting after pass {_r}")
 declare("model/exit_entropy", GAUGE, "nats", "mean", "step",
         "mean over tokens of the exit distribution's entropy")
+# a hybrid decoder's second loss and its expert layers' routing
+# (models/hybrid.py)
+declare("loss/lm", GAUGE, "nats", "mean", "step",
+        "mean next-token cross-entropy of the trunk's logits")
+declare("loss/mtp", GAUGE, "nats", "mean", "step",
+        "mean cross-entropy of the multi-token-prediction module (the token "
+        "after next)")
+declare("model/expert_rows", GAUGE, "rows", "mean", "step",
+        "rows a held routed expert received, mean over experts and layers")
+declare("model/expert_rows_max", GAUGE, "rows", "mean", "step",
+        "rows the busiest held routed expert received")
+declare("model/route_mass", GAUGE, "weight", "mean", "step",
+        "a token's routed weights on held experts, summed; mean over tokens "
+        "and expert layers (every expert held: the routed scaling factor)")
 declare("guard/loss_scale", GAUGE, "scale", "mean", "step",
         "live dynamic loss scale (replicated)")
 declare("guard/skipped", COUNTER, "steps", "max", "step",

@@ -58,8 +58,15 @@ __all__ = ["PHASES", "LOOP_SPANS", "phase", "chunk", "host_span",
 #:             decoder's passes over its layers, the attention calls in
 #:             them, the head with its cross-entropies, and a looped
 #:             model's exit gate and loss weighting
+#:   ssm / ssd / moe / moe_dispatch / experts / mtp   a hybrid decoder's
+#:             (models/hybrid.py): a Mamba-2 mixer and, in it, its
+#:             convolution and scan; an expert layer (latent projections,
+#:             shared expert) and, in it, its router, choice, sort, gathers
+#:             and combine, and the grouped product of its experts; the
+#:             multi-token-prediction module
 PHASES = ("grad", "ef", "compress", "route", "reduce", "return", "update",
-          "ici_reduce", "recompress", "stack", "attn", "head_xent", "exit")
+          "ici_reduce", "recompress", "stack", "attn", "head_xent", "exit",
+          "ssm", "ssd", "moe", "moe_dispatch", "experts", "mtp")
 
 
 def phase(name: str):

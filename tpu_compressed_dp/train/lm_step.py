@@ -39,7 +39,6 @@ from tpu_compressed_dp.models.transformer import (
     exit_weighted_loss,
     fused_head_xent,
     fused_head_xent_tokens,
-    param_specs,
     use_fused_head_xent,
     vocab_parallel_xent,
     vocab_parallel_xent_tokens,
@@ -60,7 +59,7 @@ from tpu_compressed_dp.utils import chaos as chaos_mod
 Array = jax.Array
 
 __all__ = ["make_lm_train_step", "init_lm_ef_state", "init_lm_comp_state",
-           "init_lm_model_aux", "lm_state_specs", "make_lm_mesh"]
+           "init_lm_model_aux", "lm_state_specs", "make_lm_mesh", "llama_loss"]
 
 LM_AXES = ("data", "seq", "tensor")
 
@@ -72,19 +71,76 @@ def make_lm_mesh(data: int, seq: int = 1, tensor: int = 1,
     return make_mesh((data, seq, tensor), LM_AXES, devices=devices)
 
 
-def init_lm_model_aux(cfg: LlamaConfig) -> Dict[str, Array]:
+# The step takes a model through its settings object (``LlamaConfig``,
+# ``models.hybrid.HybridConfig``): ``validate_mesh(tensor_size)``,
+# ``init(key)``, ``param_specs()``, ``init_aux()``, ``aux_metrics(aux)`` and,
+# inside the step's shard_map, its loss (:func:`_model_loss`).
+ModelConfig = Any
+
+
+def init_lm_model_aux(cfg: ModelConfig) -> Dict[str, Array]:
     """The state's auxiliary slot (``TrainState.batch_stats``) for the LM
-    step: a config with an exit gate keeps its last step's per-pass losses,
-    mean exit masses and exit entropy there; every other config keeps
-    nothing."""
-    if not cfg.exit_gate:
-        return {}
-    return {"pass_loss": jnp.zeros((cfg.n_passes,), jnp.float32),
-            "exit_mass": jnp.zeros((cfg.n_passes,), jnp.float32),
-            "exit_entropy": jnp.zeros((), jnp.float32)}
+    step: what the model's last step leaves there (a looped model its
+    per-pass losses and exit masses, a hybrid one its two losses and its
+    expert layers' routing numbers; most models nothing)."""
+    return cfg.init_aux()
 
 
-def init_lm_ef_state(cfg: LlamaConfig, params: Any, comp: CompressionConfig,
+def llama_loss(cfg: LlamaConfig, params, x: Array, y: Array, tensor_size: int):
+    """Per-worker ``(loss to differentiate, cross-entropy, auxiliary
+    numbers)`` of inputs ``x`` and targets ``y`` [B, T], inside the LM step's
+    ``shard_map`` (axes ``seq`` and ``tensor``)."""
+    # per-worker logits buffer: local tokens x vocab shard (V/tp) at the
+    # config's logits width (bf16 OR fp32 — ADVICE r5); an exit gate makes
+    # one of every pass
+    fused = use_fused_head_xent(
+        (cfg.n_passes if cfg.exit_gate else 1) * x.shape[0] * x.shape[1],
+        cfg.vocab_size // tensor_size, jnp.dtype(cfg.dtype).itemsize)
+    model_aux = {}
+    if cfg.exit_gate:
+        # every pass's hidden states through the one head, each token's
+        # losses weighted by its exit distribution
+        out, gate, aux = apply_llama(
+            cfg, params, x, tensor_axis="tensor", seq_axis="seq",
+            with_aux=True, return_hidden=fused, all_passes=True)
+        ys = jnp.broadcast_to(y, (cfg.n_passes,) + y.shape)
+        with obs_trace.phase("head_xent"):
+            if fused:
+                nll = fused_head_xent_tokens(
+                    out, params["lm_head"].astype(cfg.dtype), ys, "tensor")
+            else:
+                nll = vocab_parallel_xent_tokens(out, ys, tensor_axis="tensor")
+        with obs_trace.phase("exit"):
+            xent, model_aux = exit_weighted_loss(nll, gate, cfg.exit_beta)
+    elif fused:
+        # head matmul + softmax-xent fused through a chunked running
+        # logsumexp: the [B,T,V] logits (and AD's saved softmax inputs) never
+        # materialise in HBM
+        h, aux = apply_llama(cfg, params, x, tensor_axis="tensor",
+                             seq_axis="seq", with_aux=True, return_hidden=True)
+        with obs_trace.phase("head_xent"):
+            xent = fused_head_xent(
+                h, params["lm_head"].astype(cfg.dtype), y, "tensor")
+    else:
+        logits, aux = apply_llama(cfg, params, x, tensor_axis="tensor",
+                                  seq_axis="seq", with_aux=True)
+        with obs_trace.phase("head_xent"):
+            xent = vocab_parallel_xent(logits, y, tensor_axis="tensor")
+    return xent + cfg.moe_aux_weight * aux, xent, model_aux
+
+
+def _model_loss(cfg: ModelConfig, params, x: Array, y: Array, mesh_shape):
+    """``(loss to differentiate, loss to report, auxiliary numbers)``.  A
+    model's settings bring their loss, ``loss(params, x, y, mesh_shape)``;
+    the plain decoder's is :func:`llama_loss` in this module, which
+    ``models/`` does not import (its tests and the benchmark's plant their
+    faults on this module's names)."""
+    if isinstance(cfg, LlamaConfig):
+        return llama_loss(cfg, params, x, y, mesh_shape["tensor"])
+    return cfg.loss(params, x, y, mesh_shape)
+
+
+def init_lm_ef_state(cfg: ModelConfig, params: Any, comp: CompressionConfig,
                      mesh: Mesh) -> Any:
     """EF residual with a leading (data*seq) worker axis; tensor-sharded dims
     follow the param's own sharding (each tensor shard keeps its own
@@ -104,13 +160,13 @@ def _ef_specs(pspecs: Any) -> Any:
     )
 
 
-def _lm_is_sharded(cfg: LlamaConfig):
+def _lm_is_sharded(cfg: ModelConfig):
     pspec_leaves = jax.tree.leaves(
-        param_specs(cfg), is_leaf=lambda x: isinstance(x, P))
+        cfg.param_specs(), is_leaf=lambda x: isinstance(x, P))
     return [any(ax == "tensor" for ax in spec) for spec in pspec_leaves]
 
 
-def init_lm_comp_state(cfg: LlamaConfig, params: Any, comp: CompressionConfig,
+def init_lm_comp_state(cfg: ModelConfig, params: Any, comp: CompressionConfig,
                        mesh: Mesh) -> Any:
     """Compressor state (PowerSGD warm-start Q) for the LM step, with the
     same signature grouping ``make_lm_train_step``'s grouped sync uses and a
@@ -135,9 +191,9 @@ def init_lm_comp_state(cfg: LlamaConfig, params: Any, comp: CompressionConfig,
         params, comp, _lm_is_sharded(cfg), "tensor", workers)
 
 
-def lm_state_specs(cfg: LlamaConfig, comp: CompressionConfig) -> TrainState:
+def lm_state_specs(cfg: ModelConfig, comp: CompressionConfig) -> TrainState:
     """PartitionSpec pytree for the LM TrainState (shard_map in/out specs)."""
-    pspecs = param_specs(cfg)
+    pspecs = cfg.param_specs()
     return TrainState(
         step=P(),
         params=pspecs,
@@ -157,7 +213,7 @@ def lm_state_specs(cfg: LlamaConfig, comp: CompressionConfig) -> TrainState:
     )
 
 
-def place_lm_state(state: TrainState, cfg: LlamaConfig, comp: CompressionConfig,
+def place_lm_state(state: TrainState, cfg: ModelConfig, comp: CompressionConfig,
                    mesh: Mesh) -> TrainState:
     """Shard a (restored) TrainState onto the 3-D mesh per lm_state_specs —
     the LM analog of ``TrainState.with_mesh_sharding`` (checkpoint restore
@@ -166,7 +222,7 @@ def place_lm_state(state: TrainState, cfg: LlamaConfig, comp: CompressionConfig,
 
 
 def make_lm_train_step(
-    cfg: LlamaConfig,
+    cfg: ModelConfig,
     optimizer: SGD,
     comp_cfg: CompressionConfig,
     mesh: Mesh,
@@ -240,53 +296,10 @@ def make_lm_train_step(
                     else jnp.asarray(1.0, jnp.float32))
 
         def loss_fn(params):
-            # per-worker logits buffer: local tokens x vocab shard (V/tp)
-            # at the config's logits width (bf16 OR fp32 — ADVICE r5); an
-            # exit gate makes one of every pass
-            fused = use_fused_head_xent(
-                (cfg.n_passes if cfg.exit_gate else 1) * x.shape[0] * x.shape[1],
-                cfg.vocab_size // mesh.shape["tensor"],
-                jnp.dtype(cfg.dtype).itemsize)
-            model_aux = {}
-            if cfg.exit_gate:
-                # every pass's hidden states through the one head, each
-                # token's losses weighted by its exit distribution
-                out, gate, aux = apply_llama(
-                    cfg, params, x, tensor_axis="tensor", seq_axis="seq",
-                    with_aux=True, return_hidden=fused, all_passes=True)
-                ys = jnp.broadcast_to(y, (cfg.n_passes,) + y.shape)
-                with obs_trace.phase("head_xent"):
-                    if fused:
-                        nll = fused_head_xent_tokens(
-                            out, params["lm_head"].astype(cfg.dtype), ys,
-                            "tensor")
-                    else:
-                        nll = vocab_parallel_xent_tokens(
-                            out, ys, tensor_axis="tensor")
-                with obs_trace.phase("exit"):
-                    xent, model_aux = exit_weighted_loss(nll, gate,
-                                                         cfg.exit_beta)
-            elif fused:
-                # head matmul + softmax-xent fused through a chunked running
-                # logsumexp: the [B,T,V] logits (and AD's saved softmax
-                # inputs) never materialise in HBM
-                h, aux = apply_llama(cfg, params, x, tensor_axis="tensor",
-                                     seq_axis="seq", with_aux=True,
-                                     return_hidden=True)
-                with obs_trace.phase("head_xent"):
-                    xent = fused_head_xent(
-                        h, params["lm_head"].astype(cfg.dtype), y, "tensor")
-            else:
-                logits, aux = apply_llama(cfg, params, x,
-                                          tensor_axis="tensor",
-                                          seq_axis="seq", with_aux=True)
-                with obs_trace.phase("head_xent"):
-                    xent = vocab_parallel_xent(logits, y,
-                                               tensor_axis="tensor")
+            total, xent, model_aux = _model_loss(cfg, params, x, y, mesh.shape)
             # backprop at loss_scale x (identity unguarded/fp32); the raw
-            # xent rides along for metrics/vote
-            return ((xent + cfg.moe_aux_weight * aux) * ls_scale,
-                    (xent, model_aux))
+            # loss rides along for metrics/vote
+            return total * ls_scale, (xent, model_aux)
 
         varying = jax.tree.map(
             lambda p: jax.lax.pcast(p, sync_axes, to="varying"), state.params
@@ -336,15 +349,12 @@ def make_lm_train_step(
             "tokens": jax.lax.psum(ntok, sync_axes),
             "lr": optimizer_lr(optimizer, sched_step),
         }
-        # a looped model's per-pass numbers: step metrics, and kept in the
-        # state's auxiliary slot (where a CNN keeps its batch statistics)
+        # the model's own numbers (a looped model's per-pass losses, a
+        # hybrid one's routing): step metrics, and kept in the state's
+        # auxiliary slot (where a CNN keeps its batch statistics)
         model_aux = jax.tree.map(lambda v: jax.lax.pmean(v, sync_axes),
                                  model_aux)
-        for r in range(cfg.n_passes if model_aux else 0):
-            metrics[f"loss/pass{r + 1}"] = model_aux["pass_loss"][r]
-            metrics[f"model/exit_mass{r + 1}"] = model_aux["exit_mass"][r]
-        if model_aux:
-            metrics["model/exit_entropy"] = model_aux["exit_entropy"]
+        metrics.update(cfg.aux_metrics(model_aux))
         if guarded:
             metrics.update(guard_mod.guard_metrics(new_guard))
         for k, v in comm.items():
@@ -400,7 +410,7 @@ def make_lm_eval_step(cfg: LlamaConfig, mesh: Mesh):
             ),
         }
 
-    pspecs = param_specs(cfg)
+    pspecs = cfg.param_specs()
     sharded = jax.shard_map(
         local_eval, mesh=mesh,
         in_specs=(pspecs, P("data", "seq"), P("data", "seq")),
