@@ -280,6 +280,12 @@ def test_expert_shares_add_up_to_the_uncut_layer():
 
 # --------------------------------------------------------------- drop-less
 
+def bias_to_held_expert_2():
+    """A balancing bias under which every token chooses expert 6 (held expert
+    2 of ``HELD``'s 4..7) and five experts no share of ``HELD`` holds."""
+    return jnp.full((16,), -10.0).at[jnp.asarray([6, 0, 1, 2, 3, 12])].set(10.0)
+
+
 def test_every_token_sent_to_one_held_expert_is_computed():
     """A planted balancing bias sends every token to held expert 2 (and to
     five absent ones): its 32 rows fill four tiles, none is dropped, and the
@@ -287,8 +293,7 @@ def test_every_token_sent_to_one_held_expert_is_computed():
     cfg = HELD
     hc = settings(cfg)
     p, h = layer_params("E", cfg), hidden(cfg)
-    bias = jnp.full((16,), -10.0).at[jnp.asarray([6, 0, 1, 2, 3, 12])].set(10.0)
-    p["e_bias"] = bias
+    p["e_bias"] = bias_to_held_expert_2()
     x = hy._rms_norm(h, p["norm"], cfg["norm_eps"]).reshape(-1, cfg["hidden_size"])
     idx, w = hy.route(hc, p, x)
     _, _, counts = hy.dispatch(hc, idx, w)
@@ -315,6 +320,109 @@ def test_the_routed_weights_are_normalised_over_all_the_chosen_and_scaled():
     held = (np.asarray(idx) >= 4) & (np.asarray(idx) < 8)
     assert int(jnp.sum(counts)) == int(held.sum())
     np.testing.assert_allclose(jnp.sum(wts), np.asarray(w)[held].sum(), rtol=1e-5)
+
+
+# ------------------------------------- the routing kept across the checkpoint
+
+STACK = "EME"                       # two expert layers around another mixer
+
+
+def expert_stack(case):
+    """The settings, layers and input of a small stack whose expert layers
+    route by ``case``: ``seeded`` by a small seeded balancing bias,
+    ``one_takes_all`` by a planted one that sends every token to held expert
+    2 (48 rows: six tiles) and leaves the three others with no row."""
+    hc = settings(HELD)
+    layers = [layer_params(k, HELD, seed=7 + i) for i, k in enumerate(STACK)]
+    for lp in layers:
+        if "e_bias" in lp:
+            lp["e_bias"] = (lp["e_bias"] * 0.1 if case == "seeded"
+                            else bias_to_held_expert_2())
+    h = jax.random.normal(jax.random.key(9), (3, HELD["seq_len"],
+                                              HELD["hidden_size"]))
+    return hc, layers, h
+
+
+def stack_grad(run):
+    """The jitted gradient, by the layers' parameters and the input, of a
+    scalar of ``run(layers, h)``'s hidden states."""
+    return jax.jit(jax.grad(lambda *a: jnp.sum(jnp.sin(run(*a))), argnums=(0, 1)))
+
+
+def checkpointed(hc):
+    return lambda layers, h: hy._run_layers(hc, STACK, layers, h)[0]
+
+
+@pytest.mark.parametrize("case", ["seeded", "one_takes_all"])
+def test_the_checkpointed_stack_has_the_plain_stacks_gradients(case):
+    """Through ``_run_layers`` (every layer under ``jax.checkpoint``, the
+    routing and the routed sum kept) as through the same layers one after
+    the other with no checkpoint: every parameter's gradient and the
+    input's, to float32 rounding (the two programs order their sums apart)."""
+    hc, layers, h = expert_stack(case)
+    rows = [st["rows"] for st in hy._run_layers(hc, STACK, layers, h)[1]]
+    if case == "one_takes_all":
+        np.testing.assert_array_equal(rows, [[0, 0, 48, 0]] * 2)
+    else:
+        assert all(float(jnp.max(r)) > hy.EXPERT_TILE for r in rows)
+
+    def plain(layers, h):
+        for kind, lp in zip(STACK, layers):
+            h, _ = hy._layer(hc, kind, lp, h)
+        return h
+
+    got, want = (stack_grad(f)(layers, h) for f in (checkpointed(hc), plain))
+    flat, _ = jax.tree_util.tree_flatten_with_path(got)
+    for (path, g), w in zip(flat, jax.tree.leaves(want)):
+        assert rel(g, w) < 1e-5 or float(jnp.max(jnp.abs(w))) == 0.0, (
+            jax.tree_util.keystr(path), rel(g, w))
+
+
+def test_the_chosen_logits_sigmoid_is_the_chosen_scores_bit_for_bit():
+    """``route`` takes the sigmoid of the gathered logits; gathering from the
+    sigmoid over all the experts (the form that made the backward ask for
+    all of it) gives the same ids and the same weights, bit for bit."""
+    hc = settings(HELD)
+    p = layer_params("E", HELD)
+    assert float(jnp.min(jnp.abs(p["e_bias"]))) > 0.0
+    x = jax.random.normal(jax.random.key(2), (48, HELD["hidden_size"])) * 3.0
+
+    def gathered_scores(lp, x):
+        s = jax.nn.sigmoid(jnp.dot(x, lp["router"],
+                                   precision=jax.lax.Precision.HIGHEST))
+        _, idx = jax.lax.top_k(s + lp["e_bias"], hc.top_k)
+        chosen = jnp.take_along_axis(s, idx, axis=-1)
+        return idx, hc.routed_scale * chosen / (
+            jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
+
+    for run in (lambda f: f, jax.jit):
+        idx, w = run(lambda lp, x: hy.route(hc, lp, x))(p, x)
+        widx, ww = run(gathered_scores)(p, x)
+        np.testing.assert_array_equal(idx, widx)
+        np.testing.assert_array_equal(w, ww)
+    # the bias took part in the choice: without it other experts are chosen
+    assert not np.array_equal(idx, hy.route(hc, dict(p, e_bias=0 * p["e_bias"]), x)[0])
+
+
+@pytest.mark.parametrize("kept, runs", [(hy._KEPT, 1), (("routed",), 2)],
+                         ids=["routing_kept", "routed_sum_alone"])
+def test_the_backward_runs_no_second_router_top_k_or_sort(monkeypatch, kept, runs):
+    """Counted in the compiled gradient of the small stack: an expert layer's
+    router product, ``top_k`` and sort run once, the checkpoint's second run
+    reading what the first kept.  With the routed sum alone kept (the names
+    of the routing taken off the policy) each runs twice: the count sees it."""
+    monkeypatch.setattr(hy, "_KEPT", kept)
+    hc, layers, h = expert_stack("seeded")
+    text = stack_grad(checkpointed(hc)).lower(layers, h).compile().as_text()
+    scoped = [l for l in text.splitlines() if "tcdp.moe_dispatch" in l]
+    tokens, experts = h.shape[0] * h.shape[1], HELD["published"]["n_routed_experts"]
+    n = STACK.count("E") * runs
+    assert sum('custom_call_target="TopK"' in l for l in scoped) == n
+    assert sum(" sort(" in l for l in scoped) == n
+    # the forward product makes [tokens, experts]; the backward's two (for
+    # the input and for the router's weight) make other shapes
+    assert sum(f" f32[{tokens},{experts}]" in l.split(" dot(")[0]
+               for l in scoped if " dot(" in l) == n
 
 
 # --------------------------------------------------------------------- MTP

@@ -311,9 +311,15 @@ def route(cfg: HybridConfig, lp, x: Array) -> Tuple[Array, Array]:
     routed ones, their weights [N, top_k] float32): sigmoid scores in
     float32, the choice by score plus the balancing bias (a buffer: no
     gradient), the weights the chosen scores normalised and scaled."""
-    s = jax.nn.sigmoid(jnp.dot(x.astype(_F32), lp["router"], precision=_HIGHEST))
-    _, idx = jax.lax.top_k(s + jax.lax.stop_gradient(lp["e_bias"]), cfg.top_k)
-    chosen = jnp.take_along_axis(s, idx, axis=-1)
+    logits = jnp.dot(x.astype(_F32), lp["router"], precision=_HIGHEST)
+    _, idx = jax.lax.top_k(
+        jax.nn.sigmoid(logits) + jax.lax.stop_gradient(lp["e_bias"]), cfg.top_k)
+    # the chosen scores from the chosen LOGITS (the same function of the same
+    # numbers as a gather from the scores): the backward then reads the ids
+    # and top_k logits a token, and nothing over all the experts
+    idx = checkpoint_name(idx, "route_ids")
+    chosen = jax.nn.sigmoid(checkpoint_name(
+        jnp.take_along_axis(logits, idx, axis=-1), "route_logits"))
     return idx, cfg.routed_scale * chosen / (
         jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
 
@@ -327,7 +333,8 @@ def dispatch(cfg: HybridConfig, idx: Array, w: Array):
     hit = jnp.any(held, axis=1)                                   # [N, E_held]
     wts = jnp.sum(jnp.where(held, w[:, :, None], 0.0), axis=1)
     order = jnp.argsort(~hit.T.reshape(-1), stable=True).astype(jnp.int32)
-    return wts, order, jnp.sum(hit, axis=0, dtype=jnp.int32)
+    return (wts, checkpoint_name(order, "route_order"),
+            checkpoint_name(jnp.sum(hit, axis=0, dtype=jnp.int32), "route_counts"))
 
 
 # Rows a step of the grouped product takes.  At uniform routing an expert's
@@ -486,13 +493,19 @@ def _layer(cfg: HybridConfig, kind: str, lp, h: Array):
         return h + out, stats
 
 
+#: what an expert layer keeps across its checkpoint, by `checkpoint_name`
+_KEPT = ("routed", "route_ids", "route_logits", "route_order", "route_counts")
+
+
 def _run_layers(cfg: HybridConfig, pattern: str, layers, h: Array):
     # every layer rematerialised in the backward: none of a layer's [L, L]
     # blocks or projections outlives its own backward; of an expert layer
-    # the routed sum alone is kept (33 MB at 8,192 tokens)
+    # the routed sum is kept (33 MB at 8,192 tokens) and the routing that
+    # made it (ids, chosen logits, order, counts: 1.7 MB), so the second run
+    # has no router product, no top_k and no sort
     layer = jax.checkpoint(
         _layer, static_argnums=(0, 1),
-        policy=jax.checkpoint_policies.save_only_these_names("routed"))
+        policy=jax.checkpoint_policies.save_only_these_names(*_KEPT))
     stats = []
     for kind, lp in zip(pattern, layers):
         h, st = layer(cfg, kind, lp, h)
