@@ -438,6 +438,324 @@ class TestStepTimeline:
         assert "flushed False" in out.stdout
 
 
+def _gc_pass(ring, clk, generation, start_ms, ms):
+    """One scripted collector pass, through the callback gc would call."""
+    clk.t = int(start_ms * MS)
+    ring.on_gc("start", {"generation": generation})
+    clk.t = int((start_ms + ms) * MS)
+    ring.on_gc("stop", {"generation": generation})
+
+
+@pytest.mark.quick
+class TestHostEvents:
+    """The ring of host events under a scripted clock, its listeners in a
+    live process, and what the timeline's views make of them."""
+
+    def test_ring_is_bounded_and_totals_survive_roll_off(self):
+        clk = FakeClock()
+        ring = obs_trace.HostEvents(capacity=4, clock=clk)
+        for i in range(10):
+            ring.add("compile", f"jit(f{i})", i * 10 * MS, (i * 10 + 5) * MS)
+        held = ring.events()
+        assert len(held) == 4 and [e[1] for e in held] == [
+            "jit(f6)", "jit(f7)", "jit(f8)", "jit(f9)"]
+        assert ring.totals() == {"compile": (10, 50 * MS)}
+        # a view between two times: what overlaps it, ends included
+        assert [e[1] for e in ring.events(75 * MS, 80 * MS)] == [
+            "jit(f7)", "jit(f8)"]
+        with pytest.raises(ValueError, match="capacity"):
+            obs_trace.HostEvents(capacity=0)
+
+    @pytest.mark.parametrize("events,covered_ms", [
+        # a jitted function traced inside another's trace ends first
+        ([(20, 40), (0, 100)], 100),
+        # ... and an outer one swallows two that were apart
+        ([(10, 20), (50, 60), (0, 100)], 100),
+        # apart: the sum; touching or overlapping: the union
+        ([(0, 10), (30, 40)], 20),
+        ([(0, 10), (5, 25), (25, 30)], 30),
+        # out of order (two threads): still the union
+        ([(50, 60), (0, 55)], 60),
+    ])
+    def test_a_kind_total_is_the_union_of_its_intervals(self, events,
+                                                        covered_ms):
+        ring = obs_trace.HostEvents(clock=FakeClock())
+        for start, end in events:
+            ring.add("trace", "f", start * MS, end * MS)
+        assert ring.totals()["trace"] == (len(events), covered_ms * MS)
+        assert len(ring.events()) == len(events)     # the ring keeps each
+
+    def test_duration_listener_stamps_back_from_the_end(self):
+        """A duration listener learns of an event when it ends."""
+        from jax._src import dispatch
+
+        clk = FakeClock()
+        ring = obs_trace.HostEvents(clock=clk)
+        clk.t = 500 * MS
+        ring.on_duration(dispatch.JAXPR_TRACE_EVENT, 0.2, fun_name="step")
+        ring.on_duration(dispatch.JAXPR_TO_MLIR_MODULE_EVENT, 0.05,
+                         fun_name="jit(step)")
+        ring.on_duration(dispatch.BACKEND_COMPILE_EVENT, 0.1,
+                         fun_name="jit(step)")
+        ring.on_duration("/jax/compilation_cache/cache_retrieval_time_sec", 0.03)
+        ring.on_duration("/jax/compilation_cache/compile_time_saved_sec", 9.0)
+        ring.on_event("/jax/compilation_cache/cache_hits")
+        ring.on_event("/jax/compilation_cache/cache_misses")
+        ring.on_event("/jax/compilation_cache/cache_misses")
+        ring.on_event("/jax/compilation_cache/tasks_using_cache")
+        assert ring.events() == [
+            ("trace", "step", 300 * MS, 500 * MS),
+            ("lower", "jit(step)", 450 * MS, 500 * MS),
+            ("compile", "jit(step)", 400 * MS, 500 * MS),
+            ("cache_read", "", 470 * MS, 500 * MS)]
+        assert ring.totals() == {
+            "trace": (1, 200 * MS), "lower": (1, 50 * MS),
+            "compile": (1, 100 * MS), "cache_read": (1, 30 * MS),
+            "cache_hits": (1, 0), "cache_misses": (2, 0)}
+        assert set(ring.totals()) - {"cache_hits", "cache_misses"} \
+            <= set(obs_trace.HOST_EVENT_KINDS)
+
+    def test_short_collector_pass_is_counted_and_not_ringed(self):
+        clk = FakeClock()
+        ring = obs_trace.HostEvents(clock=clk)
+        _gc_pass(ring, clk, 0, 10, 0.4)
+        _gc_pass(ring, clk, 0, 20, 0.2)
+        _gc_pass(ring, clk, 1, 30, 3)
+        _gc_pass(ring, clk, 2, 40, 118)
+        ring.on_gc("stop", {"generation": 2})    # installed mid-pass: no start
+        assert ring.events() == [("gc", "gen1", 30 * MS, 33 * MS),
+                                 ("gc", "gen2", 40 * MS, 158 * MS)]
+        assert obs_trace.GC_RING_MIN_NS == MS
+        assert ring.totals() == {
+            "gc.gen0": (2, 600_000), "gc.gen1": (1, 3 * MS),
+            "gc.gen2": (1, 118 * MS), "gc": (4, 121 * MS + 600_000)}
+
+    def test_threads_stamp_and_read_with_no_lost_update(self):
+        """Sixteen threads stamp events while the collector's callback
+        (lock-free: it can fire inside ``add``) stamps passes and a reader
+        copies the ring: every event is counted once, every nanosecond
+        once, and no reader sees a ring changing under it."""
+        import gc
+
+        ring = obs_trace.HostEvents(capacity=64)
+        passes = []
+        count_pass = lambda phase, info: phase == "stop" and passes.append(1)
+        threads, each = 16, 400
+        errors = []
+
+        def stamp(k):
+            try:
+                for i in range(each):
+                    at = (k * each + i) * 10 * MS
+                    ring.add("trace", f"f{k}", at, at + 4 * MS)
+                    junk = [[j] for j in range(50)]     # feeds the collector
+                    ring.on_event("/jax/compilation_cache/cache_hits")
+            except Exception as exc:                    # noqa: BLE001
+                errors.append(exc)
+
+        interval = sys.getswitchinterval()
+        gc.callbacks.extend([ring.on_gc, count_pass])
+        sys.setswitchinterval(1e-6)
+        try:
+            workers = [threading.Thread(target=stamp, args=(k,))
+                       for k in range(threads)]
+            for w in workers:
+                w.start()
+            deadline = time.monotonic() + 60
+            while any(w.is_alive() for w in workers):
+                assert time.monotonic() < deadline
+                assert len(ring.events()) <= 64
+                ring.totals()
+            for w in workers:
+                w.join(10)
+                assert not w.is_alive()
+        finally:
+            sys.setswitchinterval(interval)
+            gc.callbacks.remove(ring.on_gc)
+            gc.callbacks.remove(count_pass)
+        assert errors == []
+        totals = ring.totals()
+        assert totals["trace"] == (threads * each, threads * each * 4 * MS)
+        assert totals["cache_hits"] == (threads * each, 0)
+        assert passes and totals["gc"][0] == len(passes)
+
+    def test_install_twice_installs_one_listener_of_each_kind(self):
+        import gc
+
+        from jax._src import monitoring
+
+        ring = obs_trace.install_host_events()
+        assert obs_trace.install_host_events() is ring
+        mine = lambda fns: [f for f in fns
+                            if getattr(f, "__self__", None) is ring]
+        assert len(mine(monitoring.get_event_duration_listeners())) == 1
+        assert len(mine(monitoring.get_event_listeners())) == 1
+        assert len(mine(gc.callbacks)) == 1
+
+    def test_setup_compile_cache_installs_them(self, monkeypatch):
+        """Every entry point calls it first: no entry point is edited."""
+        import jax
+
+        from tpu_compressed_dp.parallel import mesh as mesh_mod
+
+        monkeypatch.setattr(obs_trace, "_HOST_EVENTS_INSTALLED", True)
+        called = []
+        monkeypatch.setattr(mesh_mod, "install_host_events",
+                            lambda: called.append(1))
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/else")
+        assert mesh_mod.setup_compile_cache() == "/somewhere/else"
+        assert called == [1]
+
+    def test_a_jitted_function_leaves_one_event_of_each_kind(self):
+        """A real ``jax.jit``: the first call traces, lowers and compiles
+        under the function's name, the second does none of the three."""
+        import jax
+
+        ring = obs_trace.install_host_events()
+
+        @jax.jit
+        def tcdp_fresh_function_39(x):
+            return x * 2 + 1
+
+        t0 = time.time_ns()
+        tcdp_fresh_function_39(np.ones(3, np.float32)).block_until_ready()
+        t1 = time.time_ns()
+        mine = [e for e in ring.events(t0, t1)
+                if "tcdp_fresh_function_39" in e[1]]
+        assert sorted(e[0] for e in mine) == ["compile", "lower", "trace"]
+        assert {e[0]: e[1] for e in mine}["trace"] == "tcdp_fresh_function_39"
+        assert all(t0 <= e[2] <= e[3] <= t1 for e in mine)
+        tcdp_fresh_function_39(np.ones(3, np.float32)).block_until_ready()
+        again = [e for e in ring.events(t1, None)
+                 if "tcdp_fresh_function_39" in e[1] and e[2] > t1]
+        assert again == []
+
+    def test_collect_inside_an_open_call_lands_in_its_events(self):
+        """The process-wide ring under the real clock: a ``gc.collect()``
+        while a call is open is the call's, the step's and the snapshot's."""
+        import gc
+
+        obs_trace.install_host_events()
+        tl = StepTimeline(capacity=8)
+        junk = [[i] for i in range(200_000)]      # a pass worth a millisecond
+        tl.begin_call()
+        with tl.span("dispatch"):
+            gc.collect()
+        tl.step_done({"loss": 1.0})
+        with tl.span("dispatch"):
+            pass
+        tl.step_done({"loss": 1.0})
+        tl.end_call()
+        del junk
+        call = tl.calls()[-1]
+        passes = [e for e in call["events"] if e[:2] == ("gc", "gen2")]
+        assert passes and all(
+            call["t0"] <= e[2] <= e[3] <= call["t1"] for e in passes)
+        gc0, gc1 = call["totals0"].get("gc", (0, 0)), call["totals1"]["gc"]
+        assert gc1[0] > gc0[0] and gc1[1] - gc0[1] >= passes[0][3] - passes[0][2]
+        snap = tl.snapshot()
+        longest = max(e[3] - e[2] for e in passes) / 1e6
+        assert snap["host/gc_ms_max"] == pytest.approx(longest)
+        assert 0 < snap["host/gc_frac"] <= 1
+        assert tl.host_events(call["t0"], call["t1"]) == call["events"]
+        first, second = tl.drain()
+        assert ["gc", "gen2"] in [e[:2] for e in first["events"]]
+
+    def test_step_exports_events_only_where_one_overlapped(self):
+        clk = FakeClock()
+        ring = obs_trace.HostEvents(clock=clk)
+        tl = StepTimeline(capacity=8, clock=clk, events=ring)
+        tl.begin_call()
+        _step(tl, clk, 1, 1, 2, 0.0)               # 0-4 ms
+        _step(tl, clk, 1, 1, 129, 0.0)             # 4-135 ms: the pause
+        _step(tl, clk, 1, 1, 2, 0.0)               # 135-139 ms
+        now = clk.t
+        _gc_pass(ring, clk, 2, 10, 118)
+        ring.add("compile", "jit(step)", 3 * MS, 5 * MS)   # over two steps
+        clk.t = now
+        assert tl.flush(10)
+        tl.end_call()
+        one, two, three = tl.drain()
+        assert one["events"] == [["compile", "jit(step)", 2.0, 3.0]]
+        assert two["events"] == [["gc", "gen2", 118.0, 6.0],
+                                 ["compile", "jit(step)", 2.0, -1.0]]
+        assert "events" not in three
+        assert set(three) == {"t0", "data", "to_device", "dispatch", "total",
+                              "done", "device", "starved", "starved_in",
+                              "ord", "call"}
+        call = tl.calls()[0]
+        assert call["events"] == ring.events() and call["totals0"] == {}
+        assert call["totals1"]["gc.gen2"] == (1, 118 * MS)
+
+    def test_snapshot_counts_compiles_inside_the_windows_calls(self):
+        clk = FakeClock()
+        ring = obs_trace.HostEvents(clock=clk)
+        tl = StepTimeline(capacity=8, clock=clk, events=ring)
+        ring.add("compile", "jit(init)", 0, 50 * MS)         # before any call
+        clk.t = 100 * MS
+        tl.begin_call()                                      # 100-400 ms
+        _step(tl, clk, 0, 0, 300, 0.0)
+        ring.add("trace", "step", 100 * MS, 200 * MS)
+        ring.add("trace", "inner", 120 * MS, 140 * MS)
+        ring.add("lower", "jit(step)", 190 * MS, 250 * MS)
+        ring.add("compile", "jit(step)", 250 * MS, 300 * MS)
+        _gc_pass(ring, clk, 2, 340, 30)
+        _gc_pass(ring, clk, 1, 380, 3)
+        clk.t = 400 * MS
+        assert tl.flush(10)
+        tl.end_call()
+        ring.add("compile", "jit(eval)", 450 * MS, 480 * MS)  # between calls
+        snap = tl.snapshot()
+        assert snap["host/compiles"] == 1.0
+        assert snap["host/compile_s"] == pytest.approx(0.200)  # the union
+        assert snap["host/gc_ms_max"] == pytest.approx(30.0)
+        assert snap["host/gc_frac"] == pytest.approx(33 / 300)
+        assert obs_registry.undeclared(snap) == []
+
+    @pytest.mark.parametrize("key", ["host/compiles", "host/compile_s",
+                                     "host/gc_ms_max", "host/gc_frac"])
+    def test_host_keys_are_declared_and_exported(self, key, tmp_path):
+        assert obs_registry.is_declared(key)
+        assert obs_registry.spec(key).emitter == "host"
+        snap = StepTimeline(clock=FakeClock()).snapshot()
+        assert snap[key] == 0.0                   # an empty window reads 0
+        body = obs_export.write_prometheus(snap, str(tmp_path / "m.prom"))
+        assert f"# HELP {obs_registry.prometheus_name(key)} " in body
+
+    def test_process_exits_clean_after_run_train_epoch(self):
+        """The watcher is ended and joined from an exit hook: a process
+        that ran one ``run_train_epoch`` leaves nothing on standard error
+        (on the TPU's host the thread was unwound inside native code and
+        the run's last 2,000 characters were a SIGABRT trace)."""
+        code = (
+            "import threading, numpy as np\n"
+            "from tpu_compressed_dp.harness.loop import run_train_epoch\n"
+            "from tpu_compressed_dp.obs import trace\n"
+            "step = lambda s, b: (s + 1, {'loss': 1.0, 'count': 2.0})\n"
+            "s, acc = run_train_epoch(step, 0, iter([{'input': np.zeros(2)}] * 3))\n"
+            "w = trace.process_timeline()._watcher\n"
+            "import atexit\n"
+            "atexit.register(lambda: print('watcher alive at exit:', w.is_alive()))\n"
+            "print('steps', acc.steps, w.name, w.is_alive())\n")
+        out = subprocess.run([sys.executable, "-c", code], timeout=120,
+                             capture_output=True, text=True,
+                             env={**os.environ, "JAX_PLATFORMS": "cpu"})
+        assert out.returncode == 0 and out.stderr == "", out.stderr
+        assert "steps 3 tcdp-step-stamps True" in out.stdout
+
+    def test_watcher_is_joined_when_its_timeline_goes(self):
+        import gc
+
+        tl = StepTimeline(capacity=4, clock=FakeClock())
+        _step(tl, tl._clock, 1, 0, 1, 0.0)
+        assert tl.flush(10)
+        watcher = tl._watcher
+        assert watcher.is_alive()
+        del tl
+        gc.collect()
+        assert not watcher.is_alive()
+
+
 @pytest.mark.quick
 class TestTimerRegression:
     def test_constant_memory_and_split_semantics(self, monkeypatch):
@@ -948,6 +1266,40 @@ class TestTraceReport:
         out = str(tmp_path / "chrome.json")
         assert tr.main([self._events(tmp_path), "--chrome", out]) == 0
         assert json.load(open(out))["traceEvents"]
+
+    def test_long_steps_name_their_cause(self, tmp_path):
+        """Every host interval over twice the median is printed with the
+        host events that overlapped it, or "nothing recorded"; the events
+        get a lane of their own in the chrome export."""
+        import tools.trace_report as tr
+
+        p = str(tmp_path / "ev.jsonl")
+        spans = [{"ord": 400 + i, "call": 3, "t0": 10.0 + 0.1 * i, "data": 0.0,
+                  "to_device": 0.0, "dispatch": 0.1, "total": 0.1, "done": None,
+                  "device": None, "starved": None, "starved_in": None}
+                 for i in range(20)]
+        spans[12].update(total=0.231, dispatch=0.231, events=[
+            ["compile", "jit(step)", 2.0, 1.0], ["gc", "gen2", 118.0, 6.0]])
+        spans[13].update(events=[["gc", "gen2", 118.0, -94.0]])    # its tail
+        spans[17].update(total=0.35, dispatch=0.35)
+        with obs_export.EventStream(p) as es:
+            es.emit("epoch", epoch=1, step=20, metrics={}, throughput={},
+                    guard={}, timeline={}, step_spans=spans)
+        events = obs_export.read_events(p)
+        # an added optional key: the stream's version stays
+        assert all(e["v"] == obs_export.SCHEMA_VERSION == 1 for e in events)
+        report = tr.render_report(events)
+        assert "step 412: 231 ms, gc gen2 118 ms, compile jit(step) 2 ms" \
+            in report
+        assert "step 417: 350 ms, nothing recorded" in report
+        assert "step 413" not in report
+        lane = [e for e in tr.chrome_trace_events(events) if e["tid"] == 2]
+        # the pass that overlapped two steps is drawn once, where it began
+        assert sorted(e["name"] for e in lane) == ["compile jit(step)",
+                                                   "gc gen2"]
+        gc_ev = next(e for e in lane if e["name"] == "gc gen2")
+        assert gc_ev["ts"] == pytest.approx(1.206e6) and gc_ev["dur"] == 118e3
+        assert all(e["cat"] == "host_event" and e["ph"] == "X" for e in lane)
 
     def test_schema_guard(self, tmp_path):
         import tools.trace_report as tr
@@ -1528,6 +1880,39 @@ class TestPostmortemClassify:
         assert "cross-rank timeline" in report
         assert pm.verdict_line(pm.classify({0: b0, 1: b1})) \
             == report.splitlines()[0]
+
+    def test_report_names_what_covered_a_long_step(self):
+        """Drained step records reach the recorder's ``timing`` ring with
+        their ``events`` (absolute times stay out), the bundle's schema
+        version stays, and the report prints each long step's cause."""
+        from tools import postmortem as pm
+        from tpu_compressed_dp.obs.flight import (FLIGHT_SCHEMA,
+                                                  FlightRecorder,
+                                                  validate_bundle)
+
+        clk = FakeClock()
+        ring = obs_trace.HostEvents(clock=clk)
+        tl = StepTimeline(capacity=16, clock=clk, events=ring)
+        tl.begin_call()
+        for i in range(8):
+            _step(tl, clk, 1, 1, 131 if i == 5 else 8, 0.0)
+        now = clk.t
+        _gc_pass(ring, clk, 2, 55, 118)             # inside step 5's dispatch
+        clk.t = now
+        assert tl.flush(10)
+        fl = FlightRecorder(rank=0, capacity=16)
+        fl.note_spans(tl.drain())
+        timing = fl.snapshot()["rings"]["timing"]
+        assert [("events" in r) for r in timing] == [i == 5 for i in range(8)]
+        assert timing[5]["events"] == [["gc", "gen2", 118.0, 5.0]]
+        assert not {"t0", "done", "ord", "call"} & set(timing[5])
+        bundle = self._bundle(0, "error", rings={"timing": timing})
+        assert bundle["v"] == FLIGHT_SCHEMA == 1 and validate_bundle(bundle) == []
+        report = pm.render_report({0: bundle})
+        assert "rank 0: host intervals over twice" in report
+        assert "step 5: 133 ms, gc gen2 118 ms" in report
+        # the profile sums numbers only: the list does not break it
+        assert fl.phase_profile()["phases"]["total"] == pytest.approx(.203)
 
     def test_cli_json_and_missing_dir(self, tmp_path, capsys):
         from tools import postmortem as pm

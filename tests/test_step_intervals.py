@@ -43,3 +43,36 @@ def test_summary_tells_a_stall_from_a_slower_program(ms, long):
     assert s["long"] == long and s["steps"] == len(ms) + 1
     assert s["p50_ms"] == ms[0] and s["max_ms"] == max(ms)
     assert s["p50_ms"] <= s["p95_ms"] <= s["p99_ms"] <= s["max_ms"]
+
+
+def test_host_events_line_splits_set_up_and_names_a_long_interval():
+    """Set-up is what ended before the window's call began; a long interval
+    carries the events over it, an empty list where nothing was recorded."""
+    t0 = 1000 * MS
+    before = [("trace", "inner", 20 * MS, 40 * MS),
+              ("trace", "train_step", 0, 100 * MS),
+              ("lower", "jit(train_step)", 100 * MS, 150 * MS),
+              ("cache_read", "", 160 * MS, 190 * MS),
+              ("compile", "jit(train_step)", 150 * MS, 200 * MS),
+              ("gc", "gen2", 60 * MS, 120 * MS),
+              ("gc", "gen2", 500 * MS, 530 * MS),
+              ("trace", "late", 990 * MS, 1010 * MS)]     # ends in the window
+    window = dict(call([1000, 1100, 1200, 1500, 1600, 1700, 2100]), t0=t0,
+                  totals0={"gc": (40, 95 * MS), "trace": (2, 100 * MS)},
+                  totals1={"gc": (52, 220 * MS)},
+                  events=[("gc", "gen2", 1210 * MS, 1328 * MS),
+                          ("gc", "gen1", 1650 * MS, 1652 * MS)])
+    line = step_intervals.host_events_line(
+        [WARM, window, TRACED], True, lambda t: [e for e in before if e[2] <= t])
+    assert line["setup"]["trace_s"] == 0.1 and line["setup"]["lower_s"] == 0.05
+    assert line["setup"]["compile_s"] == 0.05
+    assert line["setup"]["cache_read_s"] == 0.03
+    assert line["setup"]["gc_in_trace_lower_s"] == 0.06
+    assert line["setup"]["top"]["trace"] == [["train_step", 1, 0.1],
+                                             ["inner", 1, 0.02]]
+    assert line["totals_at_window"] == {"gc": [40, 0.095], "trace": [2, 0.1]}
+    assert line["window"]["gc_passes"] == 12 and line["window"]["gc_s"] == 0.125
+    assert line["window"]["gc_ms_max"] == 118.0
+    assert line["window"]["long"] == [
+        [3, 300.0, [["gc", "gen2", 118.0]]], [6, 400.0, []]]
+    assert step_intervals.host_events_line([window], True, lambda t: []) is None

@@ -40,6 +40,7 @@ from typing import Any, Dict, List, Optional
 from tpu_compressed_dp.obs.flight import (FLIGHT_SCHEMA, profile_from_spans,
                                           read_bundles, straggler_gauges,
                                           validate_bundle)
+from tpu_compressed_dp.obs.trace import percentile
 
 #: relative skew (slowest vs fastest rank's mean step time) above which
 #: the fallback classification blames a straggler
@@ -114,6 +115,53 @@ def span_trace_events(spans: List[Dict[str, Any]], pid: int = 0,
     return out
 
 
+def long_steps(spans: List[Dict[str, Any]], factor: float = 2.0
+               ) -> List[str]:
+    """One line for each step record whose host interval (``total``) is
+    over ``factor`` times the records' median: the interval and the host
+    events that overlapped it (``events`` of the record: compiles, cache
+    reads, collector passes), longest first, or "nothing recorded".  The
+    step is named by its ordinal where the record has one (an event
+    stream's), else by its place in the list (a flight bundle's ring)."""
+    totals = sorted(s["total"] for s in spans if s.get("total") is not None)
+    if not totals:
+        return []
+    median = percentile(totals, 0.50)
+    out = []
+    for i, s in enumerate(spans):
+        if s.get("total") is None or s["total"] <= factor * median:
+            continue
+        events = sorted(s.get("events") or [], key=lambda ev: -ev[2])
+        cause = ", ".join(f"{kind} {name} {ms:.0f} ms" if name
+                          else f"{kind} {ms:.0f} ms"
+                          for kind, name, ms, *_ in events)
+        out.append(f"step {s.get('ord', i)}: {s['total'] * 1e3:.0f} ms, "
+                   + (cause or "nothing recorded"))
+    return out
+
+
+def host_event_lane(spans: List[Dict[str, Any]], pid: int = 0,
+                    **args: Any) -> List[Dict[str, Any]]:
+    """Trace-event-format spans of the host events the step records carry,
+    on a thread of their own (2) beside the loop's spans (0) and the
+    device's (1); an event that overlapped several steps is drawn once."""
+    spans = [s for s in spans if "t0" in s]
+    if not spans:
+        return []
+    t_base = min(s["t0"] for s in spans)
+    out, seen = [], set()
+    for s in spans:
+        for kind, name, ms, at_ms in s.get("events") or []:
+            ts = round((s["t0"] - t_base) * 1e6 + at_ms * 1e3)
+            if (kind, name, ts) in seen:
+                continue
+            seen.add((kind, name, ts))
+            out.append({"name": f"{kind} {name}".strip(), "cat": "host_event",
+                        "ph": "X", "pid": pid, "tid": 2, "ts": ts,
+                        "dur": ms * 1e3, "args": dict(args)})
+    return out
+
+
 def rank_lane_events(spans_by_rank: Dict[int, List[Dict[str, Any]]]
                      ) -> List[Dict[str, Any]]:
     """chrome://tracing trace events with one PROCESS LANE PER RANK
@@ -127,7 +175,8 @@ def rank_lane_events(spans_by_rank: Dict[int, List[Dict[str, Any]]]
     not absolute order."""
     out: List[Dict[str, Any]] = []
     for rank in sorted(spans_by_rank):
-        lane = span_trace_events(spans_by_rank[rank], pid=rank, rank=rank)
+        lane = (span_trace_events(spans_by_rank[rank], pid=rank, rank=rank)
+                + host_event_lane(spans_by_rank[rank], pid=rank, rank=rank))
         if lane:
             out.append({"name": "process_name", "ph": "M", "pid": rank,
                         "args": {"name": f"rank {rank}"}})
@@ -274,6 +323,14 @@ def render_report(bundles: Dict[int, Dict[str, Any]], *,
             f"straggler gauges: skew {gauges['straggler/skew_s'] * 1e3:.2f} "
             f"ms/step, slowest rank {int(gauges['straggler/rank'])} "
             f"(+{gauges['straggler/frac'] * 100:.0f}% vs fastest)")
+    for rank in sorted(bundles):
+        ring = (bundles[rank].get("rings") or {}).get("timing") or []
+        slow = long_steps(ring)
+        if slow:
+            lines.append("")
+            lines.append(f"rank {rank}: host intervals over twice the "
+                         "timing ring's median, and what covered them:")
+            lines.extend("  " + ln for ln in slow)
     merged = merge_timeline(bundles)
     if merged:
         lines.append("")

@@ -13,7 +13,9 @@
 #
 # Every result, `setup` and `intervals` line lands in chiprun_out/pairs.txt
 # behind "<side> seed<n> trace<t> "; every interval in
-# chiprun_out/intervals_<side>_<seed>_t<t>.json; stderr in err_<...>.txt.
+# chiprun_out/intervals_<side>_<seed>_t<t>.json; stderr in err_<...>.txt; and
+# from a side that stamps host events (PR 39) a `host_events` line and every
+# event the ring held in events_<side>_<seed>_t<t>.json.
 # Hand in only if every run of the change is within 0.2 % of the change's
 # median and every pair is won (.claude/skills/verify/SKILL.md, PR 30).
 S0=${1:-3000000101}; N=${2:-6}; W=${3:-resnet50_topk_lw_staged}
@@ -21,8 +23,8 @@ ROOT=$(cd "$(dirname "$0")/.." && pwd); OUT=$ROOT/chiprun_out; mkdir -p "$OUT"; 
 run() { # side seed trace
   if [ $((SECONDS - T0)) -gt 3250 ]; then echo "skipped $1 $2 trace$3: out of time" | tee -a "$OUT/pairs.txt"; return; fi
   (cd "$ROOT/bench_checkout/$1" && python3 "$ROOT/tools/step_intervals.py" --workload "$W" --seed "$2" --seconds 20 --trace "$3" \
-      --intervals_out "$OUT/intervals_$1_$2_t$3.json" 2>"$OUT/err_$1_$2_t$3.txt" \
-    | grep -a "^{\|^setup\|^intervals\|FAIL" | sed "s/^/$1 seed$2 trace$3 /" | tee -a "$OUT/pairs.txt" | cut -c1-1500)
+      --intervals_out "$OUT/intervals_$1_$2_t$3.json" --events_out "$OUT/events_$1_$2_t$3.json" 2>"$OUT/err_$1_$2_t$3.txt" \
+    | grep -a "^{\|^setup\|^intervals\|^host_events\|FAIL" | sed "s/^/$1 seed$2 trace$3 /" | tee -a "$OUT/pairs.txt" | cut -c1-1500)
 }
 for i in $(seq 0 $((N - 1))); do
   s=$((S0 + i))

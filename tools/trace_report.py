@@ -9,11 +9,14 @@ Renders, from the records the harnesses emit through
     completion stamp, the device's time on the step and the time it sat
     starved (nothing queued), with the span it starved under — the "where
     does a step's wall time go" table the paper's thesis needs;
+  * **the long steps and their cause** — every step whose host interval is
+    over twice the median, with the host events that overlapped it (a
+    compile, a cache read, a collector pass) or "nothing recorded";
   * a **throughput trajectory** — per epoch / log window: examples|tokens
     per second, MFU, per-chip comm MB/s, loss;
   * optionally (``--chrome out.json``) a **chrome://tracing /
     ui.perfetto.dev trace-event export** of the host timeline, one span
-    per phase per step.
+    per phase per step, and the host events on a lane of their own.
 
 With ``--merge``, takes MULTIPLE per-rank event streams and emits one
 cross-rank chrome://tracing export with a process lane per rank (lane
@@ -39,10 +42,11 @@ from tpu_compressed_dp.obs.export import SCHEMA_VERSION, read_all_events
 from tpu_compressed_dp.obs.trace import percentile
 
 try:
-    from tools.postmortem import (HOST_PHASES, rank_lane_events,
-                                  span_trace_events)
+    from tools.postmortem import (HOST_PHASES, host_event_lane, long_steps,
+                                  rank_lane_events, span_trace_events)
 except ImportError:  # script mode: sys.path[0] is tools/
-    from postmortem import HOST_PHASES, rank_lane_events, span_trace_events
+    from postmortem import (HOST_PHASES, host_event_lane, long_steps,
+                            rank_lane_events, span_trace_events)
 
 WINDOW_KINDS = ("epoch", "step")  # records that carry metrics + timeline
 #: a step record's durations, in report order: the host loop's spans, what
@@ -119,8 +123,10 @@ def throughput_rows(events: List[Dict[str, Any]]) -> List[Dict[str, Any]]:
 
 def chrome_trace_events(events: List[Dict[str, Any]]) -> List[Dict[str, Any]]:
     """The stream's step records as trace events — load in
-    chrome://tracing or ui.perfetto.dev."""
-    return span_trace_events(step_spans(events))
+    chrome://tracing or ui.perfetto.dev: the loop's spans, the device's,
+    and on a lane of their own the host events the records carry."""
+    spans = step_spans(events)
+    return span_trace_events(spans) + host_event_lane(spans)
 
 
 def _fmt(v: Optional[float], spec: str = "10.2f") -> str:
@@ -158,6 +164,14 @@ def render_report(events: List[Dict[str, Any]]) -> str:
         lines.append("  device starved under: " + ", ".join(
             f"{name} {sec * 1e3:.2f} ms"
             for name, sec in sorted(under.items(), key=lambda kv: -kv[1])))
+
+    slow = long_steps(step_spans(events))
+    if slow:
+        lines.append("")
+        lines.append("host intervals over twice the median, and the host "
+                     "events (compiles, cache reads, collector passes) that "
+                     "overlapped them:")
+        lines.extend("  " + ln for ln in slow)
 
     lines.append("")
     lines.append("throughput trajectory:")

@@ -30,6 +30,9 @@ __all__ = ["SCHEMA_VERSION", "EventStream", "read_events", "read_all_events",
 
 #: Bump when a record's field meaning changes incompatibly; consumers
 #: (trace_report, watchdog, tests) check it before interpreting fields.
+#: An added optional key is no such change and bumps nothing: a step record's
+#: ``events`` (the host events over its interval, ``StepTimeline.drain``) and
+#: the ``host/*`` keys of ``timeline`` came in under version 1.
 SCHEMA_VERSION = 1
 
 

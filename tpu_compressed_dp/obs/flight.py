@@ -53,6 +53,9 @@ __all__ = [
 
 #: Bump when a bundle field's meaning changes incompatibly; consumers
 #: (tools/postmortem.py, the forensics drill) check it before interpreting.
+#: An added optional key bumps nothing: a ``timing`` record's ``events``
+#: (the host events over the step, ``[[kind, name, ms, at_ms], ...]``)
+#: came in under version 1.
 FLIGHT_SCHEMA = 1
 
 #: One bounded ring per channel:
@@ -203,9 +206,11 @@ class FlightRecorder:
     def note_spans(self, spans: List[Dict[str, float]]) -> None:
         """Per-step records drained from the StepTimeline (data /
         to_device / dispatch / total host splits; device, starved and the
-        span the device starved under, from the completion stamps) — the
-        straggler evidence.  The record's absolute times and ordinals stay
-        out: the ring has its own clock and sequence."""
+        span the device starved under, from the completion stamps; where
+        a host event overlapped the step, ``events``, which name a long
+        step's cause) — the straggler evidence.  The record's absolute
+        times and ordinals stay out: the ring has its own clock and
+        sequence."""
         for span in spans:
             self.record("timing", "span",
                         **{k: span[k] for k in span

@@ -224,6 +224,20 @@ declare("time/device_starved_frac", GAUGE, "ratio", "mean", "host",
         "step's enqueue")
 declare("time/steps_per_sec", GAUGE, "steps/s", "mean", "host",
         "host-observed step rate over the timeline window")
+declare("host/compiles", COUNTER, "compiles", "max", "host",
+        "backend compiles (or persistent-cache answers in their place) "
+        "inside the timeline window's epoch calls: not 0 after the first "
+        "window means the loop recompiled")
+declare("host/compile_s", TIMING, "seconds", "max", "host",
+        "seconds of the window's epoch calls that some trace, lowering or "
+        "compile of a jitted function covers (length of the union of the "
+        "events: they nest)")
+declare("host/gc_ms_max", TIMING, "ms", "max", "host",
+        "longest garbage-collector pass inside the window's epoch calls "
+        "(passes of 1 ms or more are kept; 0 when none reached that)")
+declare("host/gc_frac", GAUGE, "ratio", "mean", "host",
+        "share of the window's epoch calls' wall time under a "
+        "garbage-collector pass of 1 ms or more")
 
 # --- elastic runtime (train/elastic.py; every survivor derives identical
 #     values from the same coordinated failure, hence max = identity) ----

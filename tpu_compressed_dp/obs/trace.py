@@ -21,6 +21,13 @@ Two complementary views of where a step's time goes:
     profiler annotation (:func:`host_span`), and the clock is the one a
     captured trace's annotations are stamped with, so the records lay over
     the device operations of an ``.xplane.pb``.
+  * **Host events** — :class:`HostEvents`, one process-wide ring on the
+    same clock of what the host does that is no span of the loop
+    (:data:`HOST_EVENT_KINDS`: every trace, lowering, compile and cache
+    answer of a jitted function, every pass of the garbage collector),
+    stamped where the work happens (:func:`install_host_events`).  The
+    timeline lays them over its calls and steps when it is read, so
+    set-up's seconds and a long step each name their cause.
 
 This is the measurement layer the paper's thesis needs: compression claims
 are stated in bits, but they live or die on *seconds per phase*
@@ -29,7 +36,9 @@ are stated in bits, but they live or die on *seconds per phase*
 
 from __future__ import annotations
 
+import bisect
 import collections
+import gc
 import queue
 import threading
 import time
@@ -37,9 +46,11 @@ import weakref
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
+from jax._src import dispatch as _jax_dispatch
 
-__all__ = ["PHASES", "LOOP_SPANS", "phase", "chunk", "host_span",
-           "StepTimeline", "process_timeline", "percentile"]
+__all__ = ["PHASES", "LOOP_SPANS", "HOST_EVENT_KINDS", "phase", "chunk",
+           "host_span", "StepTimeline", "process_timeline", "percentile",
+           "HostEvents", "install_host_events"]
 
 #: The phase taxonomy — every named scope the engines and step factories
 #: emit uses one of these (xprof filters on the ``tcdp.`` prefix):
@@ -138,8 +149,225 @@ def _ns_to_s(ns: Optional[int]) -> Optional[float]:
     return None if ns is None else ns / 1e9
 
 
+def _union(intervals) -> List[List[int]]:
+    """Merged, sorted ``[start, end]`` of any intervals."""
+    out: List[List[int]] = []
+    for s, e in sorted(intervals):
+        if out and s <= out[-1][1]:
+            out[-1][1] = max(out[-1][1], e)
+        else:
+            out.append([s, e])
+    return out
+
+
+def _covered(intervals, windows) -> int:
+    """Nanoseconds of the union of ``intervals`` that lie inside the
+    (disjoint) ``windows``."""
+    merged = _union(intervals)
+    return sum(max(0, min(e, w1) - max(s, w0))
+               for s, e in merged for w0, w1 in windows)
+
+
+#: The kinds of host event, each stamped where the work happens:
+#:   trace       a jitted function traced to a jaxpr (``name``: its
+#:               ``fun_name``); traces nest: a function traced inside
+#:               another's trace raises an event of its own
+#:   lower       the jaxpr lowered to an MLIR module (``fun_name``)
+#:   compile     the backend's compile, or the persistent cache's answer in
+#:               its place (``fun_name``)
+#:   cache_read  the persistent cache answered: the read and deserialisation
+#:               alone, inside a ``compile`` event (``cache_hits`` and
+#:               ``cache_misses`` are counted beside it, with no interval)
+#:   gc          one pass of the garbage collector (``name``: ``gen0`` /
+#:               ``gen1`` / ``gen2``)
+#: Because events of a kind nest, an aggregate of a kind is the length of the
+#: union of its intervals, never their sum; a per-name table is inclusive time.
+HOST_EVENT_KINDS = ("trace", "lower", "compile", "cache_read", "gc")
+
+#: Events the process-wide ring keeps.  The benchmark's largest set-up stamps
+#: 18,300 (the Top-K cell; ResNet-152's 14,400: my chip runs, PR 39), most of
+#: them the sub-millisecond traces of eager operations, so a whole set-up
+#: and the first comparison after it are still in the ring when it is read.
+HOST_EVENT_CAPACITY = 64 * 1024
+
+#: A collector pass shorter than this adds to its generation's totals and
+#: stays out of the ring: generation-0 passes are many and short.
+GC_RING_MIN_NS = 1_000_000
+
+#: Only a pass of this generation is also a profiler annotation
+#: (``tcdp.host.gc``), so that a captured trace's idle gaps can be laid on it.
+GC_ANNOTATED_GENERATION = 2
+
+#: Disjoint recent intervals a kind's running union keeps: an event that
+#: ends later can swallow only what nests inside it.
+_UNION_TAIL = 32
+
+_GC_NAMES = ("gen0", "gen1", "gen2")
+_DURATION_KINDS = {
+    _jax_dispatch.JAXPR_TRACE_EVENT: "trace",
+    _jax_dispatch.JAXPR_TO_MLIR_MODULE_EVENT: "lower",
+    _jax_dispatch.BACKEND_COMPILE_EVENT: "compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_read"}
+_COUNTED_EVENTS = {"/jax/compilation_cache/cache_hits": "cache_hits",
+                   "/jax/compilation_cache/cache_misses": "cache_misses"}
+
+
+def _overlapping(events, t0: Optional[int], t1: Optional[int]) -> List:
+    """Those of ``events`` that overlap ``[t0, t1]``; an open end takes
+    everything on that side."""
+    return [ev for ev in events
+            if (t1 is None or ev[2] <= t1) and (t0 is None or ev[3] >= t0)]
+
+
+class HostEvents:
+    """Bounded ring of host events ``(kind, name, start_ns, end_ns)`` on
+    ``clock`` (default ``time.time_ns``, the timeline's), with per-kind
+    totals ``(count, nanoseconds)`` that do not roll off with the ring; the
+    nanoseconds are the length of the union of the kind's intervals.
+
+    The collector's callback runs wherever an allocation tips a threshold,
+    also inside :meth:`add`, so it takes no lock: it appends to the ring
+    (atomic) and adds to lists only it touches, and a reader copies the
+    ring until no append came between."""
+
+    def __init__(self, capacity: int = HOST_EVENT_CAPACITY,
+                 clock: Callable[[], int] = time.time_ns):
+        if capacity < 1:
+            raise ValueError(f"capacity must be >= 1, got {capacity}")
+        self.capacity = capacity
+        self._clock = clock
+        self._ring: collections.deque = collections.deque(maxlen=capacity)
+        self._lock = threading.Lock()           # the listeners' side only
+        self._totals: Dict[str, List[int]] = {}
+        self._tails: Dict[str, List[Tuple[int, int]]] = {}
+        self._gc = [[0, 0] for _ in _GC_NAMES]   # by generation
+        self._gc_start: Optional[int] = None
+        self._gc_ann = None
+
+    # --- the stamping side ------------------------------------------------
+
+    def add(self, kind: str, name: str, start: int, end: int) -> None:
+        """One event; its kind's total grows by what no earlier event of
+        the kind covered."""
+        with self._lock:
+            # the kind's recent intervals, disjoint and sorted: those from
+            # i on end after this one starts, and up to j they overlap it
+            tail = self._tails.setdefault(kind, [])
+            i = len(tail)
+            while i and tail[i - 1][1] >= start:
+                i -= 1
+            j, new, lo, hi = i, end - start, start, end
+            while j < len(tail) and tail[j][0] <= end:
+                a, b = tail[j]
+                new -= min(b, end) - max(a, start)
+                lo, hi = min(lo, a), max(hi, b)
+                j += 1
+            tail[i:j] = [(lo, hi)]
+            del tail[:-_UNION_TAIL]
+            total = self._totals.setdefault(kind, [0, 0])
+            total[0] += 1
+            total[1] += new
+        self._ring.append((kind, name, start, end))
+
+    def on_duration(self, event: str, duration: float, **kw) -> None:
+        """``jax.monitoring`` duration listener: it learns of an event when
+        the event ends."""
+        kind = _DURATION_KINDS.get(event)
+        if kind is not None:
+            end = self._clock()
+            self.add(kind, str(kw.get("fun_name", "")),
+                     end - int(duration * 1e9), end)
+
+    def on_event(self, event: str, **kw) -> None:
+        """``jax.monitoring`` event listener."""
+        name = _COUNTED_EVENTS.get(event)
+        if name is not None:
+            with self._lock:
+                self._totals.setdefault(name, [0, 0])[0] += 1
+
+    def on_gc(self, phase: str, info: Dict[str, int]) -> None:
+        """``gc.callbacks`` entry: two clock reads and an add a pass."""
+        if phase == "start":
+            if info["generation"] >= GC_ANNOTATED_GENERATION:
+                self._gc_ann = host_span("host.gc")
+                self._gc_ann.__enter__()
+            self._gc_start = self._clock()
+            return
+        end = self._clock()
+        start, self._gc_start = self._gc_start, None
+        if self._gc_ann is not None:
+            self._gc_ann.__exit__(None, None, None)
+            self._gc_ann = None
+        if start is None:               # installed while a pass was running
+            return
+        generation = info["generation"]
+        total = self._gc[generation]
+        total[0] += 1
+        total[1] += end - start
+        if end - start >= GC_RING_MIN_NS:
+            self._ring.append(("gc", _GC_NAMES[generation], start, end))
+
+    # --- the reading side -------------------------------------------------
+
+    def events(self, t0: Optional[int] = None, t1: Optional[int] = None
+               ) -> List[Tuple[str, str, int, int]]:
+        """The ring's events that overlap ``[t0, t1]`` (an open end takes
+        everything on that side), in the order they ended."""
+        while True:
+            try:
+                held = list(self._ring)
+                break
+            except RuntimeError:        # a pass was stamped meanwhile
+                continue
+        return _overlapping(held, t0, t1)
+
+    def totals(self) -> Dict[str, Tuple[int, int]]:
+        """``{kind: (count, nanoseconds)}`` since the ring was made: the
+        :data:`HOST_EVENT_KINDS` that occurred, the collector's also by
+        generation (``gc.gen0``...), and the counts ``cache_hits`` /
+        ``cache_misses`` with 0 nanoseconds."""
+        with self._lock:
+            out = {k: (v[0], v[1]) for k, v in self._totals.items()}
+        passes = [(c, ns) for c, ns in self._gc if c]
+        if passes:
+            out.update(("gc." + name, (c, ns))
+                       for name, (c, ns) in zip(_GC_NAMES, self._gc) if c)
+            out["gc"] = (sum(c for c, _ in passes), sum(ns for _, ns in passes))
+        return out
+
+
+_HOST_EVENTS = HostEvents()       # the process-wide ring; empty until installed
+_HOST_EVENTS_INSTALLED = False
+
+
+def install_host_events() -> HostEvents:
+    """Point JAX's monitoring listeners and the collector's callbacks at the
+    process-wide ring, once however often it is called
+    (``parallel.mesh.setup_compile_cache`` does, first in every entry
+    point), and return the ring.  Always on, like the timeline: no
+    switch."""
+    global _HOST_EVENTS_INSTALLED
+    if not _HOST_EVENTS_INSTALLED:
+        _HOST_EVENTS_INSTALLED = True
+        jax.monitoring.register_event_duration_secs_listener(
+            _HOST_EVENTS.on_duration)
+        jax.monitoring.register_event_listener(_HOST_EVENTS.on_event)
+        gc.callbacks.append(_HOST_EVENTS.on_gc)
+    return _HOST_EVENTS
+
+
 #: what the watcher gets in place of an output for a step that raised
 _NO_OUTPUT = object()
+
+
+def _end_watcher(items: queue.SimpleQueue, thread: threading.Thread,
+                 timeout: float) -> None:
+    """End the watcher and wait for it, bounded: run when its timeline goes
+    and, for one that lives as long as the process, from the interpreter's
+    exit hooks, so that no thread of ours is unwound inside native code."""
+    items.put(None)
+    if thread is not threading.current_thread():    # a collection inside it
+        thread.join(timeout)
 
 
 def _watch(items: queue.SimpleQueue, clock: Callable[[], int],
@@ -259,11 +487,15 @@ class StepTimeline:
     """
 
     def __init__(self, capacity: int = 1024,
-                 clock: Callable[[], int] = time.time_ns):
+                 clock: Callable[[], int] = time.time_ns,
+                 events: Optional[HostEvents] = None):
         if capacity < 1:
             raise ValueError(f"capacity must be >= 1, got {capacity}")
         self.capacity = capacity
         self._clock = clock
+        # the host events laid over the records when they are read: the
+        # process-wide ring, which is on the default clock
+        self._events = _HOST_EVENTS if events is None else events
         self.records: collections.deque = collections.deque(maxlen=capacity)
         # since last drain(); a ring like `records`, so on overflow both
         # keep the NEWEST spans and drained step_spans stay consistent
@@ -291,7 +523,8 @@ class StepTimeline:
     def _open_call(self) -> Dict[str, Any]:
         call = self._calls[-1]["call"] + 1 if self._calls else 0
         self._calls.append({"call": call, "t0": self._t, "t1": None,
-                            "fetch": None, "steps": 0})
+                            "fetch": None, "steps": 0,
+                            "totals0": self._events.totals(), "totals1": None})
         return self._calls[-1]
 
     def _call(self) -> Dict[str, Any]:
@@ -353,12 +586,14 @@ class StepTimeline:
         self._pending.append(rec)
         if self._watcher is None:
             # the thread holds the queue, not the timeline: when the
-            # timeline goes, the finalizer's None ends the thread
+            # timeline goes, or the interpreter exits with it alive (a
+            # finalizer is an exit hook too), the thread is ended and joined
             self._watcher = threading.Thread(
                 target=_watch, args=(self._queue, self._clock, self._lock),
                 name="tcdp-step-stamps", daemon=True)
             self._watcher.start()
-            weakref.finalize(self, self._queue.put, None)
+            weakref.finalize(self, _end_watcher, self._queue, self._watcher,
+                             FLUSH_TIMEOUT_S)
         self._queue.put((rec, token))
 
     def end_call(self, timeout: float = FLUSH_TIMEOUT_S) -> bool:
@@ -366,7 +601,8 @@ class StepTimeline:
         watcher has stamped every step handed to it, so the call's records
         are whole.  False when the bound ran out."""
         whole = self.flush(timeout)
-        self._call()["t1"] = self._clock()
+        call = self._call()
+        call["t1"], call["totals1"] = self._clock(), self._events.totals()
         return whole
 
     def flush(self, timeout: float = FLUSH_TIMEOUT_S) -> bool:
@@ -382,32 +618,54 @@ class StepTimeline:
 
     def calls(self) -> List[Dict[str, Any]]:
         """The last calls, oldest first: ``{"call", "t0", "t1", "fetch",
-        "steps", "records"}`` with ``t0`` the call's begin,
-        ``t1`` its end (None while open, or when the fetch raised),
-        ``steps`` the steps it counted and ``records`` those of them the
-        ring still holds (copies, in dispatch order)."""
+        "steps", "totals0", "totals1", "records", "events"}`` with ``t0``
+        the call's begin, ``t1`` its end (None while open, or when the
+        fetch raised), ``steps`` the steps it counted, ``totals0`` and
+        ``totals1`` the host events' totals at those two moments
+        (:meth:`HostEvents.totals`: every collector pass, also those too
+        short for the ring), ``records`` those of its steps the ring still
+        holds (copies, in dispatch order) and ``events`` the host events
+        that overlap ``[t0, t1]``."""
         with self._lock:
             recs = [dict(r) for r in self.records]
         by_call: Dict[int, List[Dict[str, Any]]] = {}
         for r in recs:
             by_call.setdefault(r["call"], []).append(r)
-        return [dict(c, records=by_call.get(c["call"], []))
+        held = self._events.events()
+        return [dict(c, records=by_call.get(c["call"], []),
+                     events=_overlapping(held, c["t0"], c["t1"]))
                 for c in list(self._calls)]
 
+    def host_events(self, t0: Optional[int] = None, t1: Optional[int] = None
+                    ) -> List[Tuple[str, str, int, int]]:
+        """The host events ``(kind, name, start_ns, end_ns)`` that overlap
+        ``[t0, t1]``: with ``t1`` the first call's ``t0``, set-up's."""
+        return self._events.events(t0, t1)
+
     @staticmethod
-    def _as_event(rec: Dict[str, Any]) -> Dict[str, Any]:
+    def _as_event(rec: Dict[str, Any], events=()) -> Dict[str, Any]:
         """The exported form of a record: seconds, durations for the
-        spans, absolute ``t0``/``done`` on the timeline's clock."""
+        spans, absolute ``t0``/``done`` on the timeline's clock; and, only
+        where host ``events`` overlapped the step's host interval (``t0``
+        to the end of its dispatch), ``events``: ``[[kind, name, ms,
+        at_ms], ...]`` with ``ms`` the event's whole length and ``at_ms``
+        its start from the step's ``t0`` (negative: it began in an earlier
+        step, which carries it too)."""
         by = rec["starved_by"]
-        return {"ord": rec["ord"], "call": rec["call"],
-                "t0": rec["t0"] / 1e9,
-                "data": _seconds(rec["data_wait"]),
-                "to_device": _seconds(rec["to_device"]),
-                "dispatch": _seconds(rec["dispatch"]),
-                "total": (rec["end"] - rec["t0"]) / 1e9,
-                "done": _ns_to_s(rec["done"]), "device": _ns_to_s(rec["device"]),
-                "starved": _ns_to_s(rec["starved"]),
-                "starved_in": max(by, key=by.get) if by else None}
+        out = {"ord": rec["ord"], "call": rec["call"],
+               "t0": rec["t0"] / 1e9,
+               "data": _seconds(rec["data_wait"]),
+               "to_device": _seconds(rec["to_device"]),
+               "dispatch": _seconds(rec["dispatch"]),
+               "total": (rec["end"] - rec["t0"]) / 1e9,
+               "done": _ns_to_s(rec["done"]), "device": _ns_to_s(rec["device"]),
+               "starved": _ns_to_s(rec["starved"]),
+               "starved_in": max(by, key=by.get) if by else None}
+        if events:
+            out["events"] = [
+                [kind, name, (end - start) / 1e6, (start - rec["t0"]) / 1e6]
+                for kind, name, start, end in events]
+        return out
 
     def step_intervals(self) -> List[float]:
         """Seconds per step over the ring window.  A stamped step's
@@ -435,12 +693,14 @@ class StepTimeline:
         Prometheus payload).  The host fraction and the step rate are over
         the host loop's own time (enqueue to enqueue); the starved
         fraction is over the wall time of the window's segments, each from
-        its first step's start to its last completion."""
+        its first step's start to its last completion; the ``host/*`` keys
+        are the host events inside the window's calls."""
         intervals = sorted(self.step_intervals())
         with self._lock:
             rows = [(r["first"], r["t0"], r["end"], r["done"] or 0,
                      _seconds(r["data_wait"]), r["starved"] or 0)
                     for r in self.records]
+            ids = {r["call"] for r in self.records}
         host = data = starved = wall = 0
         seg_t0 = seg_end = None
         for first, t0, end, done, data_wait, starved_ns in rows:
@@ -457,12 +717,37 @@ class StepTimeline:
         host /= 1e9
         over = lambda x, base: x / base if base > 0 else 0.0
         return {
+            **self._host_summary(ids),
             "time/step_p50_ms": percentile(intervals, 0.50) * 1e3,
             "time/step_p95_ms": percentile(intervals, 0.95) * 1e3,
             "time/step_p99_ms": percentile(intervals, 0.99) * 1e3,
             "time/host_data_wait_frac": over(data, host),
             "time/device_starved_frac": over(starved, wall),
             "time/steps_per_sec": over(len(rows), host),
+        }
+
+    def _host_summary(self, call_ids) -> Dict[str, float]:
+        """The host events that overlap the calls ``call_ids``, each call
+        to its end (an open one to now): how many compiles (or cache
+        answers) and the seconds some trace, lowering or compile covers,
+        since a recompile in the loop is an operator's first question; the
+        longest collector pass and the share of the calls' wall under one
+        (passes of :data:`GC_RING_MIN_NS` or more: the ring's)."""
+        now = self._clock()
+        calls = [(c["t0"], now if c["t1"] is None else c["t1"])
+                 for c in list(self._calls) if c["call"] in call_ids]
+        events = [ev for ev in (self._events.events(calls[0][0], calls[-1][1])
+                                if calls else [])
+                  if any(ev[2] <= t1 and ev[3] >= t0 for t0, t1 in calls)]
+        of = lambda *kinds: [(s, e) for k, _, s, e in events if k in kinds]
+        passes = of("gc")
+        wall = sum(t1 - t0 for t0, t1 in calls)
+        return {
+            "host/compiles": float(len(of("compile"))),
+            "host/compile_s": _covered(of("trace", "lower", "compile"),
+                                       calls) / 1e9,
+            "host/gc_ms_max": max((e - s for s, e in passes), default=0) / 1e6,
+            "host/gc_frac": _covered(passes, calls) / wall if wall > 0 else 0.0,
         }
 
     def drain(self) -> List[Dict[str, Any]]:
@@ -474,9 +759,20 @@ class StepTimeline:
         watcher has not passed yet carry ``done=None``: :meth:`flush`
         first where the device has been drained."""
         with self._lock:
-            out = [self._as_event(r) for r in self._pending]
+            recs = [dict(r) for r in self._pending]
         self._pending.clear()
-        return out
+        if not recs:
+            return []
+        # each event to the steps it overlapped: the steps follow one
+        # another, so the first is found by its end
+        ends = [r["end"] for r in recs]
+        over: Dict[int, List] = {}
+        for ev in self._events.events(recs[0]["t0"], ends[-1]):
+            i = bisect.bisect_left(ends, ev[2])
+            while i < len(recs) and recs[i]["t0"] <= ev[3]:
+                over.setdefault(i, []).append(ev)
+                i += 1
+        return [self._as_event(r, over.get(i, ())) for i, r in enumerate(recs)]
 
 
 _PROCESS_TIMELINE: Optional[StepTimeline] = None

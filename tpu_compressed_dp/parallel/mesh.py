@@ -17,6 +17,8 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from tpu_compressed_dp.obs.trace import install_host_events
+
 __all__ = [
     "distributed_init",
     "setup_compile_cache",
@@ -87,7 +89,13 @@ def setup_compile_cache() -> str:
     ``<checkout>/.jax_cache``, derived from the package location: the
     directory is part of the cache key, so it is never a temporary, pid- or
     time-derived name.  Returns the directory in use.
+
+    Being first, it is also where the host events are switched on
+    (``obs.trace.install_host_events``): every trace, lowering, compile and
+    cache answer from here on, and every collector pass, is on the step
+    timeline's clock with no edit of an entry point.
     """
+    install_host_events()
     from_env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if from_env:
         return from_env
