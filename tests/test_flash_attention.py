@@ -11,8 +11,10 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 from jax.sharding import SingleDeviceSharding
 
+import tpu_compressed_dp.ops.flash_attention as fa
 import tpu_compressed_dp.ops.ring_attention as ra_mod
 from tpu_compressed_dp.ops.flash_attention import flash_causal_attention
 
@@ -88,6 +90,91 @@ def test_streamed_bwd_matches_resident(monkeypatch, shape):
     for a, b, nm in zip(g_streamed, g_resident, "qkv"):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-6,
                                    err_msg=f"d{nm} streamed vs resident")
+
+
+def _frozen_fwd_kernel(scale, blk_q, blk_k, n_k, d,
+                       q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref):
+    """The forward kernel as it stood before its statistics were widened
+    (PR 40's parent), frozen here as the plain reference: the running maximum
+    and sum are `[blk_q, 1]` columns, lane 0 of today's scratch, broadcast
+    over the lanes wherever they meet a block."""
+    m_ref, l_ref = m_ref.at[:, :1], l_ref.at[:, :1]
+    qi = pl.program_id(1)
+    acc_ref[:] = jnp.zeros_like(acc_ref)
+    m_ref[:] = jnp.full_like(m_ref, fa._NEG_INF)
+    l_ref[:] = jnp.zeros_like(l_ref)
+    q = q_ref[0]
+
+    def body(kj, _):
+        k = k_ref[0, pl.ds(kj * blk_k, blk_k)]
+        v = v_ref[0, pl.ds(kj * blk_k, blk_k)]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        s = jnp.where(fa._causal_pos(qi, kj, blk_q, blk_k), s, fa._NEG_INF)
+        m_prev = m_ref[:]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_ref[:] = l_ref[:] * corr + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[:] = m_new
+        return 0
+
+    n_live = jnp.minimum(((qi + 1) * blk_q + blk_k - 1) // blk_k, n_k)
+    jax.lax.fori_loop(0, n_live, body, 0)
+    l = l_ref[:]
+    o = acc_ref[:] / l
+    lse = m_ref[:] + jnp.log(l)
+    d_store = o_ref.shape[-1]
+    out = jnp.concatenate(
+        [o[:, :d], lse] + ([jnp.zeros((blk_q, d_store - d - 1), jnp.float32)]
+                           if d_store - d - 1 else []), axis=1)
+    o_ref[0] = out.astype(o_ref.dtype)
+
+
+def _o_lse_grads(q, k, v, do):
+    """(o, lse, dq, dk, dv) through the wrappers the custom VJP runs."""
+    o, res = fa._fa_fwd(q, k, v, None, True)
+    return (o, res[4]) + tuple(fa._fa_bwd(None, True, res, do))
+
+
+@pytest.mark.parametrize(
+    "shape, dtype, blk",
+    [
+        ((1, 2, 128, 64), jnp.float32, None),     # t < 512: one pair a head
+        ((2, 1, 256, 128), jnp.float32, None),    # a head of 128: o's second lane tile
+        ((1, 1, 384, 64), jnp.bfloat16, None),    # one 384-block: three lane tiles of s
+        ((1, 2, 512, 128), jnp.bfloat16, None),
+        ((1, 1, 1024, 128), jnp.float32, None),   # two blocks: corr rescales the accumulator
+        ((1, 2, 1536, 64), jnp.bfloat16, None),
+        ((1, 1, 2048, 128), jnp.float32, None),   # four: the statistics carried over four pairs
+        ((1, 1, 512, 256), jnp.bfloat16, None),   # the widest head: corr under two lane tiles
+        # blocks handed to `_fwd` / `_bwd` that differ, both ways about
+        ((1, 2, 512, 64), jnp.float32, (256, 128)),
+        ((1, 2, 512, 64), jnp.bfloat16, (128, 256)),
+    ],
+)
+def test_bitwise_the_one_lane_statistics(monkeypatch, shape, dtype, blk):
+    """`o`, `lse` and, through them, dq, dk and dv of the kernel that keeps
+    its running maximum and sum in 128 equal lanes, bit for bit those of the
+    frozen kernel that keeps them in one: the same maxima, differences,
+    `exp`s and sums on the same operands in the same order."""
+    ks = jax.random.split(jax.random.key(7), 4)
+    q, k, v, do = ((jax.random.normal(kk, shape, jnp.float32) * 0.5)
+                   .astype(dtype) for kk in ks)
+    if blk is not None:
+        monkeypatch.setattr(fa, "_pick_blocks", lambda t: blk)
+    got = _o_lse_grads(q, k, v, do)
+    monkeypatch.setattr(fa, "_fwd_kernel", _frozen_fwd_kernel)
+    want = _o_lse_grads(q, k, v, do)
+    for a, b, nm in zip(got, want, ("o", "lse", "dq", "dk", "dv")):
+        assert a.dtype == b.dtype and a.shape == b.shape, nm
+        np.testing.assert_array_equal(
+            np.asarray(a.astype(jnp.float32)), np.asarray(b.astype(jnp.float32)),
+            err_msg=nm)
 
 
 def _grad_of_sum(shape, dtype, **aval):

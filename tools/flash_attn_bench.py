@@ -1,0 +1,155 @@
+#!/usr/bin/env python3
+"""The attention kernels alone, on the chip: ms a call and bit-for-bit outputs
+of several copies of ``ops/flash_attention.py`` in one process.
+
+    git archive <parent commit> | tar -x -C bench_checkout/parent
+    chiprun -- python3 tools/flash_attn_bench.py [label=path/to/flash_attention.py ...]
+
+With no arguments the sides are ``parent`` (``bench_checkout/parent``'s
+module) and ``tree`` (this checkout's).  The first side is the reference: every
+other side's ``o``, ``lse``, dq, dk and dv are compared with its, bit for bit,
+at each shape's own softmax scale.  A side is one file, loaded by path (the
+module imports nothing of its package), so a mechanism is timed alone by
+handing in a copy of the module that holds only it.
+
+A forward is ``_fa_fwd`` (pad, kernel, slice); a backward is ``_fa_bwd``
+(``delta``, the lane packing, kernel, slices).  Each is timed as a jitted
+``lax.fori_loop`` of ``--calls`` calls whose carry runs through one element of
+an operand, so nothing is hoisted and no pass is added; the least of
+``--reps`` timings counts.  Run by no benchmark cell and no tier-1 test;
+``--rehearse`` runs two tiny shapes under the Pallas interpreter on the CPU to
+check the control flow (its times mean nothing and are not printed).
+"""
+
+from __future__ import annotations
+
+import argparse
+import importlib.util
+import json
+import os
+import sys
+import time
+
+import numpy as np
+
+import jax
+import jax.numpy as jnp
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+MODULE = "tpu_compressed_dp/ops/flash_attention.py"
+SHAPES = [
+    ((1, 16, 4096, 128), "bfloat16"),   # ouro_2p6b_dense_staged's call
+    ((1, 8, 8192, 128), "bfloat16"),    # nemotron3_super_dense_staged's
+    ((2, 12, 1024, 64), "bfloat16"),
+    ((1, 4, 4096, 128), "float32"),
+]
+REHEARSAL_SHAPES = [((1, 2, 1024, 64), "bfloat16"), ((1, 1, 1024, 64), "float32")]
+NAMES = ("o", "lse", "dq", "dk", "dv")
+
+
+def load(label: str, path: str):
+    spec = importlib.util.spec_from_file_location(f"flash_attention_{label}", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def operands(shape, dtype):
+    keys = jax.random.split(jax.random.key(40), 4)
+    return tuple((jax.random.normal(k, shape, jnp.float32) * 0.5).astype(dtype)
+                 for k in keys)
+
+
+def live_pairs(fa, t):
+    """(q block, kv block) pairs a head's kernels visit: those not wholly
+    above the diagonal, at the module's own blocks."""
+    bq, bk = fa._pick_blocks(t)
+    return sum(-(-(qi + 1) * bq // bk) for qi in range(t // bq))
+
+
+def outputs(fa, interpret):
+    def run(q, k, v, do):
+        o, res = fa._fa_fwd(q, k, v, None, interpret)
+        return (o, res[4]) + tuple(fa._fa_bwd(None, interpret, res, do))
+    return jax.jit(run)
+
+
+def loops(fa, calls, interpret):
+    def fwd(q, k, v):
+        def body(_, q):
+            o, _ = fa._fa_fwd(q, k, v, None, interpret)
+            return q.at[0, 0, 0, 0].add((o[0, 0, 0, 0] * 0).astype(q.dtype))
+        return jax.lax.fori_loop(0, calls, body, q)
+
+    def bwd(q, k, v, o, lse, do):
+        def body(_, do):
+            dq, dk, dv = fa._fa_bwd(None, interpret, (q, k, v, o, lse), do)
+            probe = dq[0, 0, 0, 0] + dk[0, 0, 0, 0] + dv[0, 0, 0, 0]
+            return do.at[0, 0, 0, 0].add((probe * 0).astype(do.dtype))
+        return jax.lax.fori_loop(0, calls, body, do)
+
+    return jax.jit(fwd), jax.jit(bwd)
+
+
+def ms_a_call(fn, args, calls, reps):
+    jax.block_until_ready(fn(*args))          # compile
+    jax.block_until_ready(fn(*args))          # and ramp the chip
+    best = float("inf")
+    for _ in range(reps):
+        t0 = time.perf_counter()
+        jax.block_until_ready(fn(*args))
+        best = min(best, time.perf_counter() - t0)
+    return best / calls * 1e3
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("sides", nargs="*", metavar="label=path",
+                    help="copies of flash_attention.py; the first is the reference")
+    ap.add_argument("--calls", type=int, default=100)
+    ap.add_argument("--reps", type=int, default=3)
+    ap.add_argument("--rehearse", action="store_true")
+    ap.add_argument("--out", default=os.path.join(ROOT, "chiprun_out",
+                                                  "flash_attn_bench.json"))
+    args = ap.parse_args(argv)
+    sides = [s.split("=", 1) for s in args.sides] or [
+        ["parent", os.path.join(ROOT, "bench_checkout", "parent", MODULE)],
+        ["tree", os.path.join(ROOT, MODULE)]]
+    device = jax.devices()[0]
+    if not args.rehearse and device.platform != "tpu":
+        print(f"flash_attn_bench: needs a TPU, found {device.platform}; "
+              "--rehearse checks the control flow on the CPU", file=sys.stderr)
+        return 2
+    calls = 2 if args.rehearse else args.calls
+    modules = [(label, load(label, path)) for label, path in sides]
+    rows = []
+    for shape, dtype in REHEARSAL_SHAPES if args.rehearse else SHAPES:
+        q, k, v, do = operands(shape, getattr(jnp, dtype))
+        reference = None
+        for label, fa in modules:
+            got = outputs(fa, args.rehearse)(q, k, v, do)
+            host = [np.asarray(x.astype(jnp.float32)) for x in got]
+            reference = reference or host
+            row = {"shape": list(shape), "dtype": dtype, "side": label,
+                   "bitwise": {n: bool(np.array_equal(a, b))
+                               for n, a, b in zip(NAMES, host, reference)}}
+            fwd, bwd = loops(fa, calls, args.rehearse)
+            fwd_ms = ms_a_call(fwd, (q, k, v), calls, args.reps)
+            bwd_ms = ms_a_call(bwd, (q, k, v, got[0], got[1], do), calls, args.reps)
+            if not args.rehearse:
+                pairs = shape[0] * shape[1] * live_pairs(fa, shape[2])
+                row.update(fwd_ms=fwd_ms, bwd_ms=bwd_ms,
+                           fwd_us_a_pair=fwd_ms * 1e3 / pairs,
+                           bwd_us_a_pair=bwd_ms * 1e3 / pairs)
+            rows.append(row)
+            print(json.dumps(row), flush=True)
+    result = {"device": device.device_kind, "platform": device.platform,
+              "calls": calls, "reps": args.reps, "rows": rows}
+    os.makedirs(os.path.dirname(args.out), exist_ok=True)
+    with open(args.out, "w") as f:
+        json.dump(result, f, indent=1)
+    return 0 if all(all(r["bitwise"].values()) for r in rows) else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())

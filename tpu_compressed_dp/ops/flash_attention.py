@@ -21,6 +21,18 @@ accumulate over its q blocks, dq of the whole head accumulates over the kv
 axis in a float32 [T, d_pad] VMEM scratch (5 products a pair; a kernel for
 dq and one for dk/dv would each recompute s and dP: 7, and the chain twice).
 
+Which pairs are masked: a (q block, kv block) pair wholly above the diagonal
+is never visited; every visited pair builds ``_causal_pos`` and selects
+through it, though only the pairs that straddle the diagonal have a masked
+element (8 of 36 a head at T=4096, 32 of 528 at T=8192).  Bodies without the
+mask for the pairs under the diagonal were built and timed (PERF.md, PR 40):
+the 651 mask operations a 512 x 512 pair sit in VALU slots that are empty
+anyway, and two loops a kernel read 0.7 % slower to 0.7 % faster than one, so
+there is one loop.  The forward pair's pace is the cross-lane unit, which is
+why its running maximum and sum are kept lane-replicated in ``[blk_q, 128]``
+scratch: a ``[blk_q, 1]`` statistic has to be broadcast over the lanes again,
+an XLU round trip a row group, wherever it meets a block.
+
 Mosaic-shaped storage: per-row scalars (lse, delta) cannot leave a kernel as
 ``[1, block_q]`` blocks (block last-two-dims must be 8/128-divisible), so
 they ride the LANE dimension of the tensors that already flow: the forward
@@ -70,6 +82,13 @@ def _causal_pos(qi, kj, blk_q, blk_k):
     return q_pos >= k_pos
 
 
+def _lanes(x, n: int):
+    """A lane-replicated ``[rows, 128]`` statistic under ``n`` lanes, a
+    multiple of 128 as every block and padded head is: the same vregs again,
+    no operation."""
+    return jnp.concatenate([x] * (n // 128), axis=1)
+
+
 def _fwd_kernel(scale: float, blk_q: int, blk_k: int, n_k: int, d: int,
                 q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref):
     qi = pl.program_id(1)
@@ -85,14 +104,22 @@ def _fwd_kernel(scale: float, blk_q: int, blk_k: int, n_k: int, d: int,
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale   # [blk_q, blk_k]
         s = jnp.where(_causal_pos(qi, kj, blk_q, blk_k), s, _NEG_INF)
+        # m and l are [blk_q, 128] with every lane of a row equal: a row
+        # reduce's result leaves the cross-lane unit in every lane, so
+        # widening it is no operation, and neither m under s nor corr over
+        # the accumulator needs the lane broadcast (an XLU round trip a row
+        # group, the pair's pace: PERF.md, PR 40) that a [blk_q, 1] column does
         m_prev = m_ref[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)                       # masked lanes -> 0
+        m_new = jnp.maximum(m_prev, jnp.broadcast_to(
+            jnp.max(s, axis=1, keepdims=True), m_prev.shape))
+        p = jnp.exp(s - _lanes(m_new, blk_k))        # masked lanes -> 0
         corr = jnp.exp(m_prev - m_new)
-        l_ref[:] = l_ref[:] * corr + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        l_ref[:] = l_ref[:] * corr + jnp.broadcast_to(
+            jnp.sum(p, axis=1, keepdims=True), corr.shape)
+        acc_ref[:] = (acc_ref[:] * _lanes(corr, acc_ref.shape[1])
+                      + jax.lax.dot_general(
+                          p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                          preferred_element_type=jnp.float32))
         m_ref[:] = m_new
         return 0
 
@@ -101,8 +128,8 @@ def _fwd_kernel(scale: float, blk_q: int, blk_k: int, n_k: int, d: int,
     n_live = jnp.minimum(((qi + 1) * blk_q + blk_k - 1) // blk_k, n_k)
     jax.lax.fori_loop(0, n_live, body, 0)
     l = l_ref[:]
-    o = acc_ref[:] / l                               # [blk_q, d_pad]
-    lse = m_ref[:] + jnp.log(l)                      # [blk_q, 1]
+    o = acc_ref[:] / _lanes(l, acc_ref.shape[1])     # [blk_q, d_pad]
+    lse = (m_ref[:] + jnp.log(l))[:, :1]             # [blk_q, 1]
     d_store = o_ref.shape[-1]
     out = jnp.concatenate(
         [o[:, :d], lse] + ([jnp.zeros((blk_q, d_store - d - 1), jnp.float32)]
@@ -264,8 +291,10 @@ def _fwd(q, k, v, scale, blk, interpret, d):
         out_shape=jax.ShapeDtypeStruct((b * h, t, ds), jnp.float32, vma=vma),
         scratch_shapes=[
             pltpu.VMEM((bq, d_pad), jnp.float32),
-            pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, 1), jnp.float32),
+            # m and l, lane-replicated: the 64 vregs a block that a [bq, 1]
+            # scratch is tiled to anyway, every lane of them in use
+            pltpu.VMEM((bq, 128), jnp.float32),
+            pltpu.VMEM((bq, 128), jnp.float32),
         ],
         interpret=interpret,
         name="flash_attn_fwd",
