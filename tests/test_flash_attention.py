@@ -93,7 +93,7 @@ def test_streamed_bwd_matches_resident(monkeypatch, shape):
 
 
 def _frozen_fwd_kernel(scale, blk_q, blk_k, n_k, d,
-                       q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref):
+                       q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, window=None):
     """The forward kernel as it stood before its statistics were widened
     (PR 40's parent), frozen here as the plain reference: the running maximum
     and sum are `[blk_q, 1]` columns, lane 0 of today's scratch, broadcast
@@ -135,10 +135,10 @@ def _frozen_fwd_kernel(scale, blk_q, blk_k, n_k, d,
     o_ref[0] = out.astype(o_ref.dtype)
 
 
-def _o_lse_grads(q, k, v, do):
+def _o_lse_grads(q, k, v, do, window=None):
     """(o, lse, dq, dk, dv) through the wrappers the custom VJP runs."""
-    o, res = fa._fa_fwd(q, k, v, None, True)
-    return (o, res[4]) + tuple(fa._fa_bwd(None, True, res, do))
+    o, res = fa._fa_fwd(q, k, v, None, True, window)
+    return (o, res[4]) + tuple(fa._fa_bwd(None, True, res, do, window))
 
 
 @pytest.mark.parametrize(
@@ -177,12 +177,90 @@ def test_bitwise_the_one_lane_statistics(monkeypatch, shape, dtype, blk):
             err_msg=nm)
 
 
-def _grad_of_sum(shape, dtype, **aval):
+def masked_softmax_attention(q, k, v, window):
+    """The plain form: the whole [T, T] of every head, the band as a mask."""
+    t, d = q.shape[-2:]
+    i, j = jnp.arange(t)[:, None], jnp.arange(t)[None, :]
+    seen = (j <= i) & (i - j < window)
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k, precision="highest") / np.sqrt(d)
+    p = jax.nn.softmax(jnp.where(seen, s, -jnp.inf), axis=-1)
+    return jnp.einsum("bhqk,bhkd->bhqd", p, v, precision="highest")
+
+
+@pytest.mark.parametrize(
+    "shape, window, blk, streamed",
+    [
+        ((1, 2, 1024, 64), 512, None, False),    # a block multiple: pairs (1, 0) and the diagonal's
+        ((1, 1, 1024, 128), 200, None, False),   # inside one block: the edge cuts pair (1, 0)
+        ((1, 1, 1536, 64), 700, None, True),     # wider than a block: three pairs a q block
+        ((2, 1, 1024, 64), 130, (128, 128), True),   # 8 blocks: whole pairs behind the band skipped
+        ((1, 2, 1024, 64), 256, (128, 256), False),  # unequal blocks, both loops' ends
+        ((1, 1, 1024, 64), 384, (256, 128), True),
+        ((1, 1, 512, 64), 1, (128, 128), False),     # the token itself alone
+        ((1, 1, 512, 64), 4096, (128, 128), True),   # a band wider than the sequence: causal
+    ],
+)
+def test_banded_kernels_match_the_masked_softmax(monkeypatch, shape, window,
+                                                 blk, streamed):
+    """o, dq, dk and dv of the kernels with a window against the masked
+    softmax over the whole [T, T], resident and (as the chip runs it) with q
+    and the cotangent streamed by DMA: the pair loops' lower and upper ends,
+    the second masked edge, and a prefetch that must stop where the loop does."""
+    if blk is not None:
+        monkeypatch.setattr(fa, "_pick_blocks", lambda t: blk)
+    if streamed:
+        monkeypatch.setenv("TPU_CDP_FORCE_STREAMED_DKV", "1")
+    ks = jax.random.split(jax.random.key(11), 4)
+    q, k, v, tgt = (jax.random.normal(kk, shape, jnp.float32) * 0.5 for kk in ks)
+    lf = lambda q, k, v: jnp.mean(
+        (flash_causal_attention(q, k, v, None, True, window) - tgt) ** 2)
+    le = lambda q, k, v: jnp.mean(
+        (masked_softmax_attention(q, k, v, window) - tgt) ** 2)
+    np.testing.assert_allclose(
+        np.asarray(flash_causal_attention(q, k, v, None, True, window)),
+        np.asarray(masked_softmax_attention(q, k, v, window)), atol=1e-5)
+    for a, b, nm in zip(jax.grad(lf, (0, 1, 2))(q, k, v),
+                        jax.grad(le, (0, 1, 2))(q, k, v), "qkv"):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5,
+                                   err_msg=f"d{nm}")
+
+
+@pytest.mark.parametrize("shape, dtype", [((1, 2, 1024, 64), jnp.float32),
+                                          ((1, 1, 1536, 128), jnp.bfloat16)])
+def test_bitwise_no_window_is_a_band_over_everything(shape, dtype):
+    """A call without a window visits every pair under the diagonal and masks
+    by the diagonal alone (the frozen kernel above is held to it bit for bit);
+    a band that reaches past the first key visits and keeps the same: o, lse,
+    dq, dk, dv bit for bit."""
+    ks = jax.random.split(jax.random.key(13), 4)
+    q, k, v, do = ((jax.random.normal(kk, shape, jnp.float32) * 0.5)
+                   .astype(dtype) for kk in ks)
+    for a, b, nm in zip(_o_lse_grads(q, k, v, do),
+                        _o_lse_grads(q, k, v, do, window=shape[2]),
+                        ("o", "lse", "dq", "dk", "dv")):
+        np.testing.assert_array_equal(np.asarray(a.astype(jnp.float32)),
+                                      np.asarray(b.astype(jnp.float32)), err_msg=nm)
+
+
+def test_the_xla_chain_takes_the_same_band():
+    """Off the TPU, and for shapes the kernel refuses, `ring_attention` masks
+    the band in its XLA chain; the ring path refuses a window."""
+    shape = (1, 4, 96, 16)
+    ks = jax.random.split(jax.random.key(17), 3)
+    q, k, v = (jax.random.normal(kk, shape, jnp.float32) for kk in ks)
+    np.testing.assert_allclose(
+        np.asarray(ra_mod.ring_attention(q, k[:, :2], v[:, :2], window=24)),
+        np.asarray(masked_softmax_attention(
+            q, jnp.repeat(k[:, :2], 2, 1), jnp.repeat(v[:, :2], 2, 1), 24)),
+        atol=1e-5)
+
+
+def _grad_of_sum(shape, dtype, window=None, **aval):
     """`jax.grad` of a sum through the kernel as dispatched (not interpreted),
     and its abstract operand: for tests that trace or compile and never run."""
     assert ra_mod.fused_attention_fits(shape, shape, jnp.dtype(dtype).itemsize)
     loss = lambda q, k, v: jnp.sum(
-        flash_causal_attention(q, k, v).astype(jnp.float32))
+        flash_causal_attention(q, k, v, None, False, window).astype(jnp.float32))
     return jax.grad(loss, (0, 1, 2)), jax.ShapeDtypeStruct(shape, dtype, **aval)
 
 
@@ -233,20 +311,23 @@ def one_chip():
 
 
 @pytest.mark.parametrize(
-    "shape, dtype",
+    "shape, dtype, window",
     [
-        ((1, 16, 4096, 128), jnp.bfloat16),   # the LM cell's attention call
-        ((1, 2, 8192, 128), jnp.bfloat16),    # longest T: dq accumulator 4 MB
-        ((1, 2, 4096, 256), jnp.bfloat16),    # widest head: 4 MB at 512-blocks
-        ((1, 2, 4096, 128), jnp.float32),     # fp32 operands, 256-lane cotangent
+        ((1, 16, 4096, 128), jnp.bfloat16, None),   # the LM cell's attention call
+        ((1, 2, 8192, 128), jnp.bfloat16, None),    # longest T: dq accumulator 4 MB
+        ((1, 2, 4096, 256), jnp.bfloat16, None),    # widest head: 4 MB at 512-blocks
+        ((1, 2, 4096, 128), jnp.float32, None),     # fp32 operands, 256-lane cotangent
+        ((1, 2, 8192, 128), jnp.bfloat16, 512),     # the Laguna cell's banded call
+        ((1, 2, 4096, 128), jnp.bfloat16, 200),     # a band inside one 512-block
     ],
 )
-def test_backward_compiles_for_v5e_at_admitted_extremes(one_chip, shape, dtype):
+def test_backward_compiles_for_v5e_at_admitted_extremes(one_chip, shape, dtype,
+                                                        window):
     """Forward and the one backward kernel pass Mosaic for a v5e at the
     extremes `fused_attention_fits` admits: the [T, d_pad] float32 dq
     accumulator, the streamed blocks and the [blk, blk] temporaries fit the
     scoped-VMEM ceiling, so no shape needs a second form of the backward.
     A compile, not a run: nothing here is a time."""
-    grad, x = _grad_of_sum(shape, dtype, sharding=one_chip)
+    grad, x = _grad_of_sum(shape, dtype, window, sharding=one_chip)
     compiled = jax.jit(grad).lower(x, x, x).compile()
     assert compiled.as_text().count("tpu_custom_call") == 2

@@ -8,7 +8,10 @@ of several copies of ``ops/flash_attention.py`` in one process.
 With no arguments the sides are ``parent`` (``bench_checkout/parent``'s
 module) and ``tree`` (this checkout's).  The first side is the reference: every
 other side's ``o``, ``lse``, dq, dk and dv are compared with its, bit for bit,
-at each shape's own softmax scale.  A side is one file, loaded by path (the
+at each shape's own softmax scale.  A shape with a third element is a
+sliding-window call (``window``): sides whose module takes no window (PR 40's
+and older) sit it out, and the first side that takes one is its reference.
+A side is one file, loaded by path (the
 module imports nothing of its package), so a mechanism is timed alone by
 handing in a copy of the module that holds only it.
 
@@ -25,6 +28,7 @@ from __future__ import annotations
 
 import argparse
 import importlib.util
+import inspect
 import json
 import os
 import sys
@@ -42,8 +46,11 @@ SHAPES = [
     ((1, 8, 8192, 128), "bfloat16"),    # nemotron3_super_dense_staged's
     ((2, 12, 1024, 64), "bfloat16"),
     ((1, 4, 4096, 128), "float32"),
+    ((1, 48, 8192, 128), "bfloat16"),         # laguna_xs2_dense_staged's full layers, a sequence
+    ((1, 64, 8192, 128), "bfloat16", 512),    # ... and its window layers
 ]
-REHEARSAL_SHAPES = [((1, 2, 1024, 64), "bfloat16"), ((1, 1, 1024, 64), "float32")]
+REHEARSAL_SHAPES = [((1, 2, 1024, 64), "bfloat16"), ((1, 1, 1024, 64), "float32"),
+                    ((1, 2, 1024, 64), "bfloat16", 300)]
 NAMES = ("o", "lse", "dq", "dk", "dv")
 
 
@@ -60,30 +67,40 @@ def operands(shape, dtype):
                  for k in keys)
 
 
-def live_pairs(fa, t):
+def live_pairs(fa, t, window=None):
     """(q block, kv block) pairs a head's kernels visit: those not wholly
-    above the diagonal, at the module's own blocks."""
+    above the diagonal nor wholly behind the band, at the module's own blocks."""
     bq, bk = fa._pick_blocks(t)
-    return sum(-(-(qi + 1) * bq // bk) for qi in range(t // bq))
+    first = lambda qi: 0 if window is None else max(qi * bq - window + 1, 0) // bk
+    return sum(-(-(qi + 1) * bq // bk) - first(qi) for qi in range(t // bq))
 
 
-def outputs(fa, interpret):
+def takes_window(fa) -> bool:
+    return "window" in inspect.signature(fa._fa_fwd).parameters
+
+
+def band(window) -> dict:
+    return {} if window is None else {"window": window}
+
+
+def outputs(fa, interpret, window=None):
     def run(q, k, v, do):
-        o, res = fa._fa_fwd(q, k, v, None, interpret)
-        return (o, res[4]) + tuple(fa._fa_bwd(None, interpret, res, do))
+        o, res = fa._fa_fwd(q, k, v, None, interpret, **band(window))
+        return (o, res[4]) + tuple(fa._fa_bwd(None, interpret, res, do, **band(window)))
     return jax.jit(run)
 
 
-def loops(fa, calls, interpret):
+def loops(fa, calls, interpret, window=None):
     def fwd(q, k, v):
         def body(_, q):
-            o, _ = fa._fa_fwd(q, k, v, None, interpret)
+            o, _ = fa._fa_fwd(q, k, v, None, interpret, **band(window))
             return q.at[0, 0, 0, 0].add((o[0, 0, 0, 0] * 0).astype(q.dtype))
         return jax.lax.fori_loop(0, calls, body, q)
 
     def bwd(q, k, v, o, lse, do):
         def body(_, do):
-            dq, dk, dv = fa._fa_bwd(None, interpret, (q, k, v, o, lse), do)
+            dq, dk, dv = fa._fa_bwd(None, interpret, (q, k, v, o, lse), do,
+                                    **band(window))
             probe = dq[0, 0, 0, 0] + dk[0, 0, 0, 0] + dv[0, 0, 0, 0]
             return do.at[0, 0, 0, 0].add((probe * 0).astype(do.dtype))
         return jax.lax.fori_loop(0, calls, body, do)
@@ -123,21 +140,24 @@ def main(argv=None) -> int:
     calls = 2 if args.rehearse else args.calls
     modules = [(label, load(label, path)) for label, path in sides]
     rows = []
-    for shape, dtype in REHEARSAL_SHAPES if args.rehearse else SHAPES:
+    for shape, dtype, *window in REHEARSAL_SHAPES if args.rehearse else SHAPES:
+        window = window[0] if window else None
         q, k, v, do = operands(shape, getattr(jnp, dtype))
         reference = None
         for label, fa in modules:
-            got = outputs(fa, args.rehearse)(q, k, v, do)
+            if window is not None and not takes_window(fa):
+                continue
+            got = outputs(fa, args.rehearse, window)(q, k, v, do)
             host = [np.asarray(x.astype(jnp.float32)) for x in got]
             reference = reference or host
-            row = {"shape": list(shape), "dtype": dtype, "side": label,
+            row = {"shape": list(shape), "dtype": dtype, "window": window, "side": label,
                    "bitwise": {n: bool(np.array_equal(a, b))
                                for n, a, b in zip(NAMES, host, reference)}}
-            fwd, bwd = loops(fa, calls, args.rehearse)
+            fwd, bwd = loops(fa, calls, args.rehearse, window)
             fwd_ms = ms_a_call(fwd, (q, k, v), calls, args.reps)
             bwd_ms = ms_a_call(bwd, (q, k, v, got[0], got[1], do), calls, args.reps)
             if not args.rehearse:
-                pairs = shape[0] * shape[1] * live_pairs(fa, shape[2])
+                pairs = shape[0] * shape[1] * live_pairs(fa, shape[2], window)
                 row.update(fwd_ms=fwd_ms, bwd_ms=bwd_ms,
                            fwd_us_a_pair=fwd_ms * 1e3 / pairs,
                            bwd_us_a_pair=bwd_ms * 1e3 / pairs)

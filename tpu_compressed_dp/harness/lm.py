@@ -52,6 +52,11 @@ PRESETS = {
     # Nemotron-3-Super, and the smoke size
     "nemotron3_super": hybrid.nemotron3_super_stage,
     "tiny_hybrid": hybrid.tiny_hybrid,
+    # the same decoder's gated full / sliding-window attention and SwiGLU
+    # expert layers: one pipeline stage's share of Laguna-XS.2, and the smoke
+    # size
+    "laguna_xs2": hybrid.laguna_xs2_stage,
+    "tiny_laguna": hybrid.tiny_laguna,
 }
 
 

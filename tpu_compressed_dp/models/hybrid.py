@@ -1,5 +1,7 @@
 """Hybrid decoder: a stack whose layers each hold ONE mixer, chosen by a
-per-layer pattern (the ``nemotron_h`` family, arXiv:2504.03624):
+per-layer pattern (the ``nemotron_h`` family, arXiv:2504.03624; the
+``laguna`` family, whose layer is two such entries, an attention sublayer and
+a feed-forward one):
 
   ``M``  a Mamba-2 mixer (:mod:`tpu_compressed_dp.ops.ssd`): input projection,
          causal depthwise convolution, the selective state-space recurrence as
@@ -10,7 +12,19 @@ per-layer pattern (the ``nemotron_h`` family, arXiv:2504.03624):
          experts, the top ``top_k`` normalised and scaled, the experts
          (squared-ReLU, ungated) computed in a latent narrower than the hidden
          state between two projections all tokens share, and a full-width
-         shared expert beside them.
+         shared expert beside them.  ``moe_latent`` 0 leaves the latent
+         out (the experts read and write the hidden state), ``moe_gated``
+         makes every expert and the shared one ``(silu(x Wg) * (x Wu)) Wd``,
+         ``router_bias`` False leaves the balancing bias out;
+  ``F``  causal attention over the whole sequence with ``full_heads`` query
+         heads: RMSNorm over each head of q and k, rotary embedding
+         (:class:`Rotary`: on the first ``dim`` channels of a head, YaRN
+         frequencies and a cos/sin factor if stated), a per-head sigmoid gate
+         from the sublayer's input on the kernel's output;
+  ``W``  the same with ``window_heads`` query heads, its own rotary, and a
+         sliding window: a query sees itself and the ``window - 1`` before it
+         (the banded flash kernels, :mod:`tpu_compressed_dp.ops.flash_attention`);
+  ``D``  a dense gated feed-forward, ``(silu(x Wg) * (x Wu)) Wd``.
 
 Layer ``l``: ``h = h + Mixer_l(RMSNorm_l(h))``; after the last the final norm
 and the untied head.  One multi-token-prediction module (DeepSeek-V3's form)
@@ -45,6 +59,8 @@ import functools
 import math
 from typing import Any, Dict, Optional, Tuple
 
+import numpy as np
+
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
@@ -59,12 +75,51 @@ from tpu_compressed_dp.ops.ssd import (causal_depthwise_conv, ssd_chunked_scan,
 
 Array = jax.Array
 
-__all__ = ["HybridConfig", "nemotron3_super_stage", "tiny_hybrid",
-           "init_hybrid", "hybrid_param_specs", "apply_hybrid", "hybrid_loss",
-           "route", "dispatch", "grouped_experts"]
+__all__ = ["HybridConfig", "Rotary", "nemotron3_super_stage", "tiny_hybrid",
+           "laguna_xs2_stage", "tiny_laguna", "init_hybrid",
+           "hybrid_param_specs", "apply_hybrid", "hybrid_loss", "route",
+           "dispatch", "grouped_experts", "gated_experts", "rotary_tables"]
 
 _F32 = jnp.float32
 _HIGHEST = jax.lax.Precision.HIGHEST
+
+
+@dataclasses.dataclass(frozen=True)
+class Rotary:
+    """One kind of attention layer's rotary embedding: the first ``dim``
+    channels of a head turn in the pairs ``(c, c + dim / 2)`` by
+    ``position * inv_c``, the rest pass untouched.  ``inv_c =
+    theta^(-2c / dim)``; with ``yarn_factor`` > 1 the YaRN blend of that and
+    ``inv_c / yarn_factor`` by a ramp between the channels that make
+    ``beta_fast`` and ``beta_slow`` turns in ``yarn_original`` positions.
+    ``attention_factor`` multiplies cos and sin."""
+    theta: float
+    dim: int
+    yarn_factor: float = 1.0
+    yarn_original: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+    def inv_freq(self) -> np.ndarray:
+        """[dim / 2] float32, from float64."""
+        half = self.dim // 2
+        inv = self.theta ** (-2.0 * np.arange(half, dtype=np.float64) / self.dim)
+        if self.yarn_factor > 1.0:
+            turns = lambda n: (self.dim * math.log(
+                self.yarn_original / (n * 2.0 * math.pi))) / (2.0 * math.log(self.theta))
+            low = max(math.floor(turns(self.beta_fast)), 0)
+            high = min(math.ceil(turns(self.beta_slow)), self.dim - 1)
+            ramp = np.clip((np.arange(half, dtype=np.float64) - low)
+                           / max(high - low, 1e-3), 0.0, 1.0)
+            inv = inv * (1.0 - ramp) + inv / self.yarn_factor * ramp
+        return inv.astype(np.float32)
+
+
+def rotary_tables(rot: Rotary, t: int) -> Tuple[Array, Array]:
+    """(cos, sin) [t, dim / 2] float32 of positions 0..t-1."""
+    ang = jnp.arange(t, dtype=_F32)[:, None] * jnp.asarray(rot.inv_freq())[None, :]
+    return rot.attention_factor * jnp.cos(ang), rot.attention_factor * jnp.sin(ang)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -93,7 +148,16 @@ class HybridConfig:
     n_kv_heads: int = 2
     n_kv_heads_held: int = 2
     head_dim: int = 128
-    # LatentMoE
+    # gated attention with rotary embedding (F over the whole sequence, W in a
+    # window), on the n_kv_heads_held key/value heads: every head is held
+    full_heads: int = 0
+    window_heads: int = 0
+    window: int = 0
+    rotary_full: Optional[Rotary] = None
+    rotary_window: Optional[Rotary] = None
+    # dense gated feed-forward (D)
+    dense_ffn: int = 0
+    # LatentMoE (moe_latent 0: no latent; moe_gated: SwiGLU experts)
     n_routed_experts: int = 512
     experts_held: int = 512
     first_expert: int = 0
@@ -102,16 +166,33 @@ class HybridConfig:
     moe_ffn: int = 2688
     shared_ffn: int = 5376
     routed_scale: float = 5.0
+    moe_gated: bool = False
+    router_bias: bool = True
     # multi-token prediction: the module's layers and its loss's weight
     mtp_pattern: str = "*E"
     mtp_loss_weight: float = 0.1
     dtype: Any = jnp.bfloat16
     init_std: float = 0.02
+    # the mixers' output projections start divided by sqrt(2 x published layers)
+    rescale_out_proj: bool = True
 
     def __post_init__(self):
-        if set(self.pattern + self.mtp_pattern) - set("M*E"):
+        kinds = set(self.pattern + self.mtp_pattern)
+        if kinds - set("M*EFWD"):
             raise ValueError(f"pattern {self.pattern!r}/{self.mtp_pattern!r}: "
-                             "a layer is M, * or E")
+                             "a layer is M, *, E, F, W or D")
+        for kind, heads, rot in (("F", self.full_heads, self.rotary_full),
+                                 ("W", self.window_heads, self.rotary_window)):
+            if kind in kinds and (
+                    rot is None or heads <= 0 or heads % self.n_kv_heads_held
+                    or rot.dim % 2 or not 0 < rot.dim <= self.head_dim):
+                raise ValueError(f"a {kind} layer needs its query heads (a "
+                                 "multiple of the key/value heads held) and "
+                                 "its rotary embedding")
+        if "W" in kinds and self.window <= 0:
+            raise ValueError("a W layer needs its window")
+        if "D" in kinds and self.dense_ffn <= 0:
+            raise ValueError("a D layer needs its width")
         per = self.mamba_heads // self.mamba_groups
         if (self.mamba_heads % self.mamba_groups
                 or self.mamba_heads_held != per * self.mamba_groups_held):
@@ -140,7 +221,7 @@ class HybridConfig:
 
     def init_aux(self) -> Dict[str, Array]:
         n_moe = (self.pattern + self.mtp_pattern).count("E")
-        return {"loss": jnp.zeros((2,), _F32),
+        return {"loss": jnp.zeros((2 if self.mtp_pattern else 1,), _F32),
                 "expert_rows": jnp.zeros((n_moe, self.experts_held), _F32),
                 "route_mass": jnp.zeros((n_moe,), _F32)}
 
@@ -151,7 +232,8 @@ class HybridConfig:
         return hybrid_loss(self, params, x, y)
 
     def aux_metrics(self, aux: Dict[str, Array]) -> Dict[str, Array]:
-        return {"loss/lm": aux["loss"][0], "loss/mtp": aux["loss"][1],
+        mtp = {"loss/mtp": aux["loss"][1]} if self.mtp_pattern else {}
+        return {"loss/lm": aux["loss"][0], **mtp,
                 "model/expert_rows": jnp.mean(aux["expert_rows"]),
                 "model/expert_rows_max": jnp.max(aux["expert_rows"]),
                 "model/route_mass": jnp.mean(aux["route_mass"])}
@@ -188,6 +270,44 @@ def tiny_hybrid(vocab: int = 256, dim: int = 64) -> HybridConfig:
         moe_latent=32, moe_ffn=48, shared_ffn=96)
 
 
+def laguna_xs2_stage() -> HybridConfig:
+    """poolside Laguna-XS.2 (huggingface.co/poolside/Laguna-XS.2 config.json,
+    33.4B-A3B): the first pipeline stage of eight (layers 0-4 of 40, with
+    embedding, final norm and head).  A layer is two entries: attention
+    (full, window, window, window, full: 48 / 64 query heads on 8 key/value
+    heads, every head held) and feed-forward (layer 0 dense, then top-8 of
+    256 SwiGLU experts with one shared expert: 32 held, one of an
+    expert-parallel group of 8), 1/8 of the vocabulary."""
+    return HybridConfig(
+        vocab_size=100352, vocab_held=12544, dim=2048, pattern="FDWEWEWEFE",
+        n_layers_published=40, norm_eps=1e-6, n_kv_heads=8, n_kv_heads_held=8,
+        head_dim=128, full_heads=48, window_heads=64, window=512,
+        rotary_full=Rotary(theta=500000.0, dim=64, yarn_factor=64.0,
+                           yarn_original=4096, beta_fast=64.0, beta_slow=1.0,
+                           attention_factor=1.4158883083359672),
+        rotary_window=Rotary(theta=10000.0, dim=128), dense_ffn=8192,
+        n_routed_experts=256, experts_held=32, top_k=8, moe_latent=0,
+        moe_ffn=512, shared_ffn=512, routed_scale=2.5, moe_gated=True,
+        router_bias=False, mtp_pattern="", rescale_out_proj=False)
+
+
+def tiny_laguna(vocab: int = 256, dim: int = 64) -> HybridConfig:
+    """Smoke/test scale of the ``laguna`` layer kinds: 2 full and 3 window
+    layers, 6 / 8 query heads on 2 key/value heads, a window of 16, top-4 of
+    16 experts, every expert held."""
+    return HybridConfig(
+        vocab_size=vocab, vocab_held=vocab, dim=dim, pattern="FDWEWEWEFE",
+        n_layers_published=5, norm_eps=1e-6, n_kv_heads=2, n_kv_heads_held=2,
+        head_dim=16, full_heads=6, window_heads=8, window=16,
+        rotary_full=Rotary(theta=500000.0, dim=8, yarn_factor=8.0,
+                           yarn_original=16, beta_fast=4.0, beta_slow=1.0,
+                           attention_factor=1.2),
+        rotary_window=Rotary(theta=10000.0, dim=16), dense_ffn=128,
+        n_routed_experts=16, experts_held=16, top_k=4, moe_latent=0,
+        moe_ffn=32, shared_ffn=32, routed_scale=2.5, moe_gated=True,
+        router_bias=False, mtp_pattern="", rescale_out_proj=False)
+
+
 # --------------------------------------------------------------- parameters
 
 def _layer_shapes(cfg: HybridConfig, kind: str) -> Dict[str, tuple]:
@@ -202,22 +322,41 @@ def _layer_shapes(cfg: HybridConfig, kind: str) -> Dict[str, tuple]:
         q, kv = cfg.n_heads_held * cfg.head_dim, cfg.n_kv_heads_held * cfg.head_dim
         return {"norm": (d,), "wq": (d, q), "wk": (d, kv), "wv": (d, kv),
                 "wo": (q, d)}
-    e, lat, f = cfg.experts_held, cfg.moe_latent, cfg.moe_ffn
-    return {"norm": (d,), "router": (d, cfg.n_routed_experts),
-            "e_bias": (cfg.n_routed_experts,),
-            "w_down_lat": (d, lat), "w_up_lat": (lat, d),
-            "w1": (e, lat, f), "w2": (e, f, lat),
-            "ws1": (d, cfg.shared_ffn), "ws2": (cfg.shared_ffn, d)}
+    if kind in ("F", "W"):
+        q = (cfg.full_heads if kind == "F" else cfg.window_heads) * cfg.head_dim
+        kv = cfg.n_kv_heads_held * cfg.head_dim
+        return {"norm": (d,), "wq": (d, q), "wk": (d, kv), "wv": (d, kv),
+                "q_norm": (cfg.head_dim,), "k_norm": (cfg.head_dim,),
+                "w_head_gate": (d, q // cfg.head_dim), "wo": (q, d)}
+    if kind == "D":
+        return {"norm": (d,), "w_gate": (d, cfg.dense_ffn),
+                "w_up": (d, cfg.dense_ffn), "w_down": (cfg.dense_ffn, d)}
+    e, lat, f, sh = cfg.experts_held, cfg.moe_latent, cfg.moe_ffn, cfg.shared_ffn
+    width = lat or d                      # what the experts read and write
+    shapes = {"norm": (d,), "router": (d, cfg.n_routed_experts)}
+    if cfg.router_bias:
+        shapes["e_bias"] = (cfg.n_routed_experts,)
+    if lat:
+        shapes.update(w_down_lat=(d, lat), w_up_lat=(lat, d))
+    if cfg.moe_gated:
+        shapes.update(wg=(e, width, f), wu=(e, width, f), wd=(e, f, width),
+                      ws_gate=(d, sh), ws_up=(d, sh), ws_down=(sh, d))
+    else:
+        shapes.update(w1=(e, width, f), w2=(e, f, width), ws1=(d, sh), ws2=(sh, d))
+    return shapes
 
 
 def hybrid_param_shapes(cfg: HybridConfig) -> Dict[str, Any]:
     d, v = cfg.dim, cfg.vocab_held
-    return {"embed": (v, d),
-            "layers": [_layer_shapes(cfg, k) for k in cfg.pattern],
-            "final_norm": (d,), "lm_head": (d, v),
-            "mtp": {"embed_norm": (d,), "hidden_norm": (d,), "w_eh": (2 * d, d),
-                    "layers": [_layer_shapes(cfg, k) for k in cfg.mtp_pattern],
-                    "final_norm": (d,)}}
+    shapes = {"embed": (v, d),
+              "layers": [_layer_shapes(cfg, k) for k in cfg.pattern],
+              "final_norm": (d,), "lm_head": (d, v)}
+    if cfg.mtp_pattern:
+        shapes["mtp"] = {
+            "embed_norm": (d,), "hidden_norm": (d,), "w_eh": (2 * d, d),
+            "layers": [_layer_shapes(cfg, k) for k in cfg.mtp_pattern],
+            "final_norm": (d,)}
+    return shapes
 
 
 #: the matrices that write a mixer's output to the residual stream
@@ -226,7 +365,8 @@ _OUT_PROJ = ("w_out", "wo", "w_up_lat", "ws2")
 
 def init_hybrid(cfg: HybridConfig, key: Array) -> Dict[str, Any]:
     """float32 masters: normal(0, init_std) matrices and embedding, the
-    mixers' output projections divided by sqrt(2 x published layers); the
+    mixers' output projections divided by sqrt(2 x published layers)
+    (``rescale_out_proj``); the
     recurrence's ``A`` log-uniform in [1, 16], its time steps log-uniform in
     [time_step_min, time_step_max] through the inverse softplus, ``D`` and
     the norm scales 1, the convolution as PyTorch's Conv1d starts, the
@@ -253,7 +393,7 @@ def init_hybrid(cfg: HybridConfig, key: Array) -> Dict[str, Any]:
             leaf = jax.random.uniform(k, shape, _F32, -bound, bound)
         else:
             leaf = jax.random.normal(k, shape, _F32) * cfg.init_std
-            if name in _OUT_PROJ:
+            if cfg.rescale_out_proj and name in _OUT_PROJ:
                 leaf = leaf / math.sqrt(2.0 * cfg.n_layers_published)
         out.append(leaf)
     return jax.tree.unflatten(treedef, out)
@@ -306,14 +446,55 @@ def _attention_mixer(cfg: HybridConfig, lp, x: Array) -> Array:
     return o.transpose(0, 2, 1, 3).reshape(bsz, t, -1) @ lp["wo"].astype(dt_)
 
 
+def _rotate(x: Array, cos: Array, sin: Array) -> Array:
+    """``x`` [B, T, H, D]: the first ``2 * cos.shape[-1]`` channels turned in
+    the pairs (c, c + half), in float32; the rest as they are."""
+    half = cos.shape[-1]
+    xf = x.astype(_F32)
+    x1, x2 = xf[..., :half], xf[..., half:2 * half]
+    cos, sin = cos[:, None, :], sin[:, None, :]
+    return jnp.concatenate(
+        [x1 * cos - x2 * sin, x2 * cos + x1 * sin, xf[..., 2 * half:]],
+        axis=-1).astype(x.dtype)
+
+
+def _gated_attention_mixer(cfg: HybridConfig, kind: str, lp, x: Array) -> Array:
+    """The ``laguna`` attention sublayer: per-head RMSNorm of q and k, rotary
+    embedding, grouped keys and values, the whole sequence (F) or a window
+    (W), and a sigmoid gate a head from the sublayer's input."""
+    dt_ = cfg.dtype
+    bsz, t, _ = x.shape
+    rot, window = ((cfg.rotary_full, None) if kind == "F"
+                   else (cfg.rotary_window, cfg.window))
+    heads = lambda w: (x @ lp[w].astype(dt_)).reshape(bsz, t, -1, cfg.head_dim)
+    cos, sin = rotary_tables(rot, t)
+    q = _rotate(_rms_norm(heads("wq"), lp["q_norm"], cfg.norm_eps), cos, sin)
+    k = _rotate(_rms_norm(heads("wk"), lp["k_norm"], cfg.norm_eps), cos, sin)
+    by_head = lambda y: y.transpose(0, 2, 1, 3)
+    with obs_trace.phase("attn" if window is None else "attn_window"):
+        o = ring_attention(by_head(q), by_head(k), by_head(heads("wv")),
+                           window=window)
+    gate = jax.nn.sigmoid((x @ lp["w_head_gate"].astype(dt_)).astype(_F32))
+    o = (by_head(o) * gate[..., None]).astype(dt_)
+    return o.reshape(bsz, t, -1) @ lp["wo"].astype(dt_)
+
+
+def _swiglu(x: Array, w_gate: Array, w_up: Array, w_down: Array, dtype) -> Array:
+    return (jax.nn.silu(x @ w_gate.astype(dtype)) * (x @ w_up.astype(dtype))
+            ) @ w_down.astype(dtype)
+
+
 def route(cfg: HybridConfig, lp, x: Array) -> Tuple[Array, Array]:
     """``x`` [N, D] -> (ids [N, top_k] of the chosen experts among all the
     routed ones, their weights [N, top_k] float32): sigmoid scores in
-    float32, the choice by score plus the balancing bias (a buffer: no
-    gradient), the weights the chosen scores normalised and scaled."""
+    float32, the choice by score plus the balancing bias where the layer has
+    one (a buffer: no gradient), the weights the chosen scores normalised and
+    scaled."""
     logits = jnp.dot(x.astype(_F32), lp["router"], precision=_HIGHEST)
-    _, idx = jax.lax.top_k(
-        jax.nn.sigmoid(logits) + jax.lax.stop_gradient(lp["e_bias"]), cfg.top_k)
+    scores = jax.nn.sigmoid(logits)
+    if "e_bias" in lp:
+        scores = scores + jax.lax.stop_gradient(lp["e_bias"])
+    _, idx = jax.lax.top_k(scores, cfg.top_k)
     # the chosen scores from the chosen LOGITS (the same function of the same
     # numbers as a gather from the scores): the backward then reads the ids
     # and top_k logits a token, and nothing over all the experts
@@ -374,24 +555,53 @@ def _rows(x: Array, tok: Array) -> Array:
 
 
 def _add_rows(x: Array, tok: Array, rows: Array) -> Array:
-    return x.at[tok].add(rows, mode="drop", indices_are_sorted=True,
-                         unique_indices=True)
+    return x.at[tok].add(rows.reshape((-1,) + x.shape[1:]), mode="drop",
+                         indices_are_sorted=True, unique_indices=True)
 
 
-def _compute_copies(w1, w2, dtype):
+# Rows wider than this are scatter-added as [width / ROW_WINDOW, ROW_WINDOW]
+# windows into an accumulator carried in that shape.  On the chip a tile of
+# 512 float32 rows of 2,048 added into [16384, 2048] takes 1,213 us, into
+# [16384, 16, 128] 66 us (a token's row is then whole (8, 128) tiles, 8 KB in
+# one piece, where in two dimensions it is one sublane of each of 16 tiles);
+# 1,024-wide rows into [8192, 1024] take 78 us as they are and keep that form
+# (PERF.md, PR 41).  Gathers of rows do not have the cliff (23-31 us either way).
+ROW_SCATTER_MAX = 1024
+ROW_WINDOW = 128
+
+
+def _accumulator_shape(shape: Tuple[int, int]) -> Tuple[int, ...]:
+    n, width = shape
+    if width > ROW_SCATTER_MAX and width % ROW_WINDOW == 0:
+        return (n, width // ROW_WINDOW, ROW_WINDOW)
+    return (n, width)
+
+
+def _compute_copies(ws, dtype):
     """The experts' weights in the compute type, made ONCE before the loop
     over tiles: without the barrier the compiler sinks the casts into the
     loop and converts an expert's 11 MB of float32 again for every tile."""
-    return jax.lax.optimization_barrier((w1.astype(dtype), w2.astype(dtype)))
+    return jax.lax.optimization_barrier(tuple(w.astype(dtype) for w in ws))
 
 
-def _expert_hidden(rows, w1e, dtype):
-    pre = jnp.dot(rows, w1e, preferred_element_type=_F32).astype(dtype)
-    r = jax.nn.relu(pre)
-    return r, r * r
+def _expert_hidden(rows, wb, e, dtype, gated: bool):
+    """One tile through an expert's first product(s): (what the backward
+    needs of it, the hidden rows).  Squared ReLU: ``relu(rows W1)``, its
+    square.  Gated: ``silu(rows Wg) * (rows Wu)`` and both pre-activations."""
+    pre = jnp.dot(rows, wb[0][e], preferred_element_type=_F32).astype(dtype)
+    if not gated:
+        r = jax.nn.relu(pre)
+        return r, r * r
+    up = jnp.dot(rows, wb[1][e], preferred_element_type=_F32).astype(dtype)
+    return (pre, up), jax.nn.silu(pre) * up
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _grouped(u: Array, ws: Tuple[Array, ...], wts: Array, order: Array,
+             counts: Array, tile: int, gated: bool) -> Array:
+    return _ge_fwd(u, ws, wts, order, counts, tile, gated)[0]
+
+
 def grouped_experts(u: Array, w1: Array, w2: Array, wts: Array, order: Array,
                     counts: Array, tile: int) -> Array:
     """``sum_e wts[n, e] relu(u_n W1_e)^2 W2_e`` over the held experts a token
@@ -400,61 +610,80 @@ def grouped_experts(u: Array, w1: Array, w2: Array, wts: Array, order: Array,
     gradients leave in float32); the routing as :func:`dispatch` gives it.
     A loop over the occupied tiles: a token's row is computed once for every
     held expert it chose, however many chose one expert."""
-    return _ge_fwd(u, w1, w2, wts, order, counts, tile)[0]
+    return _grouped(u, (w1, w2), wts, order, counts, tile, False)
 
 
-def _ge_fwd(u, w1, w2, wts, order, counts, tile):
+def gated_experts(u: Array, wg: Array, wu: Array, wd: Array, wts: Array,
+                  order: Array, counts: Array, tile: int) -> Array:
+    """:func:`grouped_experts` with ``(silu(u_n Wg_e) * (u_n Wu_e)) Wd_e`` an
+    expert: ``wg``, ``wu`` [E, width, F] and ``wd`` [E, F, width]."""
+    return _grouped(u, (wg, wu, wd), wts, order, counts, tile, True)
+
+
+def _ge_fwd(u, ws, wts, order, counts, tile, gated):
     n, dtype = u.shape[0], u.dtype
-    w1b, w2b = _compute_copies(w1, w2, dtype)
+    wb = _compute_copies(ws, dtype)
     layout, by_expert = _tile_layout(counts, tile), wts.T
 
     def body(t, acc):
         with obs_trace.phase("moe_dispatch"):
             e, tok = _tile_rows(t, layout, order, counts, tile, n)
             rw, rows = _rows(by_expert[e], tok), _rows(u, tok)
-        _, hidden = _expert_hidden(rows, w1b[e], dtype)
-        y = jnp.dot(hidden, w2b[e], preferred_element_type=_F32)
+        _, hidden = _expert_hidden(rows, wb, e, dtype, gated)
+        y = jnp.dot(hidden, wb[-1][e], preferred_element_type=_F32)
         with obs_trace.phase("moe_dispatch"):
             return _add_rows(acc, tok, rw[:, None] * y)
 
     acc = jax.lax.fori_loop(
         0, layout[0][-1], body,
-        varying_like(jnp.zeros(u.shape, _F32), u, w1, w2, wts, order))
-    return acc, (u, w1, w2, wts, order, counts)
+        varying_like(jnp.zeros(_accumulator_shape(u.shape), _F32), u, *ws, wts, order))
+    return acc.reshape(u.shape), (u, ws, wts, order, counts)
 
 
-def _ge_bwd(tile, res, g):
-    u, w1, w2, wts, order, counts = res
+def _ge_bwd(tile, gated, res, g):
+    u, ws, wts, order, counts = res
     n, dtype = u.shape[0], u.dtype
-    w1b, w2b = _compute_copies(w1, w2, dtype)
+    wb = _compute_copies(ws, dtype)
     layout, by_expert = _tile_layout(counts, tile), wts.T
+    add = lambda dw, e, a, b: dw.at[e].add(
+        jnp.dot(a.T, b, preferred_element_type=_F32))
+    back = lambda a, w: jnp.dot(a, w.T, preferred_element_type=_F32)
 
     def body(t, carry):
-        du, dw1, dw2, dwts = carry                 # dwts expert-major, [E, N]
+        du, dws, dwts = carry                      # dwts expert-major, [E, N]
         with obs_trace.phase("moe_dispatch"):
             e, tok = _tile_rows(t, layout, order, counts, tile, n)
             rw, rows, gy = _rows(by_expert[e], tok), _rows(u, tok), _rows(g, tok)
-        r, hidden = _expert_hidden(rows, w1b[e], dtype)
-        y = jnp.dot(hidden, w2b[e], preferred_element_type=_F32)
+        kept, hidden = _expert_hidden(rows, wb, e, dtype, gated)
+        y = jnp.dot(hidden, wb[-1][e], preferred_element_type=_F32)
         drw = jnp.sum(gy * y, axis=-1)             # 0 on the rows that do not exist
         dy = (gy * rw[:, None]).astype(dtype)
-        dw2 = dw2.at[e].add(jnp.dot(hidden.T, dy, preferred_element_type=_F32))
-        dh = jnp.dot(dy, w2b[e].T, preferred_element_type=_F32)
-        dpre = (2.0 * dh * r).astype(dtype)        # relu' is in r
-        dw1 = dw1.at[e].add(jnp.dot(rows.T, dpre, preferred_element_type=_F32))
-        drows = jnp.dot(dpre, w1b[e].T, preferred_element_type=_F32)
+        d_last = add(dws[-1], e, hidden, dy)
+        dh = back(dy, wb[-1][e])
+        if not gated:
+            dpre = (2.0 * dh * kept).astype(dtype)  # relu' is in r
+            dws = (add(dws[0], e, rows, dpre), d_last)
+            drows = back(dpre, wb[0][e])
+        else:
+            pre, up = (x.astype(_F32) for x in kept)
+            sig = jax.nn.sigmoid(pre)
+            dpre = (dh * up * sig * (1.0 + pre * (1.0 - sig))).astype(dtype)
+            dup = (dh * pre * sig).astype(dtype)
+            dws = (add(dws[0], e, rows, dpre), add(dws[1], e, rows, dup), d_last)
+            drows = back(dpre, wb[0][e]) + back(dup, wb[1][e])
         with obs_trace.phase("moe_dispatch"):
-            return (_add_rows(du, tok, drows), dw1, dw2,
+            return (_add_rows(du, tok, drows), dws,
                     dwts.at[e].set(_add_rows(dwts[e], tok, drw)))
 
-    zeros = (jnp.zeros(u.shape, _F32), jnp.zeros(w1.shape, _F32),
-             jnp.zeros(w2.shape, _F32), jnp.zeros(by_expert.shape, _F32))
-    du, dw1, dw2, dwts = jax.lax.fori_loop(
-        0, layout[0][-1], body, varying_like(zeros, u, w1, w2, wts, order, g))
-    return du.astype(dtype), dw1, dw2, dwts.T, None, None
+    zeros = (jnp.zeros(_accumulator_shape(u.shape), _F32),
+             tuple(jnp.zeros(w.shape, _F32) for w in ws),
+             jnp.zeros(by_expert.shape, _F32))
+    du, dws, dwts = jax.lax.fori_loop(
+        0, layout[0][-1], body, varying_like(zeros, u, *ws, wts, order, g))
+    return du.reshape(u.shape).astype(dtype), dws, dwts.T, None, None
 
 
-grouped_experts.defvjp(_ge_fwd, _ge_bwd)
+_grouped.defvjp(_ge_fwd, _ge_bwd)
 
 
 def _moe_mixer(cfg: HybridConfig, lp, x: Array) -> Tuple[Array, Dict[str, Array]]:
@@ -464,15 +693,24 @@ def _moe_mixer(cfg: HybridConfig, lp, x: Array) -> Tuple[Array, Dict[str, Array]
     with obs_trace.phase("moe_dispatch"):
         idx, w = route(cfg, lp, x2)
         wts, order, counts = dispatch(cfg, idx, w)
-    u = x2 @ lp["w_down_lat"].astype(dt_)
+    u = x2 @ lp["w_down_lat"].astype(dt_) if cfg.moe_latent else x2
     with obs_trace.phase("experts"):
         # kept across the layer's checkpoint: the backward's recomputation
         # of the layer does not run the loop over tiles again
-        routed = checkpoint_name(
-            grouped_experts(u, lp["w1"], lp["w2"], wts, order, counts,
-                            EXPERT_TILE), "routed")
-    out = routed.astype(dt_) @ lp["w_up_lat"].astype(dt_)
-    shared = jnp.square(jax.nn.relu(x2 @ lp["ws1"].astype(dt_))) @ lp["ws2"].astype(dt_)
+        if cfg.moe_gated:
+            routed = gated_experts(u, lp["wg"], lp["wu"], lp["wd"], wts, order,
+                                   counts, EXPERT_TILE)
+        else:
+            routed = grouped_experts(u, lp["w1"], lp["w2"], wts, order, counts,
+                                     EXPERT_TILE)
+        routed = checkpoint_name(routed, "routed")
+    out = routed.astype(dt_)
+    if cfg.moe_latent:
+        out = out @ lp["w_up_lat"].astype(dt_)
+    if cfg.moe_gated:
+        shared = _swiglu(x2, lp["ws_gate"], lp["ws_up"], lp["ws_down"], dt_)
+    else:
+        shared = jnp.square(jax.nn.relu(x2 @ lp["ws1"].astype(dt_))) @ lp["ws2"].astype(dt_)
     stats = {"rows": counts.astype(_F32),
              "mass": jnp.mean(jnp.sum(wts, axis=-1))}
     return (out + shared).reshape(bsz, t, d), stats
@@ -488,6 +726,12 @@ def _layer(cfg: HybridConfig, kind: str, lp, h: Array):
             return h + _mamba_mixer(cfg, lp, x), {}
     if kind == "*":
         return h + _attention_mixer(cfg, lp, x), {}
+    if kind in ("F", "W"):
+        return h + _gated_attention_mixer(cfg, kind, lp, x), {}
+    if kind == "D":
+        with obs_trace.phase("mlp"):
+            return h + _swiglu(x, lp["w_gate"], lp["w_up"], lp["w_down"],
+                               cfg.dtype), {}
     with obs_trace.phase("moe"):
         out, stats = _moe_mixer(cfg, lp, x)
         return h + out, stats
@@ -521,7 +765,7 @@ def apply_hybrid(cfg: HybridConfig, params, tokens: Array,
     to the right) the MTP module's final-normed hidden states too, stacked
     [2, B, T, D]: position i of the second predicts token i + 2.  The head is
     the loss's (:func:`hybrid_loss`), which never makes whole logits."""
-    if tokens.shape[1] % cfg.chunk:
+    if "M" in cfg.pattern + cfg.mtp_pattern and tokens.shape[1] % cfg.chunk:
         raise ValueError(f"{tokens.shape[1]} tokens are not a whole number of "
                          f"the scan's chunks of {cfg.chunk}")
     dt_ = cfg.dtype
@@ -529,7 +773,7 @@ def apply_hybrid(cfg: HybridConfig, params, tokens: Array,
     with obs_trace.phase("stack"):
         h, stats = _run_layers(cfg, cfg.pattern, params["layers"], embed[tokens])
         hf = _rms_norm(h, params["final_norm"], cfg.norm_eps)
-    if next_tokens is None:
+    if next_tokens is None or not cfg.mtp_pattern:
         return hf, stats
     mp = params["mtp"]
     with obs_trace.phase("mtp"):
@@ -546,16 +790,23 @@ def hybrid_loss(cfg: HybridConfig, params, x: Array, y: Array):
     """``(loss, loss, aux)`` for inputs ``x`` and next tokens ``y`` [B, T]:
     mean CE(trunk, y_i) + mtp_loss_weight x mean over the T - 1 positions that
     have one of CE(MTP, y_{i+1}), both through one fused head over the
-    stacked hidden states.  ``aux``: the two mean losses, and of every expert
+    stacked hidden states; without an MTP module (``mtp_pattern`` empty) the
+    first term alone.  ``aux``: the mean losses, and of every expert
     layer the rows each held expert received and the weight mass kept (with
     every expert held, ``routed_scale``)."""
     hs, stats = apply_hybrid(cfg, params, x, next_tokens=y)
-    ys = jnp.stack([y, jnp.roll(y, -1, axis=1)])
+    if cfg.mtp_pattern:
+        ys = jnp.stack([y, jnp.roll(y, -1, axis=1)])
+    else:                     # the trunk alone, through the same fused head
+        hs, ys = hs[None], y[None]
     with obs_trace.phase("head_xent"):
         nll = fused_head_xent_tokens(hs, params["lm_head"].astype(cfg.dtype), ys)
-    lm, mtp = jnp.mean(nll[0]), jnp.mean(nll[1][:, :-1])
-    loss = lm + cfg.mtp_loss_weight * mtp
-    aux = {"loss": jnp.stack([lm, mtp]),
+    loss = lm = jnp.mean(nll[0])
+    losses = [lm]
+    if cfg.mtp_pattern:
+        mtp = jnp.mean(nll[1][:, :-1])
+        loss, losses = lm + cfg.mtp_loss_weight * mtp, [lm, mtp]
+    aux = {"loss": jnp.stack(losses),
            "expert_rows": jnp.stack([s["rows"] for s in stats]),
            "route_mass": jnp.stack([s["mass"] for s in stats])}
     return loss, loss, aux

@@ -75,9 +75,14 @@ __all__ = ["PHASES", "LOOP_SPANS", "HOST_EVENT_KINDS", "phase", "chunk",
 #:             shared expert) and, in it, its router, choice, sort, gathers
 #:             and combine, and the grouped product of its experts; the
 #:             multi-token-prediction module
+#:   attn_window / mlp   the same decoder's ``laguna`` layer kinds: the
+#:             attention calls of a sliding-window layer (the banded
+#:             kernels; a full layer's stay under ``attn``), and a dense
+#:             gated feed-forward sublayer
 PHASES = ("grad", "ef", "compress", "route", "reduce", "return", "update",
           "ici_reduce", "recompress", "stack", "attn", "head_xent", "exit",
-          "ssm", "ssd", "moe", "moe_dispatch", "experts", "mtp")
+          "ssm", "ssd", "moe", "moe_dispatch", "experts", "mtp",
+          "attn_window", "mlp")
 
 
 def phase(name: str):

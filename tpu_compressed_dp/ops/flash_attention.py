@@ -1,4 +1,6 @@
-"""Tiled causal flash attention (forward + backward) in Pallas.
+"""Tiled causal flash attention (forward + backward) in Pallas, over the
+whole sequence or, with a ``window``, over the band a sliding-window layer
+sees.
 
 The single-block attention path of :mod:`tpu_compressed_dp.ops.ring_attention`
 — the unfused XLA chain materialises the [T, T] probability matrix in HBM
@@ -28,7 +30,22 @@ element (8 of 36 a head at T=4096, 32 of 528 at T=8192).  Bodies without the
 mask for the pairs under the diagonal were built and timed (PERF.md, PR 40):
 the 651 mask operations a 512 x 512 pair sit in VALU slots that are empty
 anyway, and two loops a kernel read 0.7 % slower to 0.7 % faster than one, so
-there is one loop.  The forward pair's pace is the cross-lane unit, which is
+there is one loop.
+
+The band (``window``, a static argument: a query sees itself and the
+``window - 1`` keys before it, ``i - window < j <= i``).  A pair wholly behind
+the band is never visited either: the forward's pair loop starts at the kv
+block of the q block's first row's oldest key, the backward's ends at the q
+block of the last row that still sees the kv block's last key (its DMA
+prefetch stops there too).  At T=8192 in blocks of 256 a window of 512 visits
+3 pairs a q block, 94 a head, where causal attention visits 528; every
+visited pair selects through the one mask, which then has both edges
+(``_causal_pos``).  With no window every bound and the mask are what they
+were, and so is the trace.  What stays resident for a whole head does not shrink with
+the band: K and V in the forward, the float32 dq accumulator in the backward
+still span T (a band needs only ``window + block`` of either; not written).
+
+The forward pair's pace is the cross-lane unit, which is
 why its running maximum and sum are kept lane-replicated in ``[blk_q, 128]``
 scratch: a ``[blk_q, 1]`` statistic has to be broadcast over the lanes again,
 an XLU round trip a row group, wherever it meets a block.
@@ -44,7 +61,8 @@ take a second 128-lane tile: the forward's packed output and the backward's
 packed cotangent are 256 lanes wide, float32, and the kernels that write and
 read them move twice the bytes of ``o`` and ``do`` for two lanes of stats.
 
-Layout: [B, H, T, D]; causal only (the framework's LM decoders); D padded to
+Layout: [B, H, T, D]; causal, whole or banded (the framework's LM decoders:
+no bidirectional or document-boundary mask); D padded to
 the 128-lane tile in the wrapper (zero columns are inert through qk/pv and
 sliced off).  Matmuls run on the MXU with fp32 accumulation
 (``preferred_element_type``); bf16 inputs keep bf16 operands — the same
@@ -74,12 +92,17 @@ def _vma(x: Array):
     return jax.typeof(x).vma
 
 
-def _causal_pos(qi, kj, blk_q, blk_k):
+def _causal_pos(qi, kj, blk_q, blk_k, window=None):
+    """Which elements of the (q block, kv block) pair a query sees: the keys
+    at or before it and, with a ``window``, no further back than the
+    ``window - 1`` before it (``i - window < j <= i``)."""
     q_pos = qi * blk_q + jax.lax.broadcasted_iota(
         jnp.int32, (blk_q, blk_k), 0)
     k_pos = kj * blk_k + jax.lax.broadcasted_iota(
         jnp.int32, (blk_q, blk_k), 1)
-    return q_pos >= k_pos
+    if window is None:
+        return q_pos >= k_pos
+    return (q_pos >= k_pos) & (q_pos - k_pos < window)
 
 
 def _lanes(x, n: int):
@@ -90,7 +113,7 @@ def _lanes(x, n: int):
 
 
 def _fwd_kernel(scale: float, blk_q: int, blk_k: int, n_k: int, d: int,
-                q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref):
+                q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, window=None):
     qi = pl.program_id(1)
     acc_ref[:] = jnp.zeros_like(acc_ref)
     m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
@@ -103,7 +126,7 @@ def _fwd_kernel(scale: float, blk_q: int, blk_k: int, n_k: int, d: int,
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale   # [blk_q, blk_k]
-        s = jnp.where(_causal_pos(qi, kj, blk_q, blk_k), s, _NEG_INF)
+        s = jnp.where(_causal_pos(qi, kj, blk_q, blk_k, window), s, _NEG_INF)
         # m and l are [blk_q, 128] with every lane of a row equal: a row
         # reduce's result leaves the cross-lane unit in every lane, so
         # widening it is no operation, and neither m under s nor corr over
@@ -124,9 +147,17 @@ def _fwd_kernel(scale: float, blk_q: int, blk_k: int, n_k: int, d: int,
         return 0
 
     # causal: q block qi attends kv blocks 0..ceil((qi+1)*blk_q / blk_k)-1;
-    # trailing blocks are fully masked — skipped entirely
+    # trailing blocks are fully masked — skipped entirely.  With a window the
+    # blocks wholly behind the band are skipped too: the block's first row
+    # sees back to key qi*blk_q - window + 1.  A row whose band starts after
+    # the first visited block takes that block's _NEG_INF as its maximum and
+    # counts its lanes as exp(0); the first real maximum (the diagonal pair
+    # is always visited, and last) multiplies all of that by exp(-1e30 - m),
+    # which is 0
     n_live = jnp.minimum(((qi + 1) * blk_q + blk_k - 1) // blk_k, n_k)
-    jax.lax.fori_loop(0, n_live, body, 0)
+    first = 0 if window is None else (
+        jnp.maximum(qi * blk_q - (window - 1), 0) // blk_k)
+    jax.lax.fori_loop(first, n_live, body, 0)
     l = l_ref[:]
     o = acc_ref[:] / _lanes(l, acc_ref.shape[1])     # [blk_q, d_pad]
     lse = (m_ref[:] + jnp.log(l))[:, :1]             # [blk_q, 1]
@@ -138,7 +169,7 @@ def _fwd_kernel(scale: float, blk_q: int, blk_k: int, n_k: int, d: int,
 
 
 def _bwd_block_math(scale, blk_q, blk_k, d, kj, qi, q, dop, k, v,
-                    dq_acc, dk_acc, dv_acc):
+                    dq_acc, dk_acc, dv_acc, window=None):
     """One (q block) x (kv block) pair of the backward: s, p, dP and ds are
     computed once and feed all three accumulators — shared by the
     VMEM-resident and the HBM-streamed stagings of the kernel."""
@@ -153,7 +184,7 @@ def _bwd_block_math(scale, blk_q, blk_k, d, kj, qi, q, dop, k, v,
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale
-    p = jnp.where(_causal_pos(qi, kj, blk_q, blk_k),
+    p = jnp.where(_causal_pos(qi, kj, blk_q, blk_k, window),
                   jnp.exp(s - lse), 0.0)
     dv_acc[:] += jax.lax.dot_general(
         p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
@@ -172,7 +203,7 @@ def _bwd_block_math(scale, blk_q, blk_k, d, kj, qi, q, dop, k, v,
 
 def _bwd_kernel(scale: float, blk_q: int, blk_k: int, n_q: int, d: int,
                 q_ref, k_ref, v_ref, dop_ref, dq_ref, dk_ref, dv_ref,
-                dq_acc, dk_acc, dv_acc, *stream):
+                dq_acc, dk_acc, dv_acc, *stream, window=None):
     """The whole backward of one head, one kv block a grid step.  dk/dv of
     the step's kv block accumulate over the q blocks at or below the
     diagonal; dq of the whole head accumulates in ``dq_acc`` [T, d_pad],
@@ -202,8 +233,12 @@ def _bwd_kernel(scale: float, blk_q: int, blk_k: int, n_q: int, d: int,
     k = k_ref[0]                                     # [blk_k, d_pad]
     v = v_ref[0]
     rows = lambda qi: pl.ds(qi * blk_q, blk_q)
-    # q blocks qi >= kj*blk_k // blk_q can contain positions >= this kv block
+    # q blocks qi >= kj*blk_k // blk_q can contain positions >= this kv block;
+    # with a window the last row that sees the block's last key is
+    # (kj+1)*blk_k - 1 + window - 1, and the q blocks past it are skipped
     first = kj * blk_k // blk_q
+    last = n_q if window is None else jnp.minimum(
+        ((kj + 1) * blk_k + window - 2) // blk_q + 1, n_q)
 
     if not stream:
         fetch = lambda qi: (q_ref[0, rows(qi)], dop_ref[0, rows(qi)])
@@ -222,7 +257,7 @@ def _bwd_kernel(scale: float, blk_q: int, blk_k: int, n_q: int, d: int,
             dma.start()
 
         def fetch(qi):
-            @pl.when(qi + 1 < n_q)
+            @pl.when(qi + 1 < last)
             def _():
                 for dma in dmas(qi + 1):
                     dma.start()
@@ -235,10 +270,10 @@ def _bwd_kernel(scale: float, blk_q: int, blk_k: int, n_q: int, d: int,
     def body(qi, _):
         q, dop = fetch(qi)
         _bwd_block_math(scale, blk_q, blk_k, d, kj, qi, q, dop, k, v,
-                        dq_acc, dk_acc, dv_acc)
+                        dq_acc, dk_acc, dv_acc, window)
         return 0
 
-    jax.lax.fori_loop(first, n_q, body, 0)
+    jax.lax.fori_loop(first, last, body, 0)
     dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
     dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
     dq_ref[0] = dq_acc[pl.ds(kj * blk_k, blk_k)].astype(dq_ref.dtype)
@@ -269,7 +304,7 @@ def _pad_lanes(x: Array, to: int) -> Array:
     return jnp.pad(x, ((0, 0),) * (x.ndim - 1) + ((0, to - x.shape[-1]),))
 
 
-def _fwd(q, k, v, scale, blk, interpret, d):
+def _fwd(q, k, v, scale, blk, interpret, d, window=None):
     """q/k/v pre-padded to d_pad lanes; returns packed o (lse at lane d)."""
     b, h, t, d_pad = q.shape
     bq, bk = blk
@@ -279,7 +314,7 @@ def _fwd(q, k, v, scale, blk, interpret, d):
     kv_spec = pl.BlockSpec((1, t, d_pad), lambda bh, qi: (bh, 0, 0),
                            memory_space=pltpu.VMEM)
     o_packed = pl.pallas_call(
-        functools.partial(_fwd_kernel, scale, bq, bk, t // bk, d),
+        functools.partial(_fwd_kernel, scale, bq, bk, t // bk, d, window=window),
         grid=(b * h, t // bq),
         in_specs=[
             pl.BlockSpec((1, bq, d_pad), lambda bh, qi: (bh, qi, 0),
@@ -302,7 +337,7 @@ def _fwd(q, k, v, scale, blk, interpret, d):
     return o_packed.reshape(b, h, t, ds)
 
 
-def _bwd(q, k, v, dop, scale, blk, interpret, out_dtype, d):
+def _bwd(q, k, v, dop, scale, blk, interpret, out_dtype, d, window=None):
     b, h, t, d_pad = q.shape
     bq, bk = blk
     vma = _vma(q)
@@ -334,7 +369,7 @@ def _bwd(q, k, v, dop, scale, blk, interpret, out_dtype, d):
     # the kv axis carries dq_acc from step to step: it must stay sequential
     # (Mosaic's default for an axis nobody declares parallel)
     dq, dk, dv = pl.pallas_call(
-        functools.partial(_bwd_kernel, scale, bq, bk, t // bq, d),
+        functools.partial(_bwd_kernel, scale, bq, bk, t // bq, d, window=window),
         grid=(b * h, t // bk),
         in_specs=[q_spec, kv_block, kv_block, dop_spec],
         out_specs=[kv_block] * 3,
@@ -352,28 +387,30 @@ def _bwd(q, k, v, dop, scale, blk, interpret, out_dtype, d):
     return rs(dq), rs(dk), rs(dv)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def flash_causal_attention(q: Array, k: Array, v: Array,
                            scale: Optional[float] = None,
-                           interpret: bool = False) -> Array:
+                           interpret: bool = False,
+                           window: Optional[int] = None) -> Array:
     """Exact causal attention, flash-tiled; [B, H, T, D] (equal q/kv heads —
-    GQA repeat happens in the caller, ring_attention)."""
-    o, _ = _fa_fwd(q, k, v, scale, interpret)
+    GQA repeat happens in the caller, ring_attention).  With ``window`` a
+    query sees itself and the ``window - 1`` keys before it."""
+    o, _ = _fa_fwd(q, k, v, scale, interpret, window)
     return o
 
 
-def _fa_fwd(q, k, v, scale, interpret):
+def _fa_fwd(q, k, v, scale, interpret, window=None):
     b, h, t, d = q.shape
     s = scale if scale is not None else 1.0 / (d ** 0.5)
     d_pad = d + (-d) % 128
     qp, kp, vp = (_pad_lanes(x, d_pad) for x in (q, k, v))
-    o_packed = _fwd(qp, kp, vp, s, _pick_blocks(t), interpret, d)
+    o_packed = _fwd(qp, kp, vp, s, _pick_blocks(t), interpret, d, window)
     o = o_packed[..., :d].astype(q.dtype)
     lse = o_packed[..., d]
     return o, (q, k, v, o, lse)
 
 
-def _fa_bwd(scale, interpret, res, do):
+def _fa_bwd(scale, interpret, res, do, window=None):
     q, k, v, o, lse = res
     b, h, t, d = q.shape
     s = scale if scale is not None else 1.0 / (d ** 0.5)
@@ -385,8 +422,10 @@ def _fa_bwd(scale, interpret, res, do):
                          lse[..., None]], axis=-1), ds)
     qp, kp, vp = (_pad_lanes(x, d_pad) for x in (q, k, v))
     dq, dk, dv = _bwd(qp, kp, vp, dop, s, _pick_blocks(t), interpret,
-                      q.dtype, d)
+                      q.dtype, d, window)
     return dq[..., :d], dk[..., :d], dv[..., :d]
 
 
-flash_causal_attention.defvjp(_fa_fwd, _fa_bwd)
+flash_causal_attention.defvjp(
+    _fa_fwd, lambda scale, interpret, window, res, do: _fa_bwd(
+        scale, interpret, res, do, window))

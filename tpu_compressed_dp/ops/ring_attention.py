@@ -65,7 +65,9 @@ def use_fused_attention(q_shape, k_shape, itemsize: int = 2) -> bool:
 
 def fused_attention_fits(q_shape, k_shape, itemsize: int = 2) -> bool:
     """Shapes the fused kernel takes: seq a lane multiple, head_dim
-    MXU-friendly, K/V small enough to stream through VMEM whole."""
+    MXU-friendly, K/V small enough to stream through VMEM whole.  The same
+    for a call with a window: the banded kernels skip the pairs outside the
+    band but keep the residency below (K, V and the dq accumulator whole)."""
     b, h, t, d = q_shape
     d_pad = d + (-d) % 128
     # What is resident for a whole head, and so grows with T.  Forward:
@@ -85,13 +87,14 @@ def fused_attention_fits(q_shape, k_shape, itemsize: int = 2) -> bool:
             and resident <= 4 * 1024 * 1024)
 
 
-def _fused_causal(q: Array, k: Array, v: Array, scale: float) -> Array:
+def _fused_causal(q: Array, k: Array, v: Array, scale: float,
+                  window: Optional[int] = None) -> Array:
     from tpu_compressed_dp.ops.flash_attention import flash_causal_attention
 
-    return flash_causal_attention(q, k, v, scale)
+    return flash_causal_attention(q, k, v, scale, False, window)
 
 
-def _block_attend(q, k, v, q_pos, k_pos, scale, o, m, l):
+def _block_attend(q, k, v, q_pos, k_pos, scale, o, m, l, window=None):
     """One online-softmax accumulation step against a K/V block.
 
     q: [B, H, Tq, D]; k/v: [B, H, Tk, D]; *_pos: [Tq]/[Tk] global positions.
@@ -99,6 +102,8 @@ def _block_attend(q, k, v, q_pos, k_pos, scale, o, m, l):
     """
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32) * scale
     causal = q_pos[:, None] >= k_pos[None, :]  # [Tq, Tk]
+    if window is not None:
+        causal &= q_pos[:, None] - k_pos[None, :] < window
     s = jnp.where(causal[None, None], s, _NEG_INF)
     m_new = jnp.maximum(m, jnp.max(s, axis=-1))
     # fully-masked rows keep m == -inf sentinel; exp(-inf - -inf) guarded to 0
@@ -118,6 +123,7 @@ def ring_attention(
     *,
     axis_name: Optional[str] = None,
     scale: Optional[float] = None,
+    window: Optional[int] = None,
 ) -> Array:
     """Causal attention; ``q/k/v``: [B, H, T_local, D] (local sequence block).
 
@@ -128,6 +134,11 @@ def ring_attention(
 
     GQA: pass K/V with fewer heads than Q as long as ``H_q % H_kv == 0``
     (heads are repeated locally — no extra wire traffic).
+
+    ``window``: a query sees itself and the ``window - 1`` keys before it
+    (sliding-window attention).  Single block only: the fused kernel visits
+    the block pairs that meet the band, the XLA chain masks the rest; the
+    ring path, whose blocks behind the band would still travel, refuses one.
     """
     if q.shape[1] != k.shape[1]:
         if q.shape[1] % k.shape[1]:
@@ -147,8 +158,11 @@ def ring_attention(
         # single-block case and must hit the same fused path
         ring = jax.lax.psum(1, axis_name)
         my = jax.lax.axis_index(axis_name)
+    if window is not None and ring != 1:
+        raise NotImplementedError("a window on the ring path is not written: "
+                                  "every block would still go round")
     if ring == 1 and use_fused_attention(q.shape, k.shape, q.dtype.itemsize):
-        return _fused_causal(q, k, v, scale)
+        return _fused_causal(q, k, v, scale, window)
 
     q_pos = my * t_local + jnp.arange(t_local)
     qf = q.astype(jnp.float32)
@@ -167,7 +181,7 @@ def ring_attention(
         src = (my - s) % ring if axis_name is not None else 0
         k_pos = src * t_local + jnp.arange(t_local)
         o, m, l = _block_attend(qf, kb.astype(jnp.float32), vb, q_pos, k_pos,
-                                scale, o, m, l)
+                                scale, o, m, l, window)
         if perm is not None:
             kb = jax.lax.ppermute(kb, axis_name, perm)
             vb = jax.lax.ppermute(vb, axis_name, perm)
