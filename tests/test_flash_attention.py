@@ -4,6 +4,8 @@ the kernel is the dispatched single-block attention path of the LM step, so
 a sign/transpose slip in the hand-written VJP would corrupt training
 gradients silently."""
 
+import importlib.util
+import json
 import os
 
 import numpy as np
@@ -198,6 +200,13 @@ def masked_softmax_attention(q, k, v, window):
         ((1, 1, 1024, 64), 384, (256, 128), True),
         ((1, 1, 512, 64), 1, (128, 128), False),     # the token itself alone
         ((1, 1, 512, 64), 4096, (128, 128), True),   # a band wider than the sequence: causal
+        # the shipped blocks of 512 over four q blocks (what T = 8,192 runs
+        # sixteen of): the window a whole block, two pairs a q block ...
+        ((1, 1, 2048, 64), 512, None, False),
+        ((1, 1, 2048, 64), 512, None, True),
+        # ... and half of one: the band's far edge cuts the pair behind the diagonal's
+        ((1, 1, 2048, 64), 256, None, False),
+        ((1, 1, 2048, 64), 256, None, True),
     ],
 )
 def test_banded_kernels_match_the_masked_softmax(monkeypatch, shape, window,
@@ -265,9 +274,10 @@ def _grad_of_sum(shape, dtype, window=None, **aval):
 
 
 def _pallas_calls(jaxpr):
+    """(name, grid) of every `pallas_call` under a jaxpr, in order."""
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "pallas_call":
-            yield eqn.params["name"]
+            yield eqn.params["name"], tuple(eqn.params["grid_mapping"].grid)
             continue
         for val in eqn.params.values():
             for sub in val if isinstance(val, (list, tuple)) else (val,):
@@ -281,7 +291,7 @@ def _pallas_calls(jaxpr):
     [
         ((1, 2, 256, 64), jnp.float32),
         ((1, 16, 4096, 128), jnp.bfloat16),   # the LM cell's attention call
-        ((1, 16, 8192, 128), jnp.bfloat16),   # the longest admitted sequence
+        ((1, 16, 8192, 128), jnp.bfloat16),   # the longest admitted sequence, in blocks of 512
     ],
 )
 def test_grad_is_one_forward_and_one_backward_kernel(shape, dtype):
@@ -291,8 +301,22 @@ def test_grad_is_one_forward_and_one_backward_kernel(shape, dtype):
     Traced only (shapes in, jaxpr out): nothing compiles or runs."""
     grad, x = _grad_of_sum(shape, dtype)
     jaxpr = jax.make_jaxpr(grad)(x, x, x)
-    assert list(_pallas_calls(jaxpr.jaxpr)) == [
+    assert [name for name, _ in _pallas_calls(jaxpr.jaxpr)] == [
         "flash_attn_fwd", "flash_attn_bwd"]
+
+
+@pytest.mark.parametrize("window", [None, 512])
+def test_the_longest_sequence_runs_in_blocks_of_512(window):
+    """One block rule for every length and for the band: at 8,192 tokens the
+    forward's q axis and the backward's kv axis are 16 grid steps a head, not
+    the 32 of blocks of 256 (a pair costs ~0.34 us forward and ~0.67 us
+    backward whatever its size, and blocks of 256 pay it four times as
+    often).  Traced only: it fails if the rule grows a branch on T again, and
+    says nothing of VMEM (the compile below does)."""
+    grad, x = _grad_of_sum((1, 2, 8192, 128), jnp.bfloat16, window)
+    jaxpr = jax.make_jaxpr(grad)(x, x, x)
+    assert list(_pallas_calls(jaxpr.jaxpr)) == [
+        ("flash_attn_fwd", (2, 16)), ("flash_attn_bwd", (2, 16))]
 
 
 @pytest.fixture(scope="module")
@@ -314,10 +338,10 @@ def one_chip():
     "shape, dtype, window",
     [
         ((1, 16, 4096, 128), jnp.bfloat16, None),   # the LM cell's attention call
-        ((1, 2, 8192, 128), jnp.bfloat16, None),    # longest T: dq accumulator 4 MB
+        ((1, 2, 8192, 128), jnp.bfloat16, None),    # longest T, blocks of 512: dq accumulator 4 MB
         ((1, 2, 4096, 256), jnp.bfloat16, None),    # widest head: 4 MB at 512-blocks
         ((1, 2, 4096, 128), jnp.float32, None),     # fp32 operands, 256-lane cotangent
-        ((1, 2, 8192, 128), jnp.bfloat16, 512),     # the Laguna cell's banded call
+        ((1, 2, 8192, 128), jnp.bfloat16, 512),     # the Laguna cell's banded call, blocks of 512
         ((1, 2, 4096, 128), jnp.bfloat16, 200),     # a band inside one 512-block
     ],
 )
@@ -331,3 +355,50 @@ def test_backward_compiles_for_v5e_at_admitted_extremes(one_chip, shape, dtype,
     grad, x = _grad_of_sum(shape, dtype, window, sharding=one_chip)
     compiled = jax.jit(grad).lower(x, x, x).compile()
     assert compiled.as_text().count("tpu_custom_call") == 2
+
+
+_spec = importlib.util.spec_from_file_location(
+    "flash_attn_bench", os.path.join(os.path.dirname(__file__), "..", "tools",
+                                     "flash_attn_bench.py"))
+flash_attn_bench = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(flash_attn_bench)
+
+
+@pytest.mark.parametrize("window", [None, 100])
+def test_the_bench_exact_answer_is_the_masked_softmax(window):
+    """`tools/flash_attn_bench.py` measures every side's distance from
+    `exact`: it has to be this file's plain form, the outputs and, for one
+    cotangent, the three gradients."""
+    shape = (2, 3, 256, 64)
+    ks = jax.random.split(jax.random.key(19), 4)
+    q, k, v, do = (jax.random.normal(kk, shape, jnp.float32) * 0.5 for kk in ks)
+    plain = lambda q, k, v: masked_softmax_attention(q, k, v, window or shape[2])
+    o, vjp = jax.vjp(plain, q, k, v)
+    got = flash_attn_bench.exact(q, k, v, do, window)
+    for a, b, nm in zip([got[0]] + got[2:], (o,) + vjp(do), ("o", "dq", "dk", "dv")):
+        np.testing.assert_allclose(a, np.asarray(b), atol=2e-6, err_msg=nm)
+    # over the heads the second argument holds: largest, root-mean-square
+    for n, gap in flash_attn_bench.gaps(got, [x[:, :2] + 0.5 for x in got]).items():
+        np.testing.assert_allclose(gap, [0.5, 0.5], rtol=1e-6, err_msg=n)
+
+
+def test_the_bench_tells_another_order_of_sums_from_another_answer(tmp_path):
+    """Two copies of the module whose blocks differ are not bit for bit, and
+    the rehearsal's rows say how far apart they are (`gap_to_first`) and that
+    each stands as far from the exact answer as the other (`gap_to_exact`):
+    what a hand-in of a block-shape change has to show."""
+    other = tmp_path / "flash_attention_256.py"
+    src = open(fa.__file__).read()
+    assert "bq = min(512, t)" in src
+    other.write_text(src.replace("bq = min(512, t)", "bq = min(256, t)"))
+    out = tmp_path / "rows.json"
+    rc = flash_attn_bench.main(["--rehearse", "--out", str(out),
+                                f"tree={fa.__file__}", f"b256={other}"])
+    rows = json.load(open(out))["rows"]
+    assert rc == 1 and len(rows) == 2 * len(flash_attn_bench.REHEARSAL_SHAPES)
+    for first, second in zip(rows[0::2], rows[1::2]):
+        assert (first["side"], second["side"]) == ("tree", "b256")
+        assert all(first["bitwise"].values()) and not all(second["bitwise"].values())
+        for n in ("o", "dq", "dk", "dv"):
+            (_, a), (_, b) = first["gap_to_exact"][n], second["gap_to_exact"][n]
+            assert 0 < second["gap_to_first"][n][1] < 2 * a and 0.5 * a < b < 2 * a, (n, a, b)

@@ -1,6 +1,7 @@
 #!/usr/bin/env python3
-"""The attention kernels alone, on the chip: ms a call and bit-for-bit outputs
-of several copies of ``ops/flash_attention.py`` in one process.
+"""The attention kernels alone, on the chip: ms a call, bit-for-bit outputs and
+each output's distance from the exact answer, for several copies of
+``ops/flash_attention.py`` in one process.
 
     git archive <parent commit> | tar -x -C bench_checkout/parent
     chiprun -- python3 tools/flash_attn_bench.py [label=path/to/flash_attention.py ...]
@@ -14,6 +15,14 @@ and older) sit it out, and the first side that takes one is its reference.
 A side is one file, loaded by path (the
 module imports nothing of its package), so a mechanism is timed alone by
 handing in a copy of the module that holds only it.
+
+Sides whose blocks differ sum in another order and cannot be bit for bit, so
+beside that verdict every row carries, per output, the largest and the
+root-mean-square difference from the first side (``gap_to_first``) and, over
+the first two heads, from a float32 masked softmax at ``highest`` precision
+and its gradients (``gap_to_exact``): two sides equally far from the exact
+answer compute the same thing.  The exit code is 1 unless every side is bit
+for bit the first.
 
 A forward is ``_fa_fwd`` (pad, kernel, slice); a backward is ``_fa_bwd``
 (``delta``, the lane packing, kernel, slices).  Each is timed as a jitted
@@ -52,6 +61,7 @@ SHAPES = [
 REHEARSAL_SHAPES = [((1, 2, 1024, 64), "bfloat16"), ((1, 1, 1024, 64), "float32"),
                     ((1, 2, 1024, 64), "bfloat16", 300)]
 NAMES = ("o", "lse", "dq", "dk", "dv")
+EXACT_HEADS = 2
 
 
 def load(label: str, path: str):
@@ -88,6 +98,42 @@ def outputs(fa, interpret, window=None):
         o, res = fa._fa_fwd(q, k, v, None, interpret, **band(window))
         return (o, res[4]) + tuple(fa._fa_bwd(None, interpret, res, do, **band(window)))
     return jax.jit(run)
+
+
+def exact(q, k, v, do, window=None):
+    """(o, lse, dq, dk, dv) of the plain form in float32 at ``highest``: the
+    whole [T, T] of a head with the diagonal and the band as one mask, a head
+    at a time (at 8,192 tokens a head's scores are 256 MB)."""
+    t, d = q.shape[-2:]
+
+    def head(q, k, v):
+        i, j = jnp.arange(t)[:, None], jnp.arange(t)[None, :]
+        seen = (j <= i) if window is None else (j <= i) & (i - j < window)
+        s = jnp.dot(q, k.T, precision="highest") / np.sqrt(d)
+        lse = jax.nn.logsumexp(jnp.where(seen, s, -jnp.inf), axis=-1)
+        p = jnp.where(seen, jnp.exp(s - lse[:, None]), 0.0)
+        return jnp.dot(p, v, precision="highest"), lse
+
+    @jax.jit
+    def one(q, k, v, do):
+        (o, lse), vjp = jax.vjp(head, q, k, v)
+        return (o, lse) + vjp((do, jnp.zeros_like(lse)))
+
+    b, h = q.shape[:2]
+    heads = [one(*(x[i, j].astype(jnp.float32) for x in (q, k, v, do)))
+             for i in range(b) for j in range(h)]
+    return [np.stack([np.asarray(hd[n]) for hd in heads]).reshape(
+        (b, h) + heads[0][n].shape) for n in range(len(NAMES))]
+
+
+def gaps(got, want) -> dict:
+    """{output: [largest, root-mean-square]} of the differences, over the
+    heads ``want`` holds."""
+    out = {}
+    for n, a, b in zip(NAMES, got, want):
+        diff = a[:, :b.shape[1]].astype(np.float64) - b
+        out[n] = [float(np.abs(diff).max()), float(np.sqrt(np.mean(diff ** 2)))]
+    return out
 
 
 def loops(fa, calls, interpret, window=None):
@@ -144,6 +190,7 @@ def main(argv=None) -> int:
         window = window[0] if window else None
         q, k, v, do = operands(shape, getattr(jnp, dtype))
         reference = None
+        truth = exact(*(x[:, :EXACT_HEADS] for x in (q, k, v, do)), window)
         for label, fa in modules:
             if window is not None and not takes_window(fa):
                 continue
@@ -152,7 +199,9 @@ def main(argv=None) -> int:
             reference = reference or host
             row = {"shape": list(shape), "dtype": dtype, "window": window, "side": label,
                    "bitwise": {n: bool(np.array_equal(a, b))
-                               for n, a, b in zip(NAMES, host, reference)}}
+                               for n, a, b in zip(NAMES, host, reference)},
+                   "gap_to_first": gaps(host, reference),
+                   "gap_to_exact": gaps(host, truth)}
             fwd, bwd = loops(fa, calls, args.rehearse, window)
             fwd_ms = ms_a_call(fwd, (q, k, v), calls, args.reps)
             bwd_ms = ms_a_call(bwd, (q, k, v, got[0], got[1], do), calls, args.reps)

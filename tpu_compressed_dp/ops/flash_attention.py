@@ -26,7 +26,7 @@ dq and one for dk/dv would each recompute s and dP: 7, and the chain twice).
 Which pairs are masked: a (q block, kv block) pair wholly above the diagonal
 is never visited; every visited pair builds ``_causal_pos`` and selects
 through it, though only the pairs that straddle the diagonal have a masked
-element (8 of 36 a head at T=4096, 32 of 528 at T=8192).  Bodies without the
+element (8 of 36 a head at T=4096, 16 of 136 at T=8192).  Bodies without the
 mask for the pairs under the diagonal were built and timed (PERF.md, PR 40):
 the 651 mask operations a 512 x 512 pair sit in VALU slots that are empty
 anyway, and two loops a kernel read 0.7 % slower to 0.7 % faster than one, so
@@ -37,8 +37,8 @@ The band (``window``, a static argument: a query sees itself and the
 the band is never visited either: the forward's pair loop starts at the kv
 block of the q block's first row's oldest key, the backward's ends at the q
 block of the last row that still sees the kv block's last key (its DMA
-prefetch stops there too).  At T=8192 in blocks of 256 a window of 512 visits
-3 pairs a q block, 94 a head, where causal attention visits 528; every
+prefetch stops there too).  At T=8192 in blocks of 512 a window of 512 visits
+2 pairs a q block, 31 a head, where causal attention visits 136; every
 visited pair selects through the one mask, which then has both edges
 (``_causal_pos``).  With no window every bound and the mask are what they
 were, and so is the trace.  What stays resident for a whole head does not shrink with
@@ -280,13 +280,18 @@ def _bwd_kernel(scale: float, blk_q: int, blk_k: int, n_q: int, d: int,
 
 
 def _pick_blocks(t: int) -> tuple:
-    # smaller streamed blocks at long T: the operands resident for the whole
-    # head (K + V in the forward; the float32 dq accumulator in the backward)
-    # grow with T — halving the block buffers and the [blk, blk] score
-    # temporaries buys the margin under the 16 MB scoped-vmem ceiling (r5;
-    # grid-step overhead is amortised by the larger per-step loop trip count
-    # at these T)
-    bq = min(256 if t >= 8192 else 512, t)
+    # One rule for every length, banded or not: 512 x 512, halved until it
+    # divides T.  A pair costs ~0.34 us forward and ~0.67 us backward whatever
+    # its size (PERF.md, PR 40), so nothing smaller is taken while 512 fits:
+    # at T=8192 a head is 136 pairs, 31 in a band of 512 (2 a q block), where
+    # blocks of 256 visit 528 and 94.  What the compile for a v5e reports at
+    # the admitted extreme, (b, h, 8192, 128) in bf16, of the 16 MB scoped-VMEM
+    # ceiling: forward 10.00 MB (K and V whole and double-buffered 8, the
+    # packed output's two blocks 1, accumulator and statistics 0.75, q 0.25),
+    # backward 7.00 MB (the dq accumulator 4, the streamed cotangent's two
+    # blocks 1 and q's 0.25, dk/dv accumulators 0.5, k/v in and dq/dk/dv out
+    # 1.25).
+    bq = min(512, t)
     while t % bq:
         bq //= 2
     return bq, bq

@@ -77,9 +77,12 @@ def fused_attention_fits(q_shape, k_shape, itemsize: int = 2) -> bool:
     # (equal at bf16, half at fp32) and single-buffered — beside per-block
     # K/V and the streamed q/do blocks; its full-T operands stay in HBM.
     # Both must fit the TPU's ~16 MB scoped-vmem ceiling.  Cap the
-    # single-buffered K+V set at 4 MB (= 8 MB doubled + block buffers,
-    # comfortably under 16 MB): admits the chip-verified T=8192 at d=128
-    # exactly; T=16384 (8 MB single, ~18+ doubled) would hit the scoped-vmem
+    # single-buffered K+V set at 4 MB (= 8 MB doubled + block buffers):
+    # admits the chip-verified T=8192 at d=128 exactly, where the kernels run
+    # in blocks of 512 like every shorter call (136 pairs a head, 31 in a
+    # band of 512: 2 a q block) and the compile for a v5e reports 10.00 MB
+    # forward and 7.00 MB backward (flash_attention._pick_blocks has the
+    # sums); T=16384 (8 MB single, ~18+ doubled) would hit the scoped-vmem
     # wall — long-context's designed path is the seq-axis ring sharding
     # T_local below this gate.
     resident = t * 2 * d_pad * itemsize   # K + V at input dtype
