@@ -402,3 +402,55 @@ def test_the_bench_tells_another_order_of_sums_from_another_answer(tmp_path):
         for n in ("o", "dq", "dk", "dv"):
             (_, a), (_, b) = first["gap_to_exact"][n], second["gap_to_exact"][n]
             assert 0 < second["gap_to_first"][n][1] < 2 * a and 0.5 * a < b < 2 * a, (n, a, b)
+
+
+@pytest.mark.parametrize(
+    "shape, window, fused, streamed",
+    [
+        ((1, 2, 512, 64), None, True, False),    # one block, two maps
+        ((1, 1, 1024, 64), 200, True, False),    # the band's edge inside a pair
+        ((1, 1, 1024, 64), 512, True, True),     # as the chip stages it
+        ((2, 2, 256, 64), None, False, False),   # the XLA chain, whole ...
+        ((1, 2, 256, 64), 100, False, False),    # ... and in a band
+        ((1, 1, 256, 32), None, False, False),   # keys of 32 under values of 64
+    ],
+)
+def test_values_twice_as_wide_as_the_keys(monkeypatch, shape, window, fused,
+                                          streamed):
+    """q and k of a head's width on v of twice that (a differential-attention
+    pair's values), through ``ring_attention`` whichever way it routes the
+    call: o, dq, dk and dv against the masked softmax at the head's own scale.
+    The kernels see q and k zero-padded to v's width; the gradients come back
+    in the operands' own widths."""
+    if fused:
+        monkeypatch.setattr(ra_mod, "use_fused_attention", lambda *a: True)
+        monkeypatch.setattr(
+            ra_mod, "_fused_causal",
+            lambda q, k, v, scale, window=None: flash_causal_attention(
+                q, k, v, scale, True, window))
+    if streamed:
+        monkeypatch.setenv("TPU_CDP_FORCE_STREAMED_DKV", "1")
+    b, h, t, d = shape
+    ks = jax.random.split(jax.random.key(23), 4)
+    q, k = (jax.random.normal(kk, shape, jnp.float32) * 0.5 for kk in ks[:2])
+    v, tgt = (jax.random.normal(kk, (b, h, t, 2 * d), jnp.float32) * 0.5
+              for kk in ks[2:])
+    lf = lambda q, k, v: jnp.mean(
+        (ra_mod.ring_attention(q, k, v, window=window) - tgt) ** 2)
+    le = lambda q, k, v: jnp.mean(
+        (masked_softmax_attention(q, k, v, window or t) - tgt) ** 2)
+    got = ra_mod.ring_attention(q, k, v, window=window)
+    assert got.shape == v.shape
+    np.testing.assert_allclose(np.asarray(got), np.asarray(
+        masked_softmax_attention(q, k, v, window or t)), atol=1e-5)
+    for a, e, nm in zip(jax.grad(lf, (0, 1, 2))(q, k, v),
+                        jax.grad(le, (0, 1, 2))(q, k, v), "qkv"):
+        assert a.shape == e.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(e), atol=1e-5,
+                                   err_msg=f"d{nm}")
+
+
+def test_values_narrower_than_the_keys_are_refused():
+    q = jnp.zeros((1, 1, 128, 64))
+    with pytest.raises(ValueError, match="wider than the keys"):
+        ra_mod.ring_attention(q, q, q[..., :32])

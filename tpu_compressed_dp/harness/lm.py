@@ -25,7 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from tpu_compressed_dp.data import lm as lm_data
-from tpu_compressed_dp.models import hybrid, transformer as tf
+from tpu_compressed_dp.models import hybrid, sambay, transformer as tf
 from tpu_compressed_dp.parallel.dp import CompressionConfig
 from tpu_compressed_dp.parallel.mesh import setup_compile_cache
 from tpu_compressed_dp.train.lm_step import (
@@ -57,7 +57,15 @@ PRESETS = {
     # size
     "laguna_xs2": hybrid.laguna_xs2_stage,
     "tiny_laguna": hybrid.tiny_laguna,
+    # a decoder-hybrid-decoder (models/sambay.py: Mamba-1, differential
+    # attention, Gated Memory Units and cross-attention on earlier layers'
+    # tensors): the hand-over stage of Phi-4-mini-flash-reasoning, and the
+    # smoke size
+    "phi4_mini_flash": sambay.phi4_mini_flash_stage,
+    "tiny_phi4flash": sambay.tiny_phi4flash,
 }
+
+_PATTERNED = (hybrid.HybridConfig, sambay.SambaYConfig)
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -172,7 +180,7 @@ def build_config(args):
     import dataclasses
 
     cfg = PRESETS[args.preset]()
-    if isinstance(cfg, hybrid.HybridConfig):
+    if isinstance(cfg, _PATTERNED):
         given = [n for n in ("layers", "heads", "kv_heads", "ffn", "experts")
                  if getattr(args, n) is not None]
         if given:
@@ -215,7 +223,7 @@ def run(args) -> Dict[str, float]:
         mesh = make_lm_mesh(dp, args.sp, args.tp)
     cfg = build_config(args)
     cfg.validate_mesh(args.tp)
-    if isinstance(cfg, hybrid.HybridConfig) and (pipelined or args.sp > 1
+    if isinstance(cfg, _PATTERNED) and (pipelined or args.sp > 1
                                                  or args.corpus):
         raise ValueError("a hybrid preset runs on the data axis, on synthetic "
                          "tokens: no --pp, --sp or --corpus")
@@ -567,7 +575,7 @@ def run(args) -> Dict[str, float]:
                         # the 12 L d s term
                         passes = getattr(cfg, "n_passes", 1)
                         attn_layers = (cfg.n_layers if hasattr(cfg, "n_layers")
-                                       else (cfg.pattern + cfg.mtp_pattern).count("*"))
+                                       else (cfg.pattern + getattr(cfg, "mtp_pattern", "")).count("*"))
                         tok_flops = flops_mod.transformer_train_flops_per_token(
                             n_params * passes, attn_layers * passes, cfg.dim,
                             args.seq_len)

@@ -158,6 +158,14 @@ declare("model/expert_rows_max", GAUGE, "rows", "mean", "step",
 declare("model/route_mass", GAUGE, "weight", "mean", "step",
         "a token's routed weights on held experts, summed; mean over tokens "
         "and expert layers (every expert held: the routed scaling factor)")
+# a decoder-hybrid-decoder's differential attention and handed-on memory
+# (models/sambay.py)
+declare("model/diff_lambda", GAUGE, "weight", "mean", "step",
+        "the second softmax's weight lam in differential attention, mean "
+        "over the attention layers")
+declare("model/memory_rms", GAUGE, "rms", "mean", "step",
+        "root mean square of a Mamba-1 layer's scan output m (what the Gated "
+        "Memory Units read), mean over the Mamba-1 layers")
 declare("guard/loss_scale", GAUGE, "scale", "mean", "step",
         "live dynamic loss scale (replicated)")
 declare("guard/skipped", COUNTER, "steps", "max", "step",

@@ -79,10 +79,15 @@ __all__ = ["PHASES", "LOOP_SPANS", "HOST_EVENT_KINDS", "phase", "chunk",
 #:             attention calls of a sliding-window layer (the banded
 #:             kernels; a full layer's stay under ``attn``), and a dense
 #:             gated feed-forward sublayer
+#:   gmu / attn_cross   a decoder-hybrid-decoder's (models/sambay.py, whose
+#:             Mamba-1 mixer is under ``ssm`` and its convolution and
+#:             selective scan under ``ssd``): a Gated Memory Unit on an
+#:             earlier layer's scan output, and the attention calls of a
+#:             cross layer on an earlier layer's keys and values
 PHASES = ("grad", "ef", "compress", "route", "reduce", "return", "update",
           "ici_reduce", "recompress", "stack", "attn", "head_xent", "exit",
           "ssm", "ssd", "moe", "moe_dispatch", "experts", "mtp",
-          "attn_window", "mlp")
+          "attn_window", "mlp", "gmu", "attn_cross")
 
 
 def phase(name: str):

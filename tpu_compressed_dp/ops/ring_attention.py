@@ -138,6 +138,14 @@ def ring_attention(
     GQA: pass K/V with fewer heads than Q as long as ``H_q % H_kv == 0``
     (heads are repeated locally — no extra wire traffic).
 
+    Head widths: ``q`` and ``k`` share one, and ``scale`` defaults to its
+    inverse root; ``v`` may be WIDER (a differential-attention pair's values
+    are two heads side by side: q, k of 64 on v of 128).  ``q`` and ``k`` are
+    then zero-padded to ``v``'s width (zero columns are inert in ``q k^T``)
+    and the call is one of that width: the flash kernels pad every head to
+    128 lanes anyway, so at 64 on 128 nothing is added to what they move,
+    and the score product does 128 columns where 64 are data.
+
     ``window``: a query sees itself and the ``window - 1`` keys before it
     (sliding-window attention).  Single block only: the fused kernel visits
     the block pairs that meet the band, the XLA chain masks the rest; the
@@ -152,6 +160,12 @@ def ring_attention(
     t_local = q.shape[2]
     d = q.shape[3]
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
+    if v.shape[3] != d:
+        if v.shape[3] < d:
+            raise ValueError(f"values of {v.shape[3]} under keys of {d}: the "
+                             "values may be wider than the keys, not narrower")
+        widen = ((0, 0),) * 3 + ((0, v.shape[3] - d),)
+        q, k, d = jnp.pad(q, widen), jnp.pad(k, widen), v.shape[3]
 
     if axis_name is None:
         ring, my = 1, 0

@@ -15,6 +15,14 @@ decays, their cumulative sums and the carried state are float32 whatever the
 operands' type; the backward is reverse-mode differentiation of the same
 einsums, so a layer under ``jax.checkpoint`` keeps none of the ``[L, L]``
 blocks.
+
+All of this rests on ONE scalar decay a head (``exp(dt_t A)`` with ``A`` [H]):
+a chunk's decays are then an ``[L, L]`` matrix and the recurrence is matrix
+products.  Mamba-1's decay is one number for every channel and state index
+(``A`` [channels, state]), for which that form does not exist: its scan,
+carried element by element with a backward of its own, is
+:mod:`tpu_compressed_dp.ops.selective_scan`, which shares this module's
+convolution.
 """
 
 from __future__ import annotations
@@ -29,13 +37,17 @@ __all__ = ["causal_depthwise_conv", "ssd_chunked_scan", "ssd_sequential_scan",
 
 
 def varying_like(x, *refs: Array):
-    """``x`` (a pytree of replicated loop-carry initialisers) marked as
-    varying over the mesh axes any of ``refs`` varies on: inside
-    ``shard_map`` a loop's carry must enter with the type its body returns."""
-    vma = tuple(sorted(set().union(*(jax.typeof(r).vma for r in refs))))
-    if not vma:
-        return x
-    return jax.tree.map(lambda v: jax.lax.pcast(v, vma, to="varying"), x)
+    """``x`` (a pytree of loop-carry initialisers) marked as varying over the
+    mesh axes any of ``refs`` varies on: inside ``shard_map`` a loop's carry
+    must enter with the type its body returns.  A leaf that varies on some of
+    them already (a state or a cotangent handed in) gets the rest."""
+    want = set().union(*(jax.typeof(r).vma for r in refs))
+
+    def one(v):
+        missing = tuple(sorted(want - set(jax.typeof(v).vma)))
+        return jax.lax.pcast(v, missing, to="varying") if missing else v
+
+    return jax.tree.map(one, x)
 
 
 def causal_depthwise_conv(x: Array, w: Array, b: Array) -> Array:
