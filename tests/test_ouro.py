@@ -306,12 +306,239 @@ def test_fused_head_with_per_token_weights_matches_the_unfused_path(chunk):
         float(tf.vocab_parallel_xent(h @ w, y)), rel=1e-6)
 
 
-def test_the_looped_step_runs_sharded_over_seq_and_tensor(params, two_steps):
+def head_inputs(n, d, v, dtype, seed=7):
+    """Seeded rows for the head: a target on the last id, weights with zeros."""
+    ks = jax.random.split(jax.random.key(seed), 4)
+    h = (jax.random.normal(ks[0], (n, d), jnp.float32) * 0.5).astype(dtype)
+    w = (jax.random.normal(ks[1], (d, v), jnp.float32) * 0.2).astype(dtype)
+    y = jax.random.randint(ks[2], (n,), 0, v).at[0].set(v - 1).at[n - 1].set(v - 1)
+    wts = jax.random.uniform(ks[3], (n,)).at[1::5].set(0.0)
+    return h, w, y, wts
+
+
+def wsum_heads():
+    """{name: (h, w, y, wts) -> (3 sum(wts nll), nll)}: the weighted-sum entry,
+    the per-token entry and the unfused chain in float32, under a scalar
+    cotangent that is not 1."""
+    def per_token(nll_of):
+        def f(h, w, y, wts):
+            nll = nll_of(h, w, y)
+            return 3.0 * jnp.sum(wts * nll), nll
+        return f
+
+    def wsum(h, w, y, wts):
+        s, nll = tf.fused_head_xent_wsum(h, w, y, wts)
+        return 3.0 * s, nll
+
+    return {"wsum": wsum,
+            "tokens": per_token(tf.fused_head_xent_tokens),
+            "unfused": per_token(lambda h, w, y: tf.vocab_parallel_xent_tokens(
+                h.astype(jnp.float32) @ w.astype(jnp.float32), y))}
+
+
+def value_and_grads(f, h, w, y, wts):
+    (s, nll), grads = jax.value_and_grad(
+        lambda h, w, wts: f(h, w, y, wts), (0, 1, 2), has_aux=True)(h, w, wts)
+    return [np.asarray(a.astype(jnp.float32)) for a in (s, nll) + grads]
+
+
+# rows, vocabulary, (rows a block, rows a sub-block) or None for the module's
+# own 1,024 over 512
+WSUM_CASES = {
+    "one_block_of_all_rows": (12, 50, None),
+    "two_sub_blocks_whole": (16, 96, (16, 8)),
+    "rows_not_whole_blocks": (45, 50, (16, 8)),
+    "one_padded_block": (13, 130, (16, 8)),
+    "the_modules_own_blocks_padded": (1100, 50, None),
+}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", sorted(WSUM_CASES))
+def test_the_weighted_sum_head_matches_the_per_token_head_and_the_unfused_chain(
+        case, dtype, monkeypatch):
+    """Both outputs and dh, dW, d weights of 3 sum(w nll): the entry whose
+    forward makes the gradients against the entry that recomputes the logits
+    and against whole logits; rows that are not whole blocks, a vocabulary
+    that is no multiple of 128, a target on the last id, weights with zeros."""
+    n, v, blocks = WSUM_CASES[case]
+    if blocks:
+        monkeypatch.setattr(tf, "_FHW_ROWS", blocks[0])
+        monkeypatch.setattr(tf, "_FHW_SUB", blocks[1])
+    args = head_inputs(n, 16, v, jnp.dtype(dtype))
+    got, tokens, unfused = (value_and_grads(f, *args)
+                            for f in wsum_heads().values())
+    assert got[2].dtype == got[3].dtype == np.float32
+    names = ("value", "nll", "dh", "dw", "dweights")
+    # float32: the tolerances of the per-token entry's own tests; bfloat16:
+    # two roundings of dz and of the results, of the largest entry
+    rel, atol = (1e-5, 2e-5) if dtype == "float32" else (1e-2, None)
+    for name, a, b, c in zip(names, got, tokens, unfused):
+        for other, want in (("tokens", b), ("unfused", c)):
+            tol = atol if atol else 2.0 ** -6 * float(np.max(np.abs(want)))
+            if name in ("value", "nll", "dweights"):
+                np.testing.assert_allclose(a, want, rtol=rel, atol=tol,
+                                           err_msg=f"{name} against {other}")
+            else:
+                np.testing.assert_allclose(a, want, atol=tol,
+                                           err_msg=f"{name} against {other}")
+    assert np.all(got[2][1::5] == 0)        # a weight of zero: no dh
+    # the primal alone returns the same two
+    s, nll = tf.fused_head_xent_wsum(*args)
+    assert 3.0 * float(s) == pytest.approx(float(got[0]), rel=1e-6)
+    np.testing.assert_array_equal(np.asarray(nll), got[1])
+
+
+def test_the_weighted_sum_head_over_a_vocabulary_in_two_shards():
+    """A 2-way ``tensor`` axis: v = 50 in shards of 25; dh of the replicated
+    hidden states comes back summed over the shards, dW in shards."""
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    from tpu_compressed_dp.parallel.mesh import make_mesh
+
+    h, w, y, wts = head_inputs(24, 16, 50, jnp.float32)
+    y = y.at[2].set(25).at[3].set(24)        # the shards' edge, either side
+    mesh = make_mesh((2,), ("tensor",))
+
+    def local(h, w, y, wts):
+        (s, nll), grads = jax.value_and_grad(
+            lambda h, w, wts: tf.fused_head_xent_wsum(h, w, y, 3.0 * wts,
+                                                      "tensor"),
+            (0, 1, 2), has_aux=True)(h, w, wts)
+        return (s, nll) + grads
+
+    got = shard_map(local, mesh=mesh,
+                    in_specs=(P(), P(None, "tensor"), P(), P()),
+                    out_specs=(P(), P(), P(), P(None, "tensor"), P()))(h, w, y, wts)
+    want = value_and_grads(wsum_heads()["unfused"], h, w, y, wts)
+    for name, a, b in zip(("value", "nll", "dh", "dw", "dweights"), got, want):
+        np.testing.assert_allclose(np.asarray(a), b, rtol=1e-5, atol=2e-5,
+                                   err_msg=name)
+
+
+def head_products(jaxpr, scope="", times=1):
+    """Multiply-adds of the ``dot_general``s of a jaxpr, loops unrolled:
+    ``(all, those under tcdp.head_xent)``."""
+    total = inside = 0
+    for eqn in jaxpr.eqns:
+        stack = scope + "/" + str(eqn.source_info.name_stack)
+        n = times * eqn.params.get("length", 1) if eqn.primitive.name == "scan" else times
+        if eqn.primitive.name == "dot_general":
+            (ca, _), (ba, _) = eqn.params["dimension_numbers"]
+            a, out = eqn.invars[0].aval, eqn.outvars[0].aval
+            work = times * int(np.prod(out.shape)) * int(
+                np.prod([a.shape[i] for i in ca]))
+            total += work
+            inside += work if "tcdp.head_xent" in stack else 0
+        for sub in jax.tree.leaves(list(eqn.params.values()),
+                                   is_leaf=lambda x: hasattr(x, "eqns")
+                                   or hasattr(x, "jaxpr")):
+            sub = getattr(sub, "jaxpr", sub)
+            if hasattr(sub, "eqns"):
+                a, b = head_products(sub, stack, n)
+                total, inside = total + a, inside + b
+    return total, inside
+
+
+def test_the_weighted_sum_head_makes_one_product_alone_and_three_differentiated():
+    """Counted in the jaxpr: without differentiation the logits once; under
+    ``grad`` the logits, dh and dW; the per-token entry the logits twice."""
+    n, d, v = 40, 16, 48
+    h, w, y, wts = head_inputs(n, d, v, jnp.float32)
+    one = n * d * v
+    primal = jax.make_jaxpr(
+        lambda h, w, wts: tf.fused_head_xent_wsum(h, w, y, wts))(h, w, wts)
+    assert str(primal).count("dot_general") == 1
+    assert head_products(primal.jaxpr)[0] == one
+
+    def grads(head):
+        return jax.make_jaxpr(jax.grad(
+            lambda h, w, wts: head(h, w, y, wts)[0], (0, 1, 2)))(h, w, wts)
+
+    heads = wsum_heads()
+    assert head_products(grads(heads["wsum"]).jaxpr)[0] == 3 * one
+    assert head_products(grads(heads["tokens"]).jaxpr)[0] == 4 * one
+
+
+@pytest.fixture
+def fused_step(monkeypatch):
+    """The tiny step through its fused branch, which its sizes would not take."""
+    monkeypatch.setattr(lm_step, "use_fused_head_xent", lambda *a, **k: True)
+
+
+def parents_head(h, w, ys, weights, tensor_axis):
+    """The head as the step called it before: the per-token entry, its
+    cotangent the weights."""
+    nll = tf.fused_head_xent_tokens(h, w, ys, tensor_axis)
+    return jnp.sum(weights * nll), nll
+
+
+def test_the_fused_looped_step_follows_the_reference_and_the_per_token_head(
+        params, two_steps, fused_step, monkeypatch):
+    """Loss, per-pass losses, exit masses and every leaf's first gradient of
+    the exit-gated step through the weighted-sum head: against the reference
+    and the unfused step to their tests' tolerances, and against the same
+    step with the per-token head in the new entry's place."""
+    data = batches(2)
+    got = program_steps(LC, params, data)
+    monkeypatch.setattr(lm_step, "fused_head_xent_wsum", parents_head)
+    parent = program_steps(LC, params, data)
+    names = [jax.tree_util.keystr(p) for p, _ in
+             jax.tree_util.tree_flatten_with_path(params)[0]]
+    for step in range(2):
+        metrics, momentum, aux, new_params = got[step]
+        loss, ref_aux, _, ref_momentum, ref_params = two_steps[1][step]
+        assert metrics["loss"] == pytest.approx(loss, rel=2e-5)
+        for kept, ref in zip(aux, ref_aux):
+            np.testing.assert_allclose(kept, ref, rtol=2e-5, atol=2e-6)
+        assert ouro.model_numbers(aux, ref_aux, CFG, {})["pass_loss_gap"] < 2e-5
+        assert ouro.model_numbers(aux, ref_aux, CFG, {})["exit_mass_gap"] < 2e-6
+        for other, (o_metrics, o_momentum, o_aux, _) in (
+                ("unfused", two_steps[0][step]), ("per-token", parent[step])):
+            assert metrics == pytest.approx(o_metrics, rel=2e-5, abs=2e-6), other
+            for name, a, b, ref in zip(names, momentum, o_momentum, ref_momentum):
+                scale = max(float(np.max(np.abs(ref))), 1e-6)
+                np.testing.assert_allclose(a, b, atol=3e-5 * scale,
+                                           err_msg=f"{name} against {other}")
+                np.testing.assert_allclose(a, ref, atol=3e-5 * scale, err_msg=name)
+        for name, a, ref in zip(names, new_params, ref_params):
+            np.testing.assert_allclose(a, ref, atol=5e-6, err_msg=name)
+
+
+def test_the_fused_looped_step_multiplies_by_the_head_three_times_where_four_stood(
+        params, fused_step, monkeypatch):
+    """Engagement, static: under ``tcdp.head_xent`` the differentiated loss
+    holds three products of [rows of every pass, width, vocabulary], and four
+    with the per-token head in the new entry's place."""
+    x, y = (jnp.asarray(a) for a in batches(1)[0])
+    one = LC.n_passes * x.size * LC.dim * LC.vocab_size
+
+    def head_work():
+        from jax import shard_map
+        from jax.sharding import PartitionSpec as P
+
+        mesh = lm_step.make_lm_mesh(1, 1, 1)
+        loss = shard_map(
+            lambda p, x, y: lm_step.llama_loss(LC, p, x, y, 1)[0], mesh=mesh,
+            in_specs=(tf.param_specs(LC), P(), P()), out_specs=P(),
+            check_vma=False)
+        return head_products(jax.make_jaxpr(jax.grad(loss))(params, x, y).jaxpr)[1]
+
+    assert head_work() == 3 * one
+    monkeypatch.setattr(lm_step, "fused_head_xent_wsum", parents_head)
+    assert head_work() == 4 * one
+
+
+@pytest.mark.parametrize("fused", [False, True])
+def test_the_looped_step_runs_sharded_over_seq_and_tensor(params, two_steps, fused,
+                                                          monkeypatch):
     """dp 2 x sp 2 x tp 2 on the 8 virtual devices: the same loss and per-pass
     numbers as the reference (the vocab-parallel per-token losses, the ring
-    attention and the gate all see shards)."""
-    from jax.sharding import NamedSharding
-
+    attention and the gate all see shards), through whole logits and through
+    the weighted-sum head over its vocabulary shards."""
+    if fused:
+        monkeypatch.setattr(lm_step, "use_fused_head_xent", lambda *a, **k: True)
     mesh = lm_step.make_lm_mesh(2, 2, 2)
     opt = SGD(lr=OPT["lr"], momentum=OPT["momentum"],
               weight_decay=OPT["weight_decay"])

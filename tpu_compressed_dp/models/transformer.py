@@ -17,6 +17,17 @@ sigmoid gate after every pass, from which :func:`exit_weighted_loss` makes
 the exit distribution that weights the passes' cross-entropies).  One pass
 with neither is the plain decoder, bit for bit.
 
+The fused head (the LM head's product and the softmax cross-entropy without
+the [N, V] logits in HBM) has two entries.  A loss that is a weighted sum of
+token cross-entropies whose weights exist before the head runs (the looped
+model's: its exit distribution comes from the gate, not from the head)
+takes :func:`fused_head_xent_wsum`: blocked over rows, its forward makes
+``dh`` and ``dW`` from the one set of logits, three products of [N, D, V] a
+step.  Any other caller (a per-token cotangent that is known only in the
+backward: the mean over tokens behind further arithmetic, a second loss on
+the same tokens) takes :func:`fused_head_xent_tokens`: blocked over the
+vocabulary, it recomputes each chunk's logits in the backward, four products.
+
 Parallelism design (TPU-first, megatron-style over a named mesh):
   * ``tensor`` axis — attention heads and MLP hidden are column-sharded, the
     output projections row-sharded (one ``psum`` each per layer); the LM head
@@ -54,13 +65,15 @@ from jax.sharding import PartitionSpec as P
 
 from tpu_compressed_dp.obs import trace as obs_trace
 from tpu_compressed_dp.ops.ring_attention import ring_attention
+from tpu_compressed_dp.ops.ssd import varying_like
 
 Array = jax.Array
 
 __all__ = ["LlamaConfig", "llama3_8b", "ouro_2p6b", "tiny_llama", "init_llama",
            "param_specs", "apply_llama", "vocab_parallel_xent",
            "vocab_parallel_xent_tokens",
-           "fused_head_xent", "fused_head_xent_tokens", "exit_log_probs",
+           "fused_head_xent", "fused_head_xent_tokens", "fused_head_xent_wsum",
+           "exit_log_probs", "exit_distribution", "exit_stats",
            "exit_weighted_loss"]
 
 
@@ -479,22 +492,36 @@ def exit_log_probs(gate: Array) -> Array:
             + jnp.concatenate([jax.nn.log_sigmoid(gate[:-1]), zero], axis=0))
 
 
+def exit_distribution(gate: Array):
+    """``(p, plogp)`` of gate logits ``gate`` [R, ...] (float32): the exit
+    distribution ``p`` [R, ...] of :func:`exit_log_probs` and each token's
+    ``sum_r p_r log p_r`` [...], its entropy's negative."""
+    log_p = exit_log_probs(gate)
+    p = jnp.exp(log_p)
+    return p, jnp.sum(p * log_p, axis=0)
+
+
+def exit_stats(nll: Array, p: Array, plogp: Array) -> Dict[str, Array]:
+    """What the looped step reports and keeps: every pass's mean
+    cross-entropy, mean exit mass, and the mean entropy."""
+    tokens = tuple(range(1, nll.ndim))
+    return {"pass_loss": jnp.mean(nll, axis=tokens),
+            "exit_mass": jnp.mean(p, axis=tokens),
+            "exit_entropy": -jnp.mean(plogp)}
+
+
 def exit_weighted_loss(nll: Array, gate: Array, beta: float):
     """The looped model's loss from its passes' per-token cross-entropies
     ``nll`` [R, ...] and gate logits ``gate`` [R, ...] (float32): per token
     ``sum_r p_r nll_r + beta sum_r p_r log p_r`` with ``p`` of
     :func:`exit_log_probs`, the expected loss less ``beta`` times the exit
     distribution's entropy; the mean over tokens.  Returns ``(loss,
-    stats)``, the stats being what the step reports and keeps: every pass's
-    mean cross-entropy, mean exit mass, and the mean entropy."""
-    log_p = exit_log_probs(gate)
-    p = jnp.exp(log_p)
-    plogp = jnp.sum(p * log_p, axis=0)
-    tokens = tuple(range(1, nll.ndim))
-    stats = {"pass_loss": jnp.mean(nll, axis=tokens),
-             "exit_mass": jnp.mean(p, axis=tokens),
-             "exit_entropy": -jnp.mean(plogp)}
-    return jnp.mean(jnp.sum(p * nll, axis=0) + beta * plogp), stats
+    stats)`` with :func:`exit_stats`.  The fused step takes the same loss
+    apart, ``sum(p / tokens * nll) + beta mean(plogp)``, the first term from
+    :func:`fused_head_xent_wsum`."""
+    p, plogp = exit_distribution(gate)
+    return (jnp.mean(jnp.sum(p * nll, axis=0) + beta * plogp),
+            exit_stats(nll, p, plogp))
 
 
 # Fused head+xent defaults by SHAPE (r5).  Measured on chip:
@@ -556,6 +583,12 @@ def fused_head_xent_tokens(h: Array, w: Array, targets: Array,
     psum structure); the hand-written VJP recomputes each chunk's logits in
     the backward (flash-attention discipline: trade one extra matmul pass
     for the activation storage).
+
+    The entry for ANY cotangent: a chunk's probabilities need the row's
+    whole logsumexp, known after the last chunk, so the chunks are walked
+    twice and the head costs four products of [N, D, V].  A caller whose
+    loss is ``sum(weights * nll)`` with weights that do not depend on the
+    head takes :func:`fused_head_xent_wsum`, which costs three.
     """
     nll, _ = _fhx_fwd(h, w, targets, tensor_axis, chunk)
     return nll
@@ -566,6 +599,18 @@ def fused_head_xent(h: Array, w: Array, targets: Array,
                     chunk: int = 2048) -> Array:
     """Mean of :func:`fused_head_xent_tokens` over the tokens."""
     return jnp.mean(fused_head_xent_tokens(h, w, targets, tensor_axis, chunk))
+
+
+def _match_vma(ct: Array, primal: Array) -> Array:
+    """A cotangent's varying mesh axes must match its primal's: wherever the
+    primal is REPLICATED over an axis the computation varies on (h across
+    the vocab-sharded tensor axis; lm_head across pipeline stages), the
+    true cotangent is the SUM of the per-shard partials.  The unfused path
+    gets these psums inserted automatically as transposes of the implicit
+    pvary where replicated values meet varying operands; a custom VJP must
+    place them by hand."""
+    extra = tuple(sorted(jax.typeof(ct).vma - jax.typeof(primal).vma))
+    return jax.lax.psum(ct, extra) if extra else ct
 
 
 def _fhx_scan_stats(h2, w, targets1, off, v_local, c, nc):
@@ -683,26 +728,158 @@ def _fhx_bwd(tensor_axis, chunk, res, g):
     dh, dw_stack = jax.lax.scan(body, dh0, (w3, jnp.arange(nc)))
     dw = dw_stack.transpose(1, 0, 2).reshape(d, v_pad)[:, :v_local]
 
-    # A cotangent's varying-mesh-axes must match its primal's: wherever the
-    # primal is REPLICATED over an axis the computation varies on (h across
-    # the vocab-sharded tensor axis; lm_head across pipeline stages), the
-    # true cotangent is the SUM of the per-shard partials.  The unfused path
-    # gets these psums inserted automatically as transposes of the implicit
-    # pvary where replicated values meet varying operands; a custom VJP must
-    # place them by hand.
-    def match_vma(ct, primal):
-        extra = tuple(sorted(jax.typeof(ct).vma
-                             - jax.typeof(primal).vma))
-        return jax.lax.psum(ct, extra) if extra else ct
-
-    dh = match_vma(dh, h)
-    dw = match_vma(dw, w)
+    dh = _match_vma(dh, h)
+    dw = _match_vma(dw, w)
     dt_ct = np.zeros(targets1.shape, dtype=jax.dtypes.float0)
     return (dh.reshape(h.shape).astype(h.dtype), dw.astype(w.dtype),
             dt_ct.reshape(targets.shape))
 
 
 fused_head_xent_tokens.defvjp(_fhx_fwd, _fhx_bwd)
+
+
+# The weighted-sum entry's row blocks: ``dz``, ``dh`` and ``dW += h^T dz``
+# over _FHW_ROWS rows, the logits over sub-blocks of _FHW_SUB rows.  At
+# 2,048 wide and a vocabulary of 49,152 a sub-block's float32 logits are 96
+# MiB, which the TPU compiler keeps in its fast memory (whole blocks of 1,024
+# rows do not fit: 69.5 ms a call of the head alone where this shape takes
+# 59.6), and the ``dW`` product runs at 256 FLOP a byte (the chip's ridge is
+# 240); blocks of 2,048 rows take 1.8 ms less and put 59 MB on the step's
+# peak (measured on a v5e, PR 45).
+_FHW_ROWS = 1024
+_FHW_SUB = 512
+
+
+def _fhw_blocks(n: int):
+    """(rows of a sub-block, sub-blocks a block, blocks) for ``n`` rows."""
+    sub = min(_FHW_SUB, n)
+    per = min(_FHW_ROWS // _FHW_SUB, -(-n // sub))
+    return sub, per, -(-n // (sub * per))
+
+
+def _fhw_rows(h, w, targets, weights, tensor_axis, with_grads):
+    """The row-blocked head: ``nll`` shaped like ``targets`` and,
+    ``with_grads``, the gradients of ``sum(weights * nll)``: ``dh`` like
+    ``h`` and ``dW`` [D, V_local] float32 (both per vocabulary shard)."""
+    d = h.shape[-1]
+    v_local = w.shape[-1]
+    h2 = h.reshape(-1, d)
+    n = h2.shape[0]
+    sub, per, nb = _fhw_blocks(n)
+    pad = nb * per * sub - n
+    off = (jax.lax.axis_index(tensor_axis) * v_local
+           if tensor_axis is not None else 0)
+    # a target of another shard reads no logit here and no onehot: -1
+    lt = targets.reshape(-1) - off
+    lt = jnp.where((lt >= 0) & (lt < v_local), lt, -1)
+    blocks = tuple(jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1),
+                           constant_values=c)
+                   .reshape((nb, per, sub) + a.shape[1:])
+                   for a, c in ((h2, 0), (lt, -1), (weights.reshape(-1), 0)))
+
+    def sub_block(_, xs):
+        h_s, lt_s, wt_s = xs
+        z = jax.lax.dot_general(
+            h_s, w, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)          # [sub, V_local]
+        onehot = jnp.arange(v_local)[None, :] == lt_s[:, None]
+        m = jnp.max(z, axis=-1)
+        zt = jnp.sum(jnp.where(onehot, z, 0.0), axis=-1)
+        if tensor_axis is not None:
+            m = jax.lax.pmax(m, tensor_axis)
+            zt = jax.lax.psum(zt, tensor_axis)
+        l = jnp.sum(jnp.exp(z - m[:, None]), axis=-1)
+        lse = m + jnp.log(_psum_if(l, tensor_axis))
+        nll = lse - zt
+        if not with_grads:
+            return None, nll
+        dz = ((jnp.exp(z - lse[:, None]) - onehot.astype(jnp.float32))
+              * wt_s[:, None]).astype(w.dtype)
+        return None, (nll, dz)
+
+    if not with_grads:
+        _, nll = jax.lax.scan(
+            sub_block, None, tuple(a.reshape((-1,) + a.shape[2:])
+                                   for a in blocks))
+        return nll.reshape(-1)[:n].reshape(targets.shape)
+
+    def block(dw, xs):
+        # dz has one reader inside the sub-blocks' loop, its place in the
+        # stack: with the dh product there it was written twice
+        _, (nll, dz) = jax.lax.scan(sub_block, None, xs)
+        dz = dz.reshape(-1, v_local)                      # [rows, V_local]
+        dh = jax.lax.dot_general(
+            dz, w, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dw_b = jax.lax.dot_general(
+            xs[0].reshape(-1, d), dz, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)           # [D, V_local]
+        return (dw_b if dw is None else dw + dw_b), (nll, dh.astype(h.dtype))
+
+    # the first block's product starts the sum: a carry of zeros is 4 D V
+    # bytes that the compiler allocates before the stack has run
+    dw, (nll, dh) = block(None, tuple(a[0] for a in blocks))
+    nll, dh = nll[None], dh[None]
+    if nb > 1:
+        dw, (nll_r, dh_r) = jax.lax.scan(block, dw,
+                                         tuple(a[1:] for a in blocks))
+        nll, dh = jnp.concatenate([nll, nll_r]), jnp.concatenate([dh, dh_r])
+    return (nll.reshape(-1)[:n].reshape(targets.shape),
+            dh.reshape(-1, d)[:n].reshape(h.shape), dw)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def fused_head_xent_wsum(h: Array, w: Array, targets: Array, weights: Array,
+                         tensor_axis: Optional[str] = None):
+    """``(sum(weights * nll), nll)`` of the per-token cross-entropies ``nll``
+    that :func:`fused_head_xent_tokens` returns, for a loss that is a
+    weighted sum of them with weights known before the head runs (the looped
+    model's exit distribution over its tokens).  ``h`` [..., D], ``w`` [D,
+    V_local], ``targets`` [...], ``weights`` [...] float32.
+
+    Blocked over ROWS where that entry is blocked over the vocabulary: a
+    block's logits are whole, so its logsumexp is exact at once and ``dz``,
+    ``dh`` and the block's share of ``dW`` follow from the one product.
+    Differentiated, the forward rule makes the gradients (three products of
+    [N, D, V] where the other entry's two passes over the chunks take four)
+    and keeps ``dh``, ``dW`` and ``nll``, not ``h`` or ``w``; the backward
+    scales them by the first output's scalar cotangent.  The same float32
+    logits, bfloat16 ``dz`` and float32 accumulations as the other entry;
+    the order of the sums differs.  Called without differentiation it
+    computes ``nll`` alone, one product.
+
+    The SECOND output carries no gradient: the rule ignores its cotangent.
+    Read it under ``stop_gradient`` (a statistic, as the looped model's
+    ``pass_loss``)."""
+    nll = _fhw_rows(h, w, targets, weights, tensor_axis, False)
+    return jnp.sum(weights * nll), nll
+
+
+def _fhw_fwd(h, w, targets, weights, tensor_axis):
+    nll, dh, dw = _fhw_rows(h, w, targets, weights, tensor_axis, True)
+    # the backward needs of the primals only where each varies: empty stubs
+    stubs = tuple(varying_like(jnp.zeros((0,), a.dtype), a)
+                  for a in (h, w, weights))
+    # dW leaves in w's dtype together with dh: the stack's backward waits for
+    # dh, so the float32 sum is not what lives through it to the update
+    dh, dw = jax.lax.optimization_barrier((dh, dw.astype(w.dtype)))
+    return (jnp.sum(weights * nll), nll), (dh, dw, nll, stubs)
+
+
+def _fhw_bwd(tensor_axis, res, cts):
+    import numpy as np
+
+    dh, dw, nll, primals = res
+    g = cts[0]
+    # scaled first, summed over shards after: the cotangent may vary where
+    # the primal does not
+    dh, dw, dweights = (
+        _match_vma(ct.astype(jnp.float32) * g, primal).astype(primal.dtype)
+        for ct, primal in zip((dh, dw, nll), primals))
+    return dh, dw, np.zeros(nll.shape, jax.dtypes.float0), dweights
+
+
+fused_head_xent_wsum.defvjp(_fhw_fwd, _fhw_bwd)
 
 
 def vocab_parallel_xent_tokens(

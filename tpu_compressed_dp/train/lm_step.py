@@ -36,9 +36,11 @@ from jax.sharding import Mesh, PartitionSpec as P
 from tpu_compressed_dp.models.transformer import (
     LlamaConfig,
     apply_llama,
+    exit_distribution,
+    exit_stats,
     exit_weighted_loss,
     fused_head_xent,
-    fused_head_xent_tokens,
+    fused_head_xent_wsum,
     use_fused_head_xent,
     vocab_parallel_xent,
     vocab_parallel_xent_tokens,
@@ -104,14 +106,23 @@ def llama_loss(cfg: LlamaConfig, params, x: Array, y: Array, tensor_size: int):
             cfg, params, x, tensor_axis="tensor", seq_axis="seq",
             with_aux=True, return_hidden=fused, all_passes=True)
         ys = jnp.broadcast_to(y, (cfg.n_passes,) + y.shape)
-        with obs_trace.phase("head_xent"):
-            if fused:
-                nll = fused_head_xent_tokens(
-                    out, params["lm_head"].astype(cfg.dtype), ys, "tensor")
-            else:
+        if fused:
+            # the loss is a weighted sum of the head's cross-entropies and
+            # the weights are known before it: its forward makes dh and dW
+            with obs_trace.phase("exit"):
+                p, plogp = exit_distribution(gate)
+            with obs_trace.phase("head_xent"):
+                xent, nll = fused_head_xent_wsum(
+                    out, params["lm_head"].astype(cfg.dtype), ys, p / y.size,
+                    "tensor")
+            with obs_trace.phase("exit"):
+                xent = xent + cfg.exit_beta * jnp.mean(plogp)
+                model_aux = exit_stats(jax.lax.stop_gradient(nll), p, plogp)
+        else:
+            with obs_trace.phase("head_xent"):
                 nll = vocab_parallel_xent_tokens(out, ys, tensor_axis="tensor")
-        with obs_trace.phase("exit"):
-            xent, model_aux = exit_weighted_loss(nll, gate, cfg.exit_beta)
+            with obs_trace.phase("exit"):
+                xent, model_aux = exit_weighted_loss(nll, gate, cfg.exit_beta)
     elif fused:
         # head matmul + softmax-xent fused through a chunked running
         # logsumexp: the [B,T,V] logits (and AD's saved softmax inputs) never
