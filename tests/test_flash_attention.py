@@ -4,6 +4,7 @@ the kernel is the dispatched single-block attention path of the LM step, so
 a sign/transpose slip in the hand-written VJP would corrupt training
 gradients silently."""
 
+import functools
 import importlib.util
 import json
 import os
@@ -137,10 +138,10 @@ def _frozen_fwd_kernel(scale, blk_q, blk_k, n_k, d,
     o_ref[0] = out.astype(o_ref.dtype)
 
 
-def _o_lse_grads(q, k, v, do, window=None):
+def _o_lse_grads(q, k, v, do, window=None, module=fa, interpret=True):
     """(o, lse, dq, dk, dv) through the wrappers the custom VJP runs."""
-    o, res = fa._fa_fwd(q, k, v, None, True, window)
-    return (o, res[4]) + tuple(fa._fa_bwd(None, True, res, do, window))
+    o, res = module._fa_fwd(q, k, v, None, interpret, window)
+    return (o, res[4]) + tuple(module._fa_bwd(None, interpret, res, do, window))
 
 
 @pytest.mark.parametrize(
@@ -190,62 +191,116 @@ def masked_softmax_attention(q, k, v, window):
 
 
 @pytest.mark.parametrize(
-    "shape, window, blk, streamed",
+    "shape, window, blk",
     [
-        ((1, 2, 1024, 64), 512, None, False),    # a block multiple: pairs (1, 0) and the diagonal's
-        ((1, 1, 1024, 128), 200, None, False),   # inside one block: the edge cuts pair (1, 0)
-        ((1, 1, 1536, 64), 700, None, True),     # wider than a block: three pairs a q block
-        ((2, 1, 1024, 64), 130, (128, 128), True),   # 8 blocks: whole pairs behind the band skipped
-        ((1, 2, 1024, 64), 256, (128, 256), False),  # unequal blocks, both loops' ends
-        ((1, 1, 1024, 64), 384, (256, 128), True),
-        ((1, 1, 512, 64), 1, (128, 128), False),     # the token itself alone
-        ((1, 1, 512, 64), 4096, (128, 128), True),   # a band wider than the sequence: causal
-        # the shipped blocks of 512 over four q blocks (what T = 8,192 runs
-        # sixteen of): the window a whole block, two pairs a q block ...
-        ((1, 1, 2048, 64), 512, None, False),
-        ((1, 1, 2048, 64), 512, None, True),
-        # ... and half of one: the band's far edge cuts the pair behind the diagonal's
-        ((1, 1, 2048, 64), 256, None, False),
-        ((1, 1, 2048, 64), 256, None, True),
+        # blocks of 512 walked in halves of 256 (what T = 8,192 runs sixteen
+        # of): a window of a whole block, six quarters of eight a q block ...
+        ((1, 2, 1024, 64), 512, None),
+        ((1, 1, 2048, 64), 512, None),
+        ((1, 1, 2048, 128), 512, None),      # ... at a head of two lane tiles in the packing
+        # ... of half a block: a half's rows meet two halves of keys
+        ((1, 1, 2048, 64), 256, None),
+        ((1, 1, 1024, 128), 200, None),      # inside one half: both edges cut the pair behind
+        ((1, 1, 1536, 64), 700, None),       # wider than a block: two blocks behind the diagonal's
+        ((1, 1, 1024, 64), 1023, None),      # window = t - 1, the band kernels at their widest
+        ((1, 1, 512, 64), 300, None),        # one block a head: nothing behind it to fetch
+        # blocks of 128 and 64 are walked whole
+        ((2, 1, 1024, 64), 130, (128, 128)),     # 8 blocks, two behind
+        ((1, 2, 1024, 64), 256, (256, 256)),     # halves of 128
+        ((1, 1, 1024, 64), 384, (128, 128)),     # a block multiple: no band edge inside the middle pairs
+        ((1, 1, 1024, 64), 600, (128, 128)),     # the first five q blocks fetch block 0 in place of what is not there
+        ((1, 1, 512, 64), 1, (128, 128)),        # the token itself alone
+        ((1, 1, 512, 64), 4096, (128, 128)),     # a band wider than the sequence: the whole-sequence kernels
+        ((1, 1, 64, 64), 24, None),              # not a multiple of the block or of its half: one whole pair
+        ((1, 1, 256, 64), 160, (64, 64)),        # three blocks behind, the dq carry three deep
+        ((1, 1, 256, 64), 100, (64, 64)),        # two, the oldest cut by the band's edge
     ],
 )
-def test_banded_kernels_match_the_masked_softmax(monkeypatch, shape, window,
-                                                 blk, streamed):
-    """o, dq, dk and dv of the kernels with a window against the masked
-    softmax over the whole [T, T], resident and (as the chip runs it) with q
-    and the cotangent streamed by DMA: the pair loops' lower and upper ends,
-    the second masked edge, and a prefetch that must stop where the loop does."""
+def test_banded_kernels_match_the_masked_softmax(monkeypatch, shape, window, blk):
+    """o, dq, dk and dv of the band kernels against the masked softmax over
+    the whole [T, T]: the static walk over the key blocks a q block reaches
+    (and back, the q blocks that reach a key block), the halves' own key
+    ranges, each mask's one or two edges, the blocks clamped at either end
+    of the sequence, and the dq carry from step to step.  The cotangent is
+    of the outputs' own size, so a gradient's error is not hidden under a
+    mean's 1 / n."""
     if blk is not None:
         monkeypatch.setattr(fa, "_pick_blocks", lambda t: blk)
-    if streamed:
-        monkeypatch.setenv("TPU_CDP_FORCE_STREAMED_DKV", "1")
     ks = jax.random.split(jax.random.key(11), 4)
-    q, k, v, tgt = (jax.random.normal(kk, shape, jnp.float32) * 0.5 for kk in ks)
-    lf = lambda q, k, v: jnp.mean(
-        (flash_causal_attention(q, k, v, None, True, window) - tgt) ** 2)
-    le = lambda q, k, v: jnp.mean(
-        (masked_softmax_attention(q, k, v, window) - tgt) ** 2)
-    np.testing.assert_allclose(
-        np.asarray(flash_causal_attention(q, k, v, None, True, window)),
-        np.asarray(masked_softmax_attention(q, k, v, window)), atol=1e-5)
-    for a, b, nm in zip(jax.grad(lf, (0, 1, 2))(q, k, v),
-                        jax.grad(le, (0, 1, 2))(q, k, v), "qkv"):
+    q, k, v, do = (jax.random.normal(kk, shape, jnp.float32) * 0.5 for kk in ks)
+    o, vjp = jax.vjp(lambda q, k, v: masked_softmax_attention(q, k, v, window),
+                     q, k, v)
+    got, got_vjp = jax.vjp(
+        lambda q, k, v: flash_causal_attention(q, k, v, None, True, window),
+        q, k, v)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(o), atol=1e-5)
+    for a, b, nm in zip(got_vjp(do), vjp(do), "qkv"):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5,
                                    err_msg=f"d{nm}")
+
+
+@pytest.mark.parametrize(
+    "t, window, blk, geometry, pairs",
+    [
+        (8192, 512, 512, (256, 1, 2), 16 * 6),   # both cells: six quarters of eight a grid step
+        (8192, 256, 512, (256, 1, 1), 16 * 4),
+        (8192, 1024, 512, (256, 2, 4), 16 * 10),
+        (8192, 200, 512, (256, 1, 1), 16 * 4),
+        (384, 130, 128, (128, 2, 2), 3 * 3),     # a half under a lane tile: whole blocks
+        (512, 300, 512, (256, 0, 2), 3),         # one block: its second half meets both
+    ],
+)
+def test_the_band_walk_is_static(monkeypatch, t, window, blk, geometry, pairs):
+    """What a band kernel's grid step computes is fixed by (block, window, T)
+    alone: the rows of a sub-block, the blocks fetched behind the diagonal's,
+    the sub-blocks a sub-block's band reaches; and the bench counts those
+    sub-block pairs (``live_pairs``), the same at every grid step."""
+    assert fa._band_geometry(blk, window, t // blk) == geometry
+    monkeypatch.setattr(fa, "_pick_blocks", lambda t: (blk, blk))
+    assert flash_attn_bench.live_pairs(fa, t, window) == (pairs, geometry[0])
 
 
 @pytest.mark.parametrize("shape, dtype", [((1, 2, 1024, 64), jnp.float32),
                                           ((1, 1, 1536, 128), jnp.bfloat16)])
 def test_bitwise_no_window_is_a_band_over_everything(shape, dtype):
-    """A call without a window visits every pair under the diagonal and masks
-    by the diagonal alone (the frozen kernel above is held to it bit for bit);
-    a band that reaches past the first key visits and keeps the same: o, lse,
-    dq, dk, dv bit for bit."""
+    """A band that reaches the first key from the last query IS the call
+    without a window: handed on as None to the whole-sequence kernels (no
+    band kernel is traced), so o, lse, dq, dk, dv are bit for bit."""
     ks = jax.random.split(jax.random.key(13), 4)
     q, k, v, do = ((jax.random.normal(kk, shape, jnp.float32) * 0.5)
                    .astype(dtype) for kk in ks)
     for a, b, nm in zip(_o_lse_grads(q, k, v, do),
                         _o_lse_grads(q, k, v, do, window=shape[2]),
+                        ("o", "lse", "dq", "dk", "dv")):
+        np.testing.assert_array_equal(np.asarray(a.astype(jnp.float32)),
+                                      np.asarray(b.astype(jnp.float32)), err_msg=nm)
+    jaxpr = jax.make_jaxpr(lambda *x: _o_lse_grads(*x, window=shape[2]))(q, k, v, do)
+    assert [name for name, _ in _pallas_calls(jaxpr.jaxpr)] == [
+        "flash_attn_fwd", "flash_attn_bwd"]
+
+
+@pytest.mark.parametrize("shape, dtype", [((1, 2, 256, 64), jnp.float32),
+                                          ((1, 2, 1024, 64), jnp.bfloat16),
+                                          ((1, 1, 1536, 128), jnp.float32),
+                                          ((2, 1, 1024, 128), jnp.bfloat16)])
+def test_bitwise_no_window_is_the_parents(shape, dtype):
+    """Taking the window out of the whole-sequence kernels moved nothing: a
+    call without one traces to the jaxpr that the parent's module does
+    (`fixtures/flash_attention_pr45.py`: PR 45's file, frozen, whose
+    whole-sequence kernels still carry the window's bounds and second mask
+    edge), letter for letter, interpreted and as the chip stages it, and o,
+    lse, dq, dk, dv are bit for bit the parent's."""
+    parent = flash_attn_bench.load("pr45", os.path.join(
+        os.path.dirname(__file__), "fixtures", "flash_attention_pr45.py"))
+    ks = jax.random.split(jax.random.key(29), 4)
+    q, k, v, do = ((jax.random.normal(kk, shape, jnp.float32) * 0.5)
+                   .astype(dtype) for kk in ks)
+    for interpret in (True, False):
+        trace = lambda module: str(jax.make_jaxpr(functools.partial(
+            _o_lse_grads, module=module, interpret=interpret))(q, k, v, do))
+        assert trace(fa) == trace(parent)
+    for a, b, nm in zip(_o_lse_grads(q, k, v, do),
+                        _o_lse_grads(q, k, v, do, module=parent),
                         ("o", "lse", "dq", "dk", "dv")):
         np.testing.assert_array_equal(np.asarray(a.astype(jnp.float32)),
                                       np.asarray(b.astype(jnp.float32)), err_msg=nm)
@@ -264,10 +319,28 @@ def test_the_xla_chain_takes_the_same_band():
         atol=1e-5)
 
 
+@pytest.mark.parametrize("window, fits", [(None, True), (512, True), (2048, True),
+                                          (3073, True), (3074, False),
+                                          (8191, False), (8192, True)])
+def test_the_gate_holds_a_band_to_what_its_kernels_hold(window, fits):
+    """The band kernels keep a q block's band in VMEM, not the sequence: the
+    gate admits a window while the backward's blocks stay under 12 MB (six
+    blocks of 512 behind the diagonal's at the longest admitted sequence) and
+    hands a wider one to the XLA chain; a window that reaches the whole
+    sequence is the call without one."""
+    shape = (1, 2, 8192, 128)
+    assert ra_mod.fused_attention_fits(shape, shape, 2, window) is fits
+    if window in (512, 3073):
+        blocks_behind = -(-(window - 1) // 512)
+        assert fa.band_vmem_bytes(8192, 128, 2, window) == int(
+            (2.5 + 1.5 * blocks_behind) * 2 ** 20)
+
+
 def _grad_of_sum(shape, dtype, window=None, **aval):
     """`jax.grad` of a sum through the kernel as dispatched (not interpreted),
     and its abstract operand: for tests that trace or compile and never run."""
-    assert ra_mod.fused_attention_fits(shape, shape, jnp.dtype(dtype).itemsize)
+    assert ra_mod.fused_attention_fits(shape, shape, jnp.dtype(dtype).itemsize,
+                                       window)
     loss = lambda q, k, v: jnp.sum(
         flash_causal_attention(q, k, v, None, False, window).astype(jnp.float32))
     return jax.grad(loss, (0, 1, 2)), jax.ShapeDtypeStruct(shape, dtype, **aval)
@@ -287,22 +360,27 @@ def _pallas_calls(jaxpr):
 
 
 @pytest.mark.parametrize(
-    "shape, dtype",
+    "shape, dtype, window",
     [
-        ((1, 2, 256, 64), jnp.float32),
-        ((1, 16, 4096, 128), jnp.bfloat16),   # the LM cell's attention call
-        ((1, 16, 8192, 128), jnp.bfloat16),   # the longest admitted sequence, in blocks of 512
+        ((1, 2, 256, 64), jnp.float32, None),
+        ((1, 16, 4096, 128), jnp.bfloat16, None),   # the LM cell's attention call
+        ((1, 16, 8192, 128), jnp.bfloat16, None),   # the longest admitted sequence, in blocks of 512
+        ((1, 64, 8192, 128), jnp.bfloat16, 512),    # the Laguna cell's banded call
+        ((1, 40, 8192, 128), jnp.bfloat16, 512),    # the Phi cell's: 40 maps, keys padded to the values' 128
+        ((1, 2, 256, 64), jnp.float32, 1),          # any window under T, down to the token alone
     ],
 )
-def test_grad_is_one_forward_and_one_backward_kernel(shape, dtype):
+def test_grad_is_one_forward_and_one_backward_kernel(shape, dtype, window):
     """The mechanism, not the numbers: differentiating through the kernel
     launches the forward and ONE backward `pallas_call`, for every shape the
-    dispatcher admits — no second backward kernel, no chooser between forms.
+    dispatcher admits — the whole-sequence pair without a window, the band
+    pair with one; no second backward kernel, no chooser between forms.
     Traced only (shapes in, jaxpr out): nothing compiles or runs."""
-    grad, x = _grad_of_sum(shape, dtype)
+    grad, x = _grad_of_sum(shape, dtype, window)
     jaxpr = jax.make_jaxpr(grad)(x, x, x)
+    band = "" if window is None else "band_"
     assert [name for name, _ in _pallas_calls(jaxpr.jaxpr)] == [
-        "flash_attn_fwd", "flash_attn_bwd"]
+        f"flash_attn_{band}fwd", f"flash_attn_{band}bwd"]
 
 
 @pytest.mark.parametrize("window", [None, 512])
@@ -315,8 +393,9 @@ def test_the_longest_sequence_runs_in_blocks_of_512(window):
     says nothing of VMEM (the compile below does)."""
     grad, x = _grad_of_sum((1, 2, 8192, 128), jnp.bfloat16, window)
     jaxpr = jax.make_jaxpr(grad)(x, x, x)
+    band = "" if window is None else "band_"
     assert list(_pallas_calls(jaxpr.jaxpr)) == [
-        ("flash_attn_fwd", (2, 16)), ("flash_attn_bwd", (2, 16))]
+        (f"flash_attn_{band}fwd", (2, 16)), (f"flash_attn_{band}bwd", (2, 16))]
 
 
 @pytest.fixture(scope="module")
@@ -341,8 +420,11 @@ def one_chip():
         ((1, 2, 8192, 128), jnp.bfloat16, None),    # longest T, blocks of 512: dq accumulator 4 MB
         ((1, 2, 4096, 256), jnp.bfloat16, None),    # widest head: 4 MB at 512-blocks
         ((1, 2, 4096, 128), jnp.float32, None),     # fp32 operands, 256-lane cotangent
-        ((1, 2, 8192, 128), jnp.bfloat16, 512),     # the Laguna cell's banded call, blocks of 512
-        ((1, 2, 4096, 128), jnp.bfloat16, 200),     # a band inside one 512-block
+        ((1, 2, 8192, 128), jnp.bfloat16, 512),     # the Laguna and Phi cells' banded call, halves of 256
+        ((1, 2, 4096, 128), jnp.bfloat16, 200),     # a band inside one half
+        ((1, 2, 8192, 128), jnp.bfloat16, 2048),    # four blocks behind the diagonal's: the dq carry 1 MB
+        ((1, 2, 4096, 64), jnp.float32, 1000),      # fp32 operands, no edge on a block's boundary
+        ((1, 2, 384, 64), jnp.bfloat16, 128),       # blocks of 128, walked whole
     ],
 )
 def test_backward_compiles_for_v5e_at_admitted_extremes(one_chip, shape, dtype,
@@ -350,7 +432,9 @@ def test_backward_compiles_for_v5e_at_admitted_extremes(one_chip, shape, dtype,
     """Forward and the one backward kernel pass Mosaic for a v5e at the
     extremes `fused_attention_fits` admits: the [T, d_pad] float32 dq
     accumulator, the streamed blocks and the [blk, blk] temporaries fit the
-    scoped-VMEM ceiling, so no shape needs a second form of the backward.
+    scoped-VMEM ceiling, so no shape needs a second form of the backward;
+    and the band kernels' static slices, clamped index maps and dq carry
+    pass it at both cells' shape and at windows of several blocks.
     A compile, not a run: nothing here is a time."""
     grad, x = _grad_of_sum(shape, dtype, window, sharding=one_chip)
     compiled = jax.jit(grad).lower(x, x, x).compile()
@@ -409,7 +493,8 @@ def test_the_bench_tells_another_order_of_sums_from_another_answer(tmp_path):
     [
         ((1, 2, 512, 64), None, True, False),    # one block, two maps
         ((1, 1, 1024, 64), 200, True, False),    # the band's edge inside a pair
-        ((1, 1, 1024, 64), 512, True, True),     # as the chip stages it
+        ((1, 1, 1024, 64), None, True, True),    # as the chip stages it
+        ((1, 2, 1024, 64), 512, True, False),    # the Phi cell's banded layer: halves of 256
         ((2, 2, 256, 64), None, False, False),   # the XLA chain, whole ...
         ((1, 2, 256, 64), 100, False, False),    # ... and in a band
         ((1, 1, 256, 32), None, False, False),   # keys of 32 under values of 64

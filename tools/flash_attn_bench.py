@@ -24,6 +24,11 @@ and its gradients (``gap_to_exact``): two sides equally far from the exact
 answer compute the same thing.  The exit code is 1 unless every side is bit
 for bit the first.
 
+``fwd_us_a_pair`` and ``bwd_us_a_pair`` divide a call by the pairs it
+computes, ``pair_rows`` a side: (q block, kv block) pairs for the
+whole-sequence kernels, the (sub-block, sub-block) pairs ("quarters") of the
+band kernels' static walk for a call with a window.
+
 A forward is ``_fa_fwd`` (pad, kernel, slice); a backward is ``_fa_bwd``
 (``delta``, the lane packing, kernel, slices).  Each is timed as a jitted
 ``lax.fori_loop`` of ``--calls`` calls whose carry runs through one element of
@@ -57,6 +62,7 @@ SHAPES = [
     ((1, 4, 4096, 128), "float32"),
     ((1, 48, 8192, 128), "bfloat16"),         # laguna_xs2_dense_staged's full layers, a sequence
     ((1, 64, 8192, 128), "bfloat16", 512),    # ... and its window layers
+    ((1, 40, 8192, 128), "bfloat16", 512),    # phi4_mini_flash_dense_staged's: 40 maps, keys padded to 128
 ]
 REHEARSAL_SHAPES = [((1, 2, 1024, 64), "bfloat16"), ((1, 1, 1024, 64), "float32"),
                     ((1, 2, 1024, 64), "bfloat16", 300)]
@@ -78,11 +84,20 @@ def operands(shape, dtype):
 
 
 def live_pairs(fa, t, window=None):
-    """(q block, kv block) pairs a head's kernels visit: those not wholly
-    above the diagonal nor wholly behind the band, at the module's own blocks."""
+    """(pairs a head's kernels compute, rows of one).  Whole-sequence kernels
+    (and PR 45's and older with a window): the (q block, kv block) pairs not
+    wholly above the diagonal nor wholly behind the band, at the module's own
+    blocks.  Band kernels: the (sub-block, sub-block) pairs of their static
+    walk, quarters of a block pair where a block is walked in halves; every
+    grid step computes the same number, clamped blocks included."""
     bq, bk = fa._pick_blocks(t)
+    if window is not None and window < t and hasattr(fa, "_band_geometry"):
+        sub, n_back, reach = fa._band_geometry(bq, window, t // bq)
+        n_sub = bq // sub
+        return t // bq * sum(min(reach, n_back * n_sub + a) + 1
+                             for a in range(n_sub)), sub
     first = lambda qi: 0 if window is None else max(qi * bq - window + 1, 0) // bk
-    return sum(-(-(qi + 1) * bq // bk) - first(qi) for qi in range(t // bq))
+    return sum(-(-(qi + 1) * bq // bk) - first(qi) for qi in range(t // bq)), bq
 
 
 def takes_window(fa) -> bool:
@@ -172,6 +187,8 @@ def main(argv=None) -> int:
     ap.add_argument("--calls", type=int, default=100)
     ap.add_argument("--reps", type=int, default=3)
     ap.add_argument("--rehearse", action="store_true")
+    ap.add_argument("--windowed", action="store_true",
+                    help="only the shapes with a window")
     ap.add_argument("--out", default=os.path.join(ROOT, "chiprun_out",
                                                   "flash_attn_bench.json"))
     args = ap.parse_args(argv)
@@ -188,6 +205,8 @@ def main(argv=None) -> int:
     rows = []
     for shape, dtype, *window in REHEARSAL_SHAPES if args.rehearse else SHAPES:
         window = window[0] if window else None
+        if args.windowed and window is None:
+            continue
         q, k, v, do = operands(shape, getattr(jnp, dtype))
         reference = None
         truth = exact(*(x[:, :EXACT_HEADS] for x in (q, k, v, do)), window)
@@ -206,8 +225,9 @@ def main(argv=None) -> int:
             fwd_ms = ms_a_call(fwd, (q, k, v), calls, args.reps)
             bwd_ms = ms_a_call(bwd, (q, k, v, got[0], got[1], do), calls, args.reps)
             if not args.rehearse:
-                pairs = shape[0] * shape[1] * live_pairs(fa, shape[2], window)
-                row.update(fwd_ms=fwd_ms, bwd_ms=bwd_ms,
+                pairs, rows_a_pair = live_pairs(fa, shape[2], window)
+                pairs *= shape[0] * shape[1]
+                row.update(fwd_ms=fwd_ms, bwd_ms=bwd_ms, pair_rows=rows_a_pair,
                            fwd_us_a_pair=fwd_ms * 1e3 / pairs,
                            bwd_us_a_pair=bwd_ms * 1e3 / pairs)
             rows.append(row)

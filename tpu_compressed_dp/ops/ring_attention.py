@@ -54,20 +54,23 @@ _NEG_INF = -1e30
 _FUSED_ATTN = os.environ.get("TPU_CDP_FUSED_ATTN", "1") != "0"
 
 
-def use_fused_attention(q_shape, k_shape, itemsize: int = 2) -> bool:
-    """True when the single-block causal path should hit the fused kernel
+def use_fused_attention(q_shape, k_shape, itemsize: int = 2,
+                        window: Optional[int] = None) -> bool:
+    """True when the single-block causal path should hit the fused kernels
     (:mod:`tpu_compressed_dp.ops.flash_attention`): TPU backend and shapes
-    the kernel takes (:func:`fused_attention_fits`)."""
+    the kernels take (:func:`fused_attention_fits`)."""
     if not _FUSED_ATTN or jax.default_backend() != "tpu":
         return False
-    return fused_attention_fits(q_shape, k_shape, itemsize)
+    return fused_attention_fits(q_shape, k_shape, itemsize, window)
 
 
-def fused_attention_fits(q_shape, k_shape, itemsize: int = 2) -> bool:
-    """Shapes the fused kernel takes: seq a lane multiple, head_dim
-    MXU-friendly, K/V small enough to stream through VMEM whole.  The same
-    for a call with a window: the banded kernels skip the pairs outside the
-    band but keep the residency below (K, V and the dq accumulator whole)."""
+def fused_attention_fits(q_shape, k_shape, itemsize: int = 2,
+                         window: Optional[int] = None) -> bool:
+    """Shapes the fused kernels take: seq a lane multiple, head_dim
+    MXU-friendly, K/V small enough to stream through VMEM whole.  A call with
+    a window passes the same gate, though its kernels keep nothing of length
+    T: what they hold grows with the blocks a band reaches, and a band too
+    wide for that takes the XLA chain."""
     b, h, t, d = q_shape
     d_pad = d + (-d) % 128
     # What is resident for a whole head, and so grows with T.  Forward:
@@ -79,15 +82,26 @@ def fused_attention_fits(q_shape, k_shape, itemsize: int = 2) -> bool:
     # Both must fit the TPU's ~16 MB scoped-vmem ceiling.  Cap the
     # single-buffered K+V set at 4 MB (= 8 MB doubled + block buffers):
     # admits the chip-verified T=8192 at d=128 exactly, where the kernels run
-    # in blocks of 512 like every shorter call (136 pairs a head, 31 in a
-    # band of 512: 2 a q block) and the compile for a v5e reports 10.00 MB
-    # forward and 7.00 MB backward (flash_attention._pick_blocks has the
-    # sums); T=16384 (8 MB single, ~18+ doubled) would hit the scoped-vmem
-    # wall — long-context's designed path is the seq-axis ring sharding
-    # T_local below this gate.
+    # in blocks of 512 like every shorter call (136 pairs a head) and the
+    # compile for a v5e reports 10.00 MB forward and 7.00 MB backward
+    # (flash_attention._pick_blocks has the sums); T=16384 (8 MB single, ~18+
+    # doubled) would hit the scoped-vmem wall — long-context's designed path
+    # is the seq-axis ring sharding T_local below this gate.
     resident = t * 2 * d_pad * itemsize   # K + V at input dtype
-    return (t == k_shape[2] and t >= 128 and t % 128 == 0 and d % 64 == 0
+    fits = (t == k_shape[2] and t >= 128 and t % 128 == 0 and d % 64 == 0
             and resident <= 4 * 1024 * 1024)
+    if fits and window is not None and window < t:
+        # The band kernels (a q block against the key blocks its band
+        # reaches) are far under that at a window of a block or two (2.25 MB
+        # forward, 4.00 backward at T=8192, d=128, window 512) and grow by
+        # 1.5 MB a block of 512 behind the diagonal's: held to 12 MB, a
+        # window of 3,073 there (Mosaic passes 4,096 and refuses 8,191).
+        # The T cap above is not lifted for them: no cell runs a banded
+        # layer past it.
+        from tpu_compressed_dp.ops.flash_attention import band_vmem_bytes
+
+        fits = band_vmem_bytes(t, d, itemsize, window) <= 12 * 1024 * 1024
+    return fits
 
 
 def _fused_causal(q: Array, k: Array, v: Array, scale: float,
@@ -147,9 +161,10 @@ def ring_attention(
     and the score product does 128 columns where 64 are data.
 
     ``window``: a query sees itself and the ``window - 1`` keys before it
-    (sliding-window attention).  Single block only: the fused kernel visits
-    the block pairs that meet the band, the XLA chain masks the rest; the
-    ring path, whose blocks behind the band would still travel, refuses one.
+    (sliding-window attention).  Single block only: the fused band kernels
+    meet each q block with the key blocks its band reaches, the XLA chain
+    masks the rest; the ring path, whose blocks behind the band would still
+    travel, refuses one.
     """
     if q.shape[1] != k.shape[1]:
         if q.shape[1] % k.shape[1]:
@@ -178,7 +193,8 @@ def ring_attention(
     if window is not None and ring != 1:
         raise NotImplementedError("a window on the ring path is not written: "
                                   "every block would still go round")
-    if ring == 1 and use_fused_attention(q.shape, k.shape, q.dtype.itemsize):
+    if ring == 1 and use_fused_attention(q.shape, k.shape, q.dtype.itemsize,
+                                         window):
         return _fused_causal(q, k, v, scale, window)
 
     q_pos = my * t_local + jnp.arange(t_local)
