@@ -71,7 +71,7 @@ def test_projection_method_aware_topk_vs_randomk(mesh8):
 
 
 def test_run_adaptive_point_schema_and_convergence(mesh8):
-    """BENCH_r09 protocol: the closed-loop record carries the per-window
+    """The closed-loop record carries the per-window
     trajectory + per-rung static baselines, and with a budget only the
     bottom rung satisfies the controller must walk down to it."""
     rec = sweep.run_adaptive_point(

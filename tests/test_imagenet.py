@@ -9,10 +9,6 @@ checkpoint/resume (including the EF residual the reference failed to save).
 import numpy as np
 import pytest
 
-# ~2 min of ResNet compiles on the 1-core CI host: excluded from the 870 s
-# tier-1 budget (`-m 'not slow'`), runs in the unfiltered suite
-pytestmark = pytest.mark.slow
-
 import jax
 import jax.numpy as jnp
 
@@ -175,6 +171,7 @@ class TestCheckpoint:
         ckpt.close()
 
 
+@pytest.mark.slow  # 20-40 s of ResNet compiles
 def test_imagenet_harness_e2e(tmp_path):
     """Full smoke: synthetic data, progressive resize (64->96 px with rect
     val), bf16 resnet18, layer-wise Top-K + EF, checkpoint every improvement,
@@ -285,6 +282,7 @@ class TestImageFolderSizeCache:
         assert ds2._load_size_cache() is not None
 
 
+@pytest.mark.slow  # 20-40 s of ResNet compiles
 def test_imagenet_harness_e2e_imagefolder(tmp_path):
     """On-disk ImageFolder end-to-end (VERDICT r2 #7): train + rect-val
     through the smoke schedule's two image sizes, driven by real files."""

@@ -39,7 +39,6 @@ class TestTopkThreshold:
         np.testing.assert_array_equal(np.asarray(mag >= t), np.asarray(mag >= exact))
         assert int(jnp.sum(mag >= t)) == keep
 
-    @pytest.mark.slow  # ~9 s each on the 1-core host (multi-MB interpret runs)
     @pytest.mark.parametrize("keep_frac", [0.01, 0.1])
     def test_sampled_init_large_n(self, keep_frac):
         # large n + moderate keep engages the sampled-init fast path (slab
@@ -63,7 +62,6 @@ class TestTopkThreshold:
         t = kernels._topk_threshold_pallas(mag, keep, interpret=True)
         assert int(jnp.sum(mag >= t)) == keep
 
-    @pytest.mark.slow  # ~9 s on the 1-core host
     def test_sampled_init_adversarial_layout_keeps_guarantee(self):
         # the slab sample reads the first 128 lanes of each C-block (C=4096
         # for this n/keep); hide MORE than `keep` spikes in the unsampled
@@ -216,19 +214,18 @@ class TestFusedSelectPack:
         return (wire._sorted_gather(flat, idx), idx,
                 jnp.sum(mask, dtype=jnp.int32))
 
-    # tier-1 parity core: the multi-chunk ragged case in both dtypes plus
-    # the keep=1 and keep=n extremes; the full size x dtype cross rides
-    # `-m slow` with the rest of the wire matrix (each row pays ~2 s of
-    # interpreter compile, and tier-1 runs against a fixed wall budget)
+    # the multi-chunk ragged case in both dtypes, the keep=1 and keep=n
+    # extremes and an odd size, each in both dtypes (a row pays ~2 s of
+    # interpreter compile)
     @pytest.mark.parametrize("n,keep,dtype", [
         (70000, 700, jnp.float32),
         (70000, 700, jnp.bfloat16),
         (65536, 1, jnp.float32),
         (4096, 4096, jnp.float32),
-        pytest.param(65536, 1, jnp.bfloat16, marks=pytest.mark.slow),
-        pytest.param(4096, 4096, jnp.bfloat16, marks=pytest.mark.slow),
-        pytest.param(12345, 300, jnp.float32, marks=pytest.mark.slow),
-        pytest.param(12345, 300, jnp.bfloat16, marks=pytest.mark.slow),
+        (65536, 1, jnp.bfloat16),
+        (4096, 4096, jnp.bfloat16),
+        (12345, 300, jnp.float32),
+        (12345, 300, jnp.bfloat16),
     ])
     def test_bitwise_parity_topk(self, n, keep, dtype):
         flat = jax.random.normal(jax.random.key(n + keep), (n,), dtype)

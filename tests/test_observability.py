@@ -930,8 +930,7 @@ class TestWatchdog:
         assert check_heartbeat(q, max_straggler_skew_s=0.001) == []
 
     def test_max_step_p95_cli(self, tmp_path):
-        """--max_step_p95_ms reads the telemetry snapshot's tail latency —
-        the digital twin's modeled budget enforced live (ISSUE 19)."""
+        """--max_step_p95_ms reads the telemetry snapshot's tail latency."""
         import tools.watchdog as wd
 
         p = self._hb(tmp_path, telemetry={"step_p95_ms": 1800.0})

@@ -166,7 +166,7 @@ class TestDeltaCodec:
 
 # --------------------------------------------------------- window invariant
 
-class TestWindowInvariant:
+class TestLosslessWindow:
     def test_keyframe_plus_deltas_reconstruct_bitwise(self, tmp_path):
         """Tier-1 pin of the lossless invariant: at every window close,
         ``keyframe + deltas`` == the producer's params, bitwise; mid-window

@@ -14,11 +14,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 from jax import shard_map
 
-# compile-dominated on the 1-core CI host (~7 min alone vs the 870 s tier-1
-# budget for the whole suite): excluded from `-m 'not slow'`, runs in the
-# unfiltered suite on real hardware
-pytestmark = pytest.mark.slow
-
 from tpu_compressed_dp.models import transformer as tf
 from tpu_compressed_dp.ops.ring_attention import dense_causal_attention, ring_attention
 

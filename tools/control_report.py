@@ -15,19 +15,10 @@ the epoch/step records carry:
   * a one-line **summary** — decisions taken, moves by direction, final
     rung, and whether the loop converged (last K windows held).
 
-With ``--twin_records <dir>`` every decision row also carries a **twin
-ms** column: the window's billed bits re-priced through the calibrated
-per-fabric digital twin (``tpu_compressed_dp/twin/``) next to the flat
-``--adaptive_bw_mbps`` price the controller steered on — the audit of
-what each rung decision WOULD have seen under the schedule-aware model.
-Topology defaults come from the ``run_start`` record and can be
-overridden (``--twin_world/--twin_pods/--twin_transport``).
-
 Usage::
 
     python tools/control_report.py events.jsonl
     python tools/control_report.py events.jsonl --json
-    python tools/control_report.py events.jsonl --twin_records .
 """
 
 from __future__ import annotations
@@ -85,35 +76,6 @@ def window_rows(events: List[Dict[str, Any]]) -> List[Dict[str, Any]]:
     return rows
 
 
-def build_pricer(events: List[Dict[str, Any]], twin_records: str,
-                 world: Optional[int] = None, pods: Optional[int] = None,
-                 transport: Optional[str] = None):
-    """A :class:`~tpu_compressed_dp.control.signals.TwinPricer` for this
-    run: twin fitted from ``twin_records``, topology from the
-    ``run_start`` record unless overridden."""
-    from tpu_compressed_dp.control.signals import TwinPricer
-    from tpu_compressed_dp.twin import calibration_rows, fit
-
-    start = next((e for e in events if e.get("kind") == "run_start"), {})
-    rows = calibration_rows(twin_records)
-    tr = transport or str(start.get("transport") or "psum")
-    if tr == "allgather":
-        tr = "all_gather"
-    return TwinPricer(
-        model=fit(rows).model,
-        world=int(world or start.get("devices") or start.get("world") or 8),
-        pods=int(pods or start.get("dp_pods") or 1),
-        transport=tr, calib_rows=len(rows))
-
-
-def attach_twin_price(rows: List[Dict[str, Any]], pricer) -> None:
-    """Add ``twin_comm_ms`` next to each row's flat-priced ``comm_ms``."""
-    for r in rows:
-        bits = r.get("bits")
-        if isinstance(bits, (int, float)):
-            r["twin_comm_ms"] = pricer.comm_ms(float(bits))
-
-
 def summarize(decisions: List[Dict[str, Any]],
               hold_tail: int = 3) -> Dict[str, Any]:
     """Aggregate the decision stream: move counts, final rung/value, and
@@ -140,7 +102,7 @@ def _fmt(v: Optional[float], spec: str = "9.2f") -> str:
     return format(v, spec) if isinstance(v, (int, float)) else " " * 6 + "-"
 
 
-def render_report(events: List[Dict[str, Any]], pricer=None) -> str:
+def render_report(events: List[Dict[str, Any]]) -> str:
     check_schema(events)
     lines = []
     start = next((e for e in events if e.get("kind") == "run_start"), {})
@@ -148,17 +110,11 @@ def render_report(events: List[Dict[str, Any]], pricer=None) -> str:
     lines.append(f"run: {json.dumps(ctx)}")
 
     decs = decision_rows(events)
-    if pricer is not None:
-        attach_twin_price(decs, pricer)
-        lines.append(f"twin: W={pricer.world} pods={pricer.pods} "
-                     f"transport={pricer.transport} "
-                     f"calib_rows={pricer.calib_rows}")
     lines.append("")
     lines.append("rung trajectory (one row per closed window):")
     lines.append(f"  {'#':>4}{'applied':>9}{'updates':>9}{'rung':>6}"
                  f"{'value':>9}{'comm ms':>9}"
-                 + (f"{'twin ms':>9}" if pricer is not None else "")
-                 + f"{'budget ms':>10}{'bits/upd':>11}  move")
+                 f"{'budget ms':>10}{'bits/upd':>11}  move")
     for d in decs:
         move = d.get("direction", "?")
         if move != "hold":
@@ -168,9 +124,7 @@ def render_report(events: List[Dict[str, Any]], pricer=None) -> str:
             f"{d.get('updates', '?'):>9}{d.get('rung_to', '?'):>6}"
             f"{_fmt(d.get('value_to'), '9.4g')}"
             f"{_fmt(d.get('comm_ms'))}"
-            + (f"{_fmt(d.get('twin_comm_ms'))}" if pricer is not None
-               else "")
-            + f"{_fmt(d.get('budget_ms'), '10.2f')}"
+            f"{_fmt(d.get('budget_ms'), '10.2f')}"
             f"{_fmt(d.get('bits'), '11.3g')}  {move}")
     if not decs:
         lines.append("  (no control_decision records — was the run "
@@ -209,33 +163,16 @@ def main(argv: Optional[List[str]] = None) -> int:
     p.add_argument("events", help="JSONL event stream (harness --events)")
     p.add_argument("--json", action="store_true",
                    help="emit decisions/windows/summary as JSON")
-    p.add_argument("--twin_records", default=None,
-                   help="dir of BENCH/MULTICHIP record files; when given, "
-                        "decision rows gain a twin-priced comm column")
-    p.add_argument("--twin_world", type=int, default=None,
-                   help="override twin topology: data-parallel world size")
-    p.add_argument("--twin_pods", type=int, default=None,
-                   help="override twin topology: DCN pod count")
-    p.add_argument("--twin_transport", default=None,
-                   help="override twin transport schedule "
-                        "(psum|all_gather|sharded|hierarchical)")
     args = p.parse_args(argv)
     events = read_events(args.events)
-    pricer = None
-    if args.twin_records is not None:
-        pricer = build_pricer(events, args.twin_records,
-                              world=args.twin_world, pods=args.twin_pods,
-                              transport=args.twin_transport)
     if args.json:
         check_schema(events)
         decs = decision_rows(events)
-        if pricer is not None:
-            attach_twin_price(decs, pricer)
         print(json.dumps({"decisions": decs,
                           "windows": window_rows(events),
                           "summary": summarize(decs)}, indent=2))
     else:
-        print(render_report(events, pricer=pricer))
+        print(render_report(events))
     return 0
 
 

@@ -38,7 +38,7 @@ row's ``first`` falls below ``X`` — the CI gate for the ISSUE 5 acceptance
 artifact (r5 baseline: 0.24–0.39).
 
 Findings land in ``benchmarks/overlap_hlo_r8.txt`` (r5 file kept for
-history) and BENCH_r08.json cites them.
+history).
 
 Usage::
 

@@ -24,7 +24,7 @@ synthetic per-step perturbations (every coordinate moves, like an
 optimizer step, which is the property that sizes a delta), not real LM
 training.  The byte accounting — the point of this bench — is exact.
 
-    python tools/stream_bench.py --out BENCH_r12.json
+    python tools/stream_bench.py --out stream_bench.json
 """
 
 from __future__ import annotations

@@ -22,8 +22,7 @@ Checks (see :func:`tpu_compressed_dp.utils.resilience.check_heartbeat`):
     alive and applying updates, but crawling.
   * **slow tail** — telemetry ``step_p95_ms`` above ``--max_step_p95_ms``:
     the mean rate still passes but the tail latency regressed past the
-    run's budget (set it from the run's own steady ``step_p95_ms``, or
-    the digital twin's modeled step time, x 1.1).
+    run's budget (set it from the run's own steady ``step_p95_ms`` x 1.1).
   * **checkpoint-stale** — heartbeat ``ckpt_age_s`` (plus the heartbeat's
     own age) exceeds ``--max_ckpt_age``: the run is making progress it
     could not recover — a crash now loses that much work.
@@ -305,9 +304,8 @@ def main(argv: Optional[List[str]] = None) -> int:
     p.add_argument("--min_step_rate", type=float, default=None,
                    help="min telemetry steps/sec (default: no stall check)")
     p.add_argument("--max_step_p95_ms", type=float, default=None,
-                   help="max telemetry p95 step latency in ms — budget it "
-                        "from the twin's modeled step time (perf pin x "
-                        "tolerance); default: no tail-latency check")
+                   help="max telemetry p95 step latency in ms; "
+                        "default: no tail-latency check")
     p.add_argument("--max_ckpt_age", type=float, default=None,
                    help="max seconds since the run's last durable "
                         "checkpoint (heartbeat ckpt_age_s + heartbeat age; "

@@ -82,12 +82,6 @@ REPLAY_DETERMINISTIC_MODULES = (
     "tpu_compressed_dp/stream/writer.py",
     "tpu_compressed_dp/stream/reader.py",
     "tpu_compressed_dp/stream/rejoin.py",
-    # the digital twin's fit/predict core: calibrations and predictions
-    # must be pure functions of the committed artifacts — same records,
-    # same model, bitwise
-    "tpu_compressed_dp/twin/model.py",
-    "tpu_compressed_dp/twin/records.py",
-    "tpu_compressed_dp/twin/calibrate.py",
 )
 
 #: modules that write records other processes read over shared storage —
@@ -114,8 +108,7 @@ SHARED_DIR_MODULES = (
 #: registry-governed stat-key families (TCDP103); literals shaped
 #: "<family>/<name>" with these families must be declared
 STAT_FAMILIES = ("comm", "guard", "elastic", "ckpt", "throughput", "time",
-                 "net", "control", "fleet", "flight", "straggler", "stream",
-                 "twin")
+                 "net", "control", "fleet", "flight", "straggler", "stream")
 STAT_KEY_RE = re.compile(r"^(?:%s)/[a-z0-9_]+$" % "|".join(STAT_FAMILIES))
 
 _WALLCLOCK_CALLS = frozenset({

@@ -45,67 +45,7 @@ from tpu_compressed_dp.train.optim import SGD
 from tpu_compressed_dp.train.state import TrainState
 from tpu_compressed_dp.train.step import make_train_step
 
-__all__ = ["run_point", "run_adaptive_point", "run_sweep", "main",
-           "attach_prediction", "PREDICT_WORLDS"]
-
-#: the flagship projection worlds the --predict table prices
-PREDICT_WORLDS = (64, 256, 1024, 4096)
-
-
-def attach_prediction(rec: Dict, calib, *, pod_size: int = 64) -> Dict:
-    """Add twin-modeled columns to one flat sweep record, in place.
-
-    ``pred_step_ms`` is the calibrated twin's price for the record's own
-    config (context compute anchor + priced comm); ``pred_err_frac`` its
-    relative miss against the measured ``step_ms``; ``pred_err_bar_ms``
-    the calibration's step-row RMS error scaled to the prediction (the
-    +/- bar to quote next to it).  ``pred_step_ms_w<W>`` prices the SAME
-    config projected to W chips (``W // pod_size`` pods, the measured
-    row's compute held fixed, comm re-laid on the scaled schedule) for
-    each W in :data:`PREDICT_WORLDS` — the scale-out table
-    ``tools/twin_report.py`` renders.  Records the twin cannot price
-    (uncalibrated fabric/context) get ``pred_basis`` explaining why.
-
-    A config whose fitted compute anchor comes out negative (the context
-    term absorbed a comm overshoot, so the anchor is unphysical) keeps
-    its own-topology prediction — the overshoot cancels there by
-    construction — but refuses the W projections (``None``): projecting
-    a negative anchor onto a different schedule extrapolates the fit
-    artifact, not the config.
-    """
-    from tpu_compressed_dp.twin.model import UncalibratedFabricError
-    from tpu_compressed_dp.twin.records import (context_key, scaled_schedule,
-                                                step_row)
-
-    row = step_row(rec, source="sweep", index=0)
-    comm = calib.comm_ms_for(row)
-    ctx = context_key(rec)
-    if ctx in calib.contexts:
-        rec["pred_basis"] = "context"
-        compute = calib.contexts[ctx]
-    else:
-        # config never benchmarked: anchor compute on the measured step
-        # (comm columns still carry twin information; pred_err_frac then
-        # only scores the comm model, and says so)
-        rec["pred_basis"] = "measured_anchor"
-        compute = float(rec["step_ms"]) - comm
-    pred = compute + comm
-    rec["pred_step_ms"] = round(pred, 3)
-    rec["pred_err_frac"] = round(
-        (pred - float(rec["step_ms"])) / max(float(rec["step_ms"]), 1e-9), 5)
-    rec["pred_err_bar_ms"] = round(calib.step_rms_frac * pred, 3)
-    for w in PREDICT_WORLDS:
-        if compute < 0.0:
-            rec[f"pred_step_ms_w{w}"] = None
-            continue
-        pods = max(1, w // max(int(pod_size), 1))
-        try:
-            sched = scaled_schedule(rec, world=w, pods=pods)
-            rec[f"pred_step_ms_w{w}"] = round(
-                compute + calib.model.comm_ms(sched), 3)
-        except UncalibratedFabricError:
-            rec[f"pred_step_ms_w{w}"] = None
-    return rec
+__all__ = ["run_point", "run_adaptive_point", "run_sweep", "main"]
 
 
 def _build_model(name: str, image_size: int, num_classes: int,
@@ -312,8 +252,7 @@ def run_point(
             "dense_allreduce_gbps_per_chip": round(dense_gbps, 3),
             # per-step per-chip link traffic at the RUN's device count —
             # the rate-free quantity transport comparisons (allgather vs
-            # sharded, BENCH_r07; per-fabric split for hierarchical,
-            # BENCH_r10) are made on
+            # sharded; per-fabric split for hierarchical) are made on
             "per_chip_traffic_mb": round(traffic_ici + traffic_dcn, 4),
             "per_chip_traffic_mb_ici": round(traffic_ici, 4),
             "per_chip_traffic_mb_dcn": round(traffic_dcn, 4),
@@ -367,8 +306,7 @@ def run_adaptive_point(
     channels_scale: float = 1.0,
 ) -> Dict:
     """Run the closed-loop controller on one (method, granularity) point and
-    bill it against every static rung — the adaptive-vs-best-static record
-    (BENCH_r09 protocol).
+    bill it against every static rung — the adaptive-vs-best-static record.
 
     The measured half runs ``windows`` decision windows of ``window`` steps
     each through the real rung-switching loop (trace-cached step variant per
@@ -554,25 +492,14 @@ def run_sweep(args) -> List[Dict[str, float]]:
     transports = [t.strip() for t in args.transports.split(",") if t.strip()]
     records = []
 
-    calib = None
-    if getattr(args, "predict", False):
-        from tpu_compressed_dp.twin import calibration_rows, fit
-
-        calib = fit(calibration_rows(args.twin_records))
-        print(f"# twin: fitted {calib.n_step_rows} step + "
-              f"{calib.n_phase_rows} phase rows from {args.twin_records} "
-              f"(step rms {calib.step_rms_frac:.1%})", file=sys.stderr)
-
     def emit(rec):
-        if calib is not None and "step_ms" in rec and "transport" in rec:
-            attach_prediction(rec, calib, pod_size=args.twin_pod_size)
         records.append(rec)
         print(json.dumps(rec), flush=True)
 
     if getattr(args, "adaptive", False):
         # closed-loop comparison instead of the static grid: one nested
         # record per (method, granularity) — per-window rung trajectory +
-        # per-rung static baselines + the best-static pick (BENCH_r09)
+        # per-rung static baselines + the best-static pick
         from tpu_compressed_dp.control.config import TUNABLE_METHODS
 
         ranks = [int(r) for r in args.ranks.split(",") if r.strip()]
@@ -678,23 +605,6 @@ def run_sweep(args) -> List[Dict[str, float]]:
             for r in records:
                 f.write("\t".join(str(r.get(k, "")) for k in keys) + "\n")
         print(f"# wrote {args.tsv}", file=sys.stderr)
-    if calib is not None:
-        # the scale-out table, human-shaped (same numbers as the
-        # pred_step_ms_w* columns on each JSON line)
-        print(f"# twin projection, modeled step ms "
-              f"(pods = W // {args.twin_pod_size}):", file=sys.stderr)
-        cols = "".join(f"{f'W={w}':>12s}" for w in PREDICT_WORLDS)
-        print(f"# {'config':40s}{cols}", file=sys.stderr)
-        for r in records:
-            if f"pred_step_ms_w{PREDICT_WORLDS[0]}" not in r:
-                continue
-            name = (f"{r.get('method')}/{r.get('granularity')}"
-                    f"/{r.get('transport')}")
-            vals = "".join(
-                f"{r.get(f'pred_step_ms_w{w}'):>12.1f}"
-                if r.get(f"pred_step_ms_w{w}") is not None else
-                f"{'n/a':>12s}" for w in PREDICT_WORLDS)
-            print(f"# {name:40s}{vals}", file=sys.stderr)
     return records
 
 
@@ -771,7 +681,7 @@ def build_parser() -> argparse.ArgumentParser:
                         "static grid: per (method, granularity), run the "
                         "rung-switching control loop for --adaptive_windows "
                         "decision windows and bill it against every static "
-                        "rung (control/ subsystem; BENCH_r09 protocol)")
+                        "rung (control/ subsystem)")
     p.add_argument("--adaptive_windows", type=int, default=6,
                    help="decision windows to run the control loop for")
     p.add_argument("--adaptive_window", type=int, default=2,
@@ -789,18 +699,6 @@ def build_parser() -> argparse.ArgumentParser:
                         "bits -> comm-ms conversion")
     p.add_argument("--adaptive_deadband", type=float, default=0.25,
                    help="controller hysteresis band around the budget")
-    p.add_argument("--predict", action="store_true",
-                   help="price every row through the calibrated digital "
-                        "twin (tpu_compressed_dp/twin/): adds pred_step_ms/"
-                        "pred_err_frac (+/- pred_err_bar_ms) next to the "
-                        "measured columns and a pred_step_ms_w{64,256,1024,"
-                        "4096} scale-out projection per row")
-    p.add_argument("--twin_records", type=str, default=".",
-                   help="directory with the BENCH_r*/MULTICHIP_r* records "
-                        "the twin calibrates from (--predict)")
-    p.add_argument("--twin_pod_size", type=int, default=64,
-                   help="chips per pod assumed by the W-projection columns "
-                        "(pods = W // pod_size)")
     return p
 
 

@@ -48,14 +48,6 @@ class ControlConfig:
                     replayable); 'measured' — the harness feeds StepTimeline
                     wall-time signals instead (production mode; documented
                     as NOT cross-run bitwise)
-    model:          how the 'modeled' signal prices bits: 'flat' (default)
-                    divides by ``bandwidth_mbps``; 'twin' prices the
-                    transport's collective schedule through the calibrated
-                    per-fabric twin (``tpu_compressed_dp/twin/``) — the
-                    harness must hand the Controller a
-                    :class:`~tpu_compressed_dp.control.signals.TwinPricer`.
-                    Still a pure function of billed bits, so still
-                    replay-deterministic
     bandwidth_mbps: modeled per-chip wire bandwidth, Mbit/s ('modeled' only)
     budget_ms:      hideable-compute budget per update, ms.  > 0 pins the
                     budget; 0 means the harness must derive it (measured
@@ -69,7 +61,6 @@ class ControlConfig:
     window: int = 8
     deadband: float = 0.25
     signal: str = "modeled"
-    model: str = "flat"
     bandwidth_mbps: float = 100.0
     budget_ms: float = 0.0
     start_rung: int = 0
@@ -104,9 +95,6 @@ class ControlConfig:
         if self.signal not in ("modeled", "measured"):
             raise ValueError(
                 f"signal must be modeled|measured, got {self.signal!r}")
-        if self.model not in ("flat", "twin"):
-            raise ValueError(
-                f"model must be flat|twin, got {self.model!r}")
         if self.signal == "modeled" and self.bandwidth_mbps <= 0:
             raise ValueError(
                 f"bandwidth_mbps must be positive for the modeled signal, "

@@ -70,18 +70,10 @@ class Controller:
     run-state rides ``TrainState.control`` so resume replays decisions
     bitwise."""
 
-    def __init__(self, cfg: ControlConfig, *, events: Any = None,
-                 pricer: Any = None):
+    def __init__(self, cfg: ControlConfig, *, events: Any = None):
         self.cfg = cfg
         self.knob = ladder_knob(cfg.method)
         self.events = events
-        # the calibrated twin's bit pricer (--adaptive_model twin): still
-        # a pure function of billed bits, so decisions stay replayable
-        self.pricer = pricer
-        if cfg.model == "twin" and pricer is None:
-            raise ValueError(
-                "cfg.model='twin' needs a TwinPricer (build one from the "
-                "records dir: harness.loop.build_twin_pricer)")
 
     # ----------------------------------------------------------- signals
 
@@ -91,10 +83,7 @@ class Controller:
                        hideable_fraction: float = 1.0) -> WindowSignals:
         """Assemble one tick's per-update signals per ``cfg.signal``."""
         if self.cfg.signal == "modeled":
-            if self.cfg.model == "twin":
-                comm = self.pricer.comm_ms(mean_bits)
-            else:
-                comm = modeled_comm_ms(mean_bits, self.cfg.bandwidth_mbps)
+            comm = modeled_comm_ms(mean_bits, self.cfg.bandwidth_mbps)
         else:
             if measured_comm_ms is None:
                 raise ValueError(
@@ -199,7 +188,7 @@ class Controller:
             return {}
         rung = int(control.rung)
         n = max(1, int(control.win_updates))
-        out = {
+        return {
             "control/rung": float(rung),
             "control/value": float(rung_value(self.cfg, rung)),
             "control/decisions": float(int(control.decisions)),
@@ -207,20 +196,6 @@ class Controller:
             "control/comm_ms": float(control.win_comm_ms) / n,
             "control/budget_ms": float(control.win_budget_ms) / n,
         }
-        if self.pricer is not None and int(control.win_updates) > 0:
-            # twin audit gauges ride the same export path: the twin's
-            # price for the open window's mean billed bits, and its
-            # discrepancy against the flat-bandwidth price (declared in
-            # obs/registry.py; derived from checkpointed state only)
-            mean_bits = float(control.win_bits) / n
-            twin_comm = self.pricer.comm_ms(mean_bits)
-            flat_comm = modeled_comm_ms(mean_bits, self.cfg.bandwidth_mbps)
-            out["twin/pred_step_ms"] = (
-                float(self.pricer.compute_anchor_ms) + twin_comm)
-            out["twin/pred_err_frac"] = ((twin_comm - flat_comm)
-                                         / max(flat_comm, 1e-9))
-            out["twin/calib_rows"] = float(self.pricer.calib_rows)
-        return out
 
     def heartbeat_fields(self, control: Any) -> dict:
         if control == ():

@@ -527,14 +527,11 @@ def run(args) -> dict:
     hide_frac = 1.0
     if ctrl_cfg is not None:
         from tpu_compressed_dp.control import Controller, comp_for_rung
-        from tpu_compressed_dp.harness.loop import build_twin_pricer
         from tpu_compressed_dp.parallel.overlap import (hideable_byte_fraction,
                                                         plan_chunks)
         from tpu_compressed_dp.train.guard import schedule_step
 
-        controller = Controller(ctrl_cfg, events=events,
-                                pricer=build_twin_pricer(args, comp,
-                                                         world=ndev))
+        controller = Controller(ctrl_cfg, events=events)
         # the overlap schedule's hideable byte fraction scales the measured
         # compute into the per-update budget (signals.hideable_budget_ms);
         # ignored when --adaptive_budget_ms pins the budget

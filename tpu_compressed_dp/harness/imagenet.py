@@ -524,14 +524,11 @@ def run(args) -> Dict[str, float]:
     hide_frac = 1.0
     if ctrl_cfg is not None:
         from tpu_compressed_dp.control import Controller
-        from tpu_compressed_dp.harness.loop import build_twin_pricer
         from tpu_compressed_dp.parallel.overlap import (hideable_byte_fraction,
                                                         plan_chunks)
         from tpu_compressed_dp.train.guard import schedule_step
 
-        controller = Controller(ctrl_cfg, events=events,
-                                pricer=build_twin_pricer(args, comp,
-                                                         world=ndev))
+        controller = Controller(ctrl_cfg, events=events)
         hide_frac = hideable_byte_fraction(plan_chunks(
             [leaf.size * 4 for leaf in jax.tree_util.tree_leaves(params)],
             comp))

@@ -279,18 +279,6 @@ def add_adaptive_args(p) -> None:
                         "--adaptive_bw_mbps (bitwise replay-deterministic); "
                         "'measured' uses harness-observed wall times "
                         "(NOT replay-deterministic)")
-    p.add_argument("--adaptive_model", type=str, default="flat",
-                   choices=("flat", "twin"),
-                   help="how 'modeled' prices bits: 'flat' divides by "
-                        "--adaptive_bw_mbps; 'twin' prices the transport's "
-                        "collective schedule through the calibrated "
-                        "per-fabric digital twin (tpu_compressed_dp/twin/, "
-                        "fitted from --twin_records).  Both are pure "
-                        "functions of billed bits (replay-deterministic)")
-    p.add_argument("--twin_records", type=str, default=".",
-                   help="directory holding the BENCH_r*/MULTICHIP_r* "
-                        "records the twin calibrates from "
-                        "(--adaptive_model twin)")
 
 
 def build_control(args, comp_cfg):
@@ -323,37 +311,8 @@ def build_control(args, comp_cfg):
         method=method, rungs=rungs,
         window=args.adaptive_window, deadband=args.adaptive_deadband,
         signal=args.adaptive_signal,
-        model=getattr(args, "adaptive_model", "flat"),
         bandwidth_mbps=args.adaptive_bw_mbps,
         budget_ms=args.adaptive_budget_ms)
-
-
-def build_twin_pricer(args, comp_cfg, *, world: int):
-    """Fit the digital twin from ``--twin_records`` and wrap it as the
-    Controller's bit pricer — None unless ``--adaptive_model twin``.
-
-    The fit happens once at harness start (a least-squares over the
-    committed artifacts, milliseconds of host work); from then on every
-    decision window prices its billed bits through the frozen result, so
-    the control loop stays replay-deterministic."""
-    if getattr(args, "adaptive_model", "flat") != "twin":
-        return None
-    from tpu_compressed_dp.control.signals import TwinPricer
-    from tpu_compressed_dp.twin import calibration_rows, fit
-
-    rows = calibration_rows(args.twin_records)
-    calib = fit(rows)
-    mode = getattr(comp_cfg, "mode", "simulate") if comp_cfg else "simulate"
-    transport = getattr(comp_cfg, "transport", None) if comp_cfg else None
-    if mode != "wire" or not transport:
-        transport = "psum"   # simulate bills compressed payloads on psum
-    elif transport == "allgather":
-        transport = "all_gather"
-    return TwinPricer(
-        model=calib.model, world=max(int(world), 1),
-        pods=int(getattr(args, "dp_pods", 1) or 1),
-        transport=transport,
-        calib_rows=len(rows))
 
 
 def control_summary(controller, control) -> Dict[str, float]:

@@ -333,19 +333,6 @@ declare("control/comm_ms", TIMING, "ms", "mean", "host",
 declare("control/budget_ms", TIMING, "ms", "mean", "host",
         "open window's mean per-update hideable-compute budget")
 
-# --- scale-out digital twin (twin/; host-side — the calibrated
-#     alpha/beta/gamma cost model pricing the run's comm, exported when
-#     the controller runs with --adaptive_model twin) --------------------
-declare("twin/pred_step_ms", TIMING, "ms", "mean", "host",
-        "twin-modeled step time at the open window's mean billed bits: "
-        "the calibrated context's compute anchor plus the priced comm")
-declare("twin/pred_err_frac", GAUGE, "frac", "mean", "host",
-        "relative discrepancy between the twin's comm price and the flat "
-        "--adaptive_bw_mbps price for the same billed bits (the audit "
-        "signal tools/control_report.py tabulates)")
-declare("twin/calib_rows", GAUGE, "rows", "max", "host",
-        "calibration rows behind the twin's fitted fabric parameters")
-
 
 # --- fleet control plane (fleet/scheduler.py; host-side — the scheduler
 #     process is the single writer, per-job values carry a job="<id>"

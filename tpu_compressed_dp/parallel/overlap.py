@@ -51,7 +51,7 @@ Measured, not asserted: ``tools/overlap_evidence.py`` AOT-compiles the real
 train step for a v5e topology and reads ``compute_after_frac`` off the
 scheduled module (per-chunk collectives labelled by their
 ``tcdp.chunk<ii>`` scopes); ``--assert-frac`` gates it.  Results land in
-``benchmarks/overlap_hlo_r8.txt`` / ``BENCH_r08.json``.
+``benchmarks/overlap_hlo_r8.txt``.
 """
 
 from __future__ import annotations

@@ -148,7 +148,7 @@ def throughput_record(fwd_flops: Optional[float], steps_per_sec: float,
 def cnn_mfu_record(apply_fn, params, batch_stats, input_shape,
                    steps_per_sec: float) -> Dict[str, float]:
     """The benchmark-record MFU fields for a CNN-style ``apply_fn`` (the
-    shared epilogue of bench.py and bench/sweep.py): forward FLOPs from the
+    epilogue of bench/sweep.py): forward FLOPs from the
     XLA cost model at the given per-chip input shape, train = 3x fwd at the
     measured step rate, ``mfu`` vs the chip's bf16 peak.  Empty dict where
     the backend exposes no cost model; ``mfu`` omitted off-TPU."""
